@@ -9,8 +9,10 @@ by the HLO cost model, winners banked into the autotune cache that
 trainer and serve consult), use ``python -m benchmark.autotune``; keep
 this script for validating banked winners on real hardware — each
 variant still runs ``python bench.py`` in its own subprocess (its own
-device client and compile cache) so a wedged/crashed config can't poison
-the rest of the sweep. Results append to
+device client; the persistent compile cache is shared through
+``bench.use_compile_cache``) so a hung or crashed config can't poison the
+rest of the sweep. The variants run one after another: a chip belongs to
+one process at a time. Results append to
 ``benchmark/sweep_results.jsonl`` and print as a table.
 
     python benchmark/bert_sweep.py             # the derived hardware sweep
@@ -30,6 +32,10 @@ import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# One process for each chip: this parent must never touch JAX, or it holds
+# the chip and every ``bench.py`` child fails or hangs at device init. It
+# imports only ``benchmark/autotune.py``, whose ``import jax`` lines are all
+# inside functions that a sweep does not call — keep it so.
 try:                              # package import (python -m benchmark...)
     from . import autotune as _autotune
 except ImportError:               # direct script run

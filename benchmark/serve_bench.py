@@ -62,6 +62,9 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
+# One process for each chip: this bench imports jax here and starts no child
+# process (its replicas are threads of this process), so on a TPU host it is
+# the one holder of the chip. The flag below only sizes the CPU platform.
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=1")
 import jax  # noqa: E402
 

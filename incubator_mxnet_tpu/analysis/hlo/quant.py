@@ -26,7 +26,7 @@ declared-int8 regions hold:
 ==========  =============================================================
 
 Detection runs over a *flattened* view of each graph: transparent call
-primitives (``pjit`` — every ``jnp.clip``/``jnp.round`` helper lowers to
+primitives (``jit`` — every ``jnp.clip``/``jnp.round`` helper lowers to
 one — plus custom-derivative wrappers) are inlined with var
 substitution, so dataflow walks cross them; control-flow bodies
 (scan/while/cond) stay separate scopes, analyzed independently.
@@ -70,9 +70,8 @@ _Q_CHAIN_PRIMS = frozenset({
 #: call-shaped primitives inlined by the flattener — one sub-jaxpr,
 #: invars/outvars align one-to-one with the sub-jaxpr's
 _TRANSPARENT_CALLS = frozenset({
-    "pjit", "closed_call", "core_call", "xla_call", "remat", "checkpoint",
-    "remat_call", "custom_jvp_call", "custom_vjp_call",
-    "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr",
+    "jit", "closed_call", "call", "remat2", "custom_jvp_call",
+    "custom_vjp_call",
 })
 
 

@@ -102,7 +102,7 @@ class TraceResult:
 
 def walk_eqns(jaxpr):
     """Yield every eqn in a (open) jaxpr, recursing into sub-jaxprs held
-    in eqn params (pjit / scan / cond bodies) — duck-typed so it works
+    in eqn params (jit / scan / cond bodies) — duck-typed so it works
     across jax versions."""
     for eqn in jaxpr.eqns:
         yield eqn
@@ -124,18 +124,26 @@ def _jaxprs_in(v):
             yield from _jaxprs_in(x)
 
 
-def _unwrap_pjit(closed):
-    """make_jaxpr over a jitted callable yields one wrapping pjit eqn;
-    return (inner ClosedJaxpr, donated_invars) when that shape holds,
-    else (closed, None)."""
+def _unwrap_jit(closed, fn):
+    """make_jaxpr over a jitted callable yields one wrapping ``jit`` eqn
+    carrying the donation intent; return (inner ClosedJaxpr,
+    donated_invars). A plain callable has no wrapper: (closed, None).
+    A jitted ``fn`` (it has ``.lower``) that does not unwrap is an error
+    — donation would be silently invisible to MX704/MX708 and to
+    ``peak_live_bytes``."""
     jaxpr = closed.jaxpr
-    if len(jaxpr.eqns) == 1 and jaxpr.eqns[0].primitive.name == "pjit":
+    if len(jaxpr.eqns) == 1 and jaxpr.eqns[0].primitive.name == "jit":
         eqn = jaxpr.eqns[0]
-        inner = eqn.params.get("jaxpr")
-        donated = eqn.params.get("donated_invars")
-        if inner is not None and hasattr(inner, "jaxpr") \
-                and len(inner.jaxpr.invars) == len(jaxpr.invars):
-            return inner, (tuple(donated) if donated is not None else None)
+        inner = eqn.params["jaxpr"]
+        if len(inner.jaxpr.invars) == len(jaxpr.invars):
+            return inner, tuple(eqn.params["donated_invars"])
+    if hasattr(fn, "lower"):
+        raise MXNetError(
+            "analysis.hlo: the jitted entry "
+            f"{getattr(fn, '__name__', type(fn).__name__)!r} did not trace "
+            "to one wrapping 'jit' eqn (got "
+            f"{[e.primitive.name for e in jaxpr.eqns][:4]}) — the jaxpr "
+            "spelling of the installed jax changed; repair _unwrap_jit")
     return closed, None
 
 
@@ -223,7 +231,7 @@ def _trace_block(block, sample_args, max_graphs: int) -> TraceResult:
         avals += [_sds(s, d) for s, d in sig]
         avals += [_sds(tuple(p.shape), p.dtype) for p in params]
         closed = jax.make_jaxpr(pure)(*avals)
-        closed, donated = _unwrap_pjit(closed)
+        closed, donated = _unwrap_jit(closed, pure)
         res.graphs.append(TracedGraph(
             entry=entry, site=site, closed=closed,
             arg_names=(["rng_key"] + [f"input:{i}" for i in range(n_in)]
@@ -280,7 +288,7 @@ def _trace_compiled(cm, sample_args, max_graphs: int) -> TraceResult:
         else:
             fn = fns
         closed = jax.make_jaxpr(fn)(*avals)
-        closed, unwrapped_donated = _unwrap_pjit(closed)
+        closed, unwrapped_donated = _unwrap_jit(closed, fn)
         res.graphs.append(TracedGraph(
             entry=entry, site=site, closed=closed,
             arg_names=(["rng_key"] + [f"input:{i}" for i in range(n_in)]
@@ -349,7 +357,7 @@ def _trace_artifact(block, sample_args, max_graphs: int) -> TraceResult:
         avals += [_sds(tuple(block._param_arrays[n].shape),
                        block._param_arrays[n]._data.dtype) for n in order]
         closed = jax.make_jaxpr(fn)(*avals)
-        closed, _don = _unwrap_pjit(closed)
+        closed, _don = _unwrap_jit(closed, fn)
         res.graphs.append(TracedGraph(
             entry=entry, site=f"sig{i}:{_sig_str(sig)}", closed=closed,
             arg_names=(["rng_key"]
@@ -404,7 +412,7 @@ def _trace_trainer(trainer, sample_args) -> TraceResult:
         specs.append(getattr(getattr(v, "sharding", None), "spec", None))
     with active_mesh(trainer._mesh):
         closed = jax.make_jaxpr(trainer._step_fn)(*args)
-    closed, donated = _unwrap_pjit(closed)
+    closed, donated = _unwrap_jit(closed, trainer._step_fn)
     if len(names) != len(closed.jaxpr.invars):
         # flattening mismatch (exotic optimizer state): degrade gracefully
         names = [f"arg:{i}" for i in range(len(closed.jaxpr.invars))]
@@ -446,7 +454,7 @@ def _trace_callable(fn, sample_args, entry=None) -> TraceResult:
     for i, site in enumerate(sites):
         avals = [_sds(*_aval_of(a)) for a in site]
         closed = jax.make_jaxpr(fn)(*avals)
-        closed, donated = _unwrap_pjit(closed)
+        closed, donated = _unwrap_jit(closed, fn)
         n = len(closed.jaxpr.invars)
         res.graphs.append(TracedGraph(
             entry=name, site=f"site{i}", closed=closed,

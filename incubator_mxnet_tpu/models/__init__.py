@@ -109,7 +109,8 @@ class _NMTEncodeEntry(HybridBlock):
 
     def hybrid_forward(self, F, src, src_len):
         B, L = src.shape
-        steps = F.arange(0, L, dtype="float32").reshape((1, L))
+        steps = F.arange(0, L, dtype="float32",
+                         ctx=src_len.context).reshape((1, L))
         mask = F.broadcast_lesser(steps, src_len.reshape((B, 1)))
         return self.encoder(self.src_embed(src),
                             mask.reshape((B, 1, 1, L)))

@@ -172,7 +172,11 @@ class BERTModel(HybridBlock):
     def _attn_mask(self, F, valid_length, B, L):
         if valid_length is None:
             return None
-        steps = F.arange(0, L, dtype="float32").reshape((1, L))
+        # made where the input lives: run eagerly on another context than
+        # the current one, a default-context arange would meet
+        # valid_length across devices
+        steps = F.arange(0, L, dtype="float32",
+                         ctx=valid_length.context).reshape((1, L))
         mask = F.broadcast_lesser(steps, valid_length.reshape((B, 1)))
         return mask.reshape((B, 1, 1, L))
 
@@ -202,7 +206,8 @@ class BERTModel(HybridBlock):
         if self.use_decoder and masked_positions is not None:
             P = masked_positions.shape[1]
             flat = seq.reshape((B * L, self._units))
-            offsets = F.arange(0, B, dtype="int32").reshape((B, 1)) * L
+            offsets = F.arange(0, B, dtype="int32",
+                               ctx=seq.context).reshape((B, 1)) * L
             idx = (masked_positions.astype("int32") + offsets).reshape((B * P,))
             h = F.take(flat, idx, axis=0).reshape((B, P, self._units))
             h = self.decoder_ln(self.decoder_transform(h))

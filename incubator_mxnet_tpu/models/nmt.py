@@ -169,7 +169,8 @@ class NMTModel(HybridBlock):
     def _src_mask(self, F, src_valid_length, B, L):
         if src_valid_length is None:
             return None
-        steps = F.arange(0, L, dtype="float32").reshape((1, L))
+        steps = F.arange(0, L, dtype="float32",
+                         ctx=src_valid_length.context).reshape((1, L))
         m = F.broadcast_lesser(steps, src_valid_length.reshape((B, 1)))
         return m.reshape((B, 1, 1, L))
 

@@ -27,22 +27,52 @@ from .lockcheck import make_lock
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _NATIVE_DIR = os.path.join(_REPO_ROOT, "native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "libmxtpu_native.so")
+_SO_NAME = "libmxtpu_native.so"
+_SO_PATH = os.path.join(_NATIVE_DIR, _SO_NAME)
 
 _LIB: Optional[ctypes.CDLL] = None
 _LOAD_LOCK = make_lock("native._LOAD_LOCK")
-_LOAD_FAILED = False
+_LOAD_FAILED = ""      # why the build failed, once it has
 
 _TASK_FN = ctypes.CFUNCTYPE(None, ctypes.c_void_p)
 
 
-def _build() -> bool:
+_SRC_PATH = os.path.join(_NATIVE_DIR, "mxtpu_native.cc")
+
+
+def _stale() -> bool:
+    """No library yet, or one older than its source (a copied tree can
+    carry a stale ``.so``: it is ignored by git, the source is not)."""
     try:
-        subprocess.run(["make", "-C", _NATIVE_DIR],
-                       check=True, capture_output=True, timeout=120)
-        return os.path.exists(_SO_PATH)
-    except Exception:
-        return False
+        return os.path.getmtime(_SO_PATH) < os.path.getmtime(_SRC_PATH)
+    except OSError:
+        return True
+
+
+def _build() -> None:
+    """``make -C native`` into a private name, then an atomic rename —
+    several processes (pytest workers) may build at once and none may
+    load a half-written library. Raises MXNetError carrying what failed."""
+    tmp_name = f"{_SO_NAME}.tmp-{os.getpid()}"
+    tmp_path = os.path.join(_NATIVE_DIR, tmp_name)
+    try:
+        subprocess.run(["make", "-C", _NATIVE_DIR, f"SO={tmp_name}"],
+                       check=True, capture_output=True, text=True,
+                       timeout=120)
+        os.replace(tmp_path, _SO_PATH)
+    except FileNotFoundError as e:
+        raise MXNetError(
+            f"cannot build {_SO_PATH}: no make on PATH ({e})") from e
+    except subprocess.TimeoutExpired as e:
+        raise MXNetError(f"cannot build {_SO_PATH}: make timed out "
+                         f"after {e.timeout:g}s") from e
+    except subprocess.CalledProcessError as e:
+        raise MXNetError(
+            f"cannot build {_SO_PATH}: make exited {e.returncode}\n"
+            f"{(e.stderr or e.stdout or '').strip()[-2000:]}") from e
+    finally:
+        if os.path.exists(tmp_path):
+            os.unlink(tmp_path)
 
 
 def _lib() -> ctypes.CDLL:
@@ -53,11 +83,13 @@ def _lib() -> ctypes.CDLL:
         if _LIB is not None:
             return _LIB
         if _LOAD_FAILED:
-            raise MXNetError("native library unavailable (build failed)")
-        if not os.path.exists(_SO_PATH) and not _build():
-            _LOAD_FAILED = True
-            raise MXNetError(
-                "cannot build native/libmxtpu_native.so (no toolchain?)")
+            raise MXNetError(f"native library unavailable: {_LOAD_FAILED}")
+        if _stale():
+            try:
+                _build()
+            except MXNetError as e:
+                _LOAD_FAILED = str(e)
+                raise
         lib = ctypes.CDLL(_SO_PATH)
         lib.MXTPUGetLastError.restype = ctypes.c_char_p
         lib.MXTPURecordIOWriterCreate.restype = ctypes.c_void_p

@@ -94,6 +94,44 @@ def _maybe_ring(query, key, value, mask, causal, scale):
     return fn(query, key, value, key_mask)
 
 
+def _flash_on_mesh(query, key, value, mask, causal, scale, window):
+    """The Pallas flash kernel, run per shard where a mesh is active.
+
+    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"), so inside a step compiled over a multi-device mesh the
+    kernel runs under a full-manual ``shard_map``: batch over ``dp`` and
+    heads over ``tp`` where they divide (attention is independent along
+    both), replicated over every other axis. Outside a trace, or on a
+    one-device mesh, it is the plain kernel call.
+    """
+    from functools import partial
+    from ..parallel.mesh import current_active_mesh
+    from .pallas.flash_attention import _as_key_mask, flash_attention
+    attn = partial(flash_attention, causal=causal, scale=scale,
+                   window=window)
+    mesh = current_active_mesh()
+    if mesh is None or mesh.devices.size == 1 \
+            or not isinstance(query, jax.core.Tracer):
+        return attn(query, key, value, mask=mask)
+    from jax.sharding import PartitionSpec as P
+    from ..parallel.collectives import shard_map
+    B, H, Lq, _ = query.shape
+    dp, tp = mesh.shape.get("dp", 1), mesh.shape.get("tp", 1)
+    bspec = "dp" if dp > 1 and B % dp == 0 else None
+    hspec = "tp" if tp > 1 and H % tp == 0 else None
+    spec = P(bspec, hspec, None, None)
+    if mask is None:
+        return shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec)(query, key, value)
+    key_mask = _as_key_mask(mask, B, H, Lq, key.shape[2])
+    if key_mask is None:
+        return attn(query, key, value, mask=mask)   # raises: not a key mask
+    return shard_map(
+        lambda q, k, v, m: attn(q, k, v, mask=m), mesh=mesh,
+        in_specs=(spec, spec, spec, P(bspec, None)),
+        out_specs=spec)(query, key, value, key_mask)
+
+
 @register_op()
 def dot_product_attention(query, key, value, mask=None, causal=False,
                           scale=None, impl="auto", window=None, **_):
@@ -133,17 +171,13 @@ def dot_product_attention(query, key, value, mask=None, causal=False,
         ring_out = _maybe_ring(query, key, value, mask, causal, scale)
         if ring_out is not None:
             return ring_out
-    use_flash = False
     if impl in ("auto", "flash"):
-        try:
-            from .pallas.flash_attention import flash_attention, flash_supported
-            use_flash = impl == "flash" or flash_supported(query, key, value, mask)
-        except Exception:
-            use_flash = False
-    if use_flash:
-        from .pallas.flash_attention import flash_attention
-        return flash_attention(query, key, value, mask=mask, causal=causal,
-                               scale=scale, window=window)
+        # no catch here: a kernel that fails to import or lower must be
+        # seen, not replaced by the XLA path in silence
+        from .pallas.flash_attention import flash_supported
+        if impl == "flash" or flash_supported(query, key, value, mask):
+            return _flash_on_mesh(query, key, value, mask, causal, scale,
+                                  window)
     acc = jnp.float32
     s = jnp.einsum("bhqd,bhkd->bhqk", query, key,
                    preferred_element_type=acc) * scale

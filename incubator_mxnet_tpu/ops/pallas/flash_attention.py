@@ -40,34 +40,24 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu is importable on CPU builds too; guard for safety
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+_VMEM = pltpu.VMEM
 
 __all__ = ["flash_attention", "flash_supported"]
 
 _NEG = -1e30
 
 
-def _platform_of(x) -> Optional[str]:
-    """Platform of a concrete jax.Array, or None for tracers."""
-    try:
-        devs = x.devices()
-        return next(iter(devs)).platform
-    except Exception:
-        return None
-
-
 def _interpret_for(x) -> bool:
-    """Run the kernel in interpreter mode? Concrete arrays: wherever they
-    live; tracers: the backend this trace is being compiled for (best
-    available signal: the process default backend)."""
-    p = _platform_of(x)
-    return (jax.default_backend() if p is None else p) != "tpu"
+    """Run the kernel in interpreter mode? Concrete arrays: only where
+    they live off the TPU; tracers: only where the backend this trace is
+    compiled for (the process default backend) is not the TPU. So on a
+    TPU host a jitted step or an array on the chip always compiles the
+    kernel with Mosaic, whatever ``impl`` asked for."""
+    if isinstance(x, jax.core.Tracer):
+        return jax.default_backend() != "tpu"
+    return next(iter(x.devices())).platform != "tpu"
 
 
 def flash_supported(q, k, v, mask=None) -> bool:
@@ -96,8 +86,9 @@ def _auto_block(length: int) -> int:
     else 512 (which won't divide — the caller then routes to the XLA path
     via ``flash_supported``).
 
-    Measured on v5e (BERT-base, L=512, D=64): (BQ, BK)=(512, 512) runs the
-    step at 40.9ms vs 45.5ms for (256, 512) and a pathological 1066ms for
+    Measured 2026-07-30 on a v5e under an earlier installation (BASELINE.md;
+    not re-measured since): BERT-base, L=512, D=64, (BQ, BK)=(512, 512) ran
+    the step at 40.9ms vs 45.5ms for (256, 512) and a pathological 1066ms for
     (128, 512) — bigger tiles amortize the grid/recurrence overhead and keep
     the MXU busier, and VMEM comfortably holds a 512-row block up to D=256.
     Tiles below 256 rows are never chosen automatically (the 128-row config
@@ -131,11 +122,10 @@ def _bk(lk: int) -> int:
     return _auto_block(lk)
 
 
-def _dimsem(n: int = 2):
-    """(parallel, ..., arbitrary) compiler hints; None off-TPU."""
-    if pltpu is None:
-        return None
-    return dict(dimension_semantics=("parallel",) * n + ("arbitrary",))
+#: grid semantics of all three kernels for Mosaic: (batch·head, fixed
+#: block) are parallel, the streamed block carries the recurrence
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 def _as_key_mask(mask, B, H, Lq, Lk):
@@ -235,8 +225,6 @@ def _fwd_kernel_nomask(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _scratch(bq, d):
-    if pltpu is None:
-        return None
     return [pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32)]
@@ -267,9 +255,7 @@ def _fwd(q, k, v, key_mask, causal, scale, window=None):
         _fwd_kernel if key_mask is not None else _fwd_kernel_nomask,
         scale=scale, causal=causal, causal_off=Lk - Lq, window=window)
     interpret = _interpret_for(q3)
-    kwargs = {}
-    if not interpret and pltpu is not None:
-        kwargs["compiler_params"] = pltpu.CompilerParams(**_dimsem(2))
+    kwargs = {} if interpret else {"compiler_params": _COMPILER_PARAMS}
     o, lse = pl.pallas_call(
         kern,
         grid=grid,
@@ -425,9 +411,7 @@ def _bwd(q, k, v, key_mask, causal, scale, o, lse, do, dlse=None,
     lse3 = lse.reshape(BH, 1, Lq)
     delta3 = delta.reshape(BH, 1, Lq)
     interpret = _interpret_for(q3)
-    kwargs = {}
-    if not interpret and pltpu is not None:
-        kwargs["compiler_params"] = pltpu.CompilerParams(**_dimsem(2))
+    kwargs = {} if interpret else {"compiler_params": _COMPILER_PARAMS}
 
     # ---- dk/dv: fixed k-block (parallel), stream q-blocks (arbitrary)
     dkv_specs = [
@@ -461,9 +445,8 @@ def _bwd(q, k, v, key_mask, causal, scale, o, lse, do, dlse=None,
             jax.ShapeDtypeStruct((BH, Lk, D), k.dtype),
             jax.ShapeDtypeStruct((BH, Lk, D), v.dtype),
         ],
-        scratch_shapes=([pltpu.VMEM((bk, D), jnp.float32),
-                         pltpu.VMEM((bk, D), jnp.float32)]
-                        if pltpu is not None else None),
+        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                        pltpu.VMEM((bk, D), jnp.float32)],
         interpret=interpret,
         **kwargs,
     )(*args)
@@ -491,8 +474,7 @@ def _bwd(q, k, v, key_mask, causal, scale, o, lse, do, dlse=None,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
                                memory_space=_VMEM),
         out_shape=jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
-        scratch_shapes=([pltpu.VMEM((bq, D), jnp.float32)]
-                        if pltpu is not None else None),
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
         **kwargs,
     )(*args)

@@ -108,8 +108,15 @@ class Optimizer:
 
     def create_state_multi_precision(self, index, weight: NDArray) -> Tuple:
         if self.multi_precision and weight.dtype in ("float16", "bfloat16"):
-            master = weight._data.astype(jnp.float32)
-            return (master,) + self.create_state(index, weight)
+            # the states belong to the fp32 master (reference:
+            # create_state(index, weight_master_copy)): the update runs
+            # in fp32 and returns fp32 states, and states created in the
+            # weight's 16-bit dtype would change type after one step — a
+            # second trace and compile of a jitted step, and donated
+            # buffers that cannot alias their outputs
+            master = NDArray(weight._data.astype(jnp.float32),
+                             ctx=weight.context)
+            return (master._data,) + self.create_state(index, master)
         return self.create_state(index, weight)
 
     # -- update ------------------------------------------------------------

@@ -15,21 +15,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec
-try:
-    from jax import shard_map as _raw_shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _raw_shard_map
+from jax import shard_map as _raw_shard_map
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions (the
-    kwarg was renamed check_rep → check_vma)."""
-    try:
-        return _raw_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _raw_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with the varying-manual-axes check off: the
+    per-shard bodies here (ring attention, MoE dispatch, the pipeline
+    schedule) return values replicated by construction."""
+    return _raw_shard_map(f, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False)
 
 
 P = PartitionSpec

@@ -112,13 +112,13 @@ def world() -> Tuple[int, int]:
     fallback so the checkpoint/telemetry layers see a consistent answer
     at import time. The pair the manifest commit protocol, the data
     shard view, and the telemetry namespacing key on."""
-    try:
-        from jax._src.distributed import global_state
-        if getattr(global_state, "client", None) is not None:
-            return (int(global_state.process_id or 0),
-                    int(global_state.num_processes or 1))
-    except Exception:  # noqa: BLE001 — jax version drift → env fallback
-        pass
+    # a private jax module: an import or attribute error here means the
+    # installed jax moved it, and must be seen (a silent env fallback
+    # would make every host of a pod believe it is host 0 of 1)
+    from jax._src.distributed import global_state
+    if global_state.client is not None:
+        return (int(global_state.process_id or 0),
+                int(global_state.num_processes or 1))
     try:
         idx = int(os.environ.get("DMLC_WORKER_ID", "0") or 0)
         n = int(os.environ.get("DMLC_NUM_WORKER", "1") or 1)

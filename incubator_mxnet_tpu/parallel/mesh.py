@@ -82,19 +82,23 @@ def make_mesh(config: Optional[MeshConfig] = None,
               devices: Optional[Sequence] = None, **axes) -> Mesh:
     """Build a named Mesh. ``make_mesh(dp=2, tp=4)`` or with a MeshConfig.
 
-    Axes are laid out in :data:`CANONICAL_ORDER`; on real TPU slices the
+    Axes are laid out in :data:`CANONICAL_ORDER`; on TPU devices the
     device order comes from ``mesh_utils.create_device_mesh`` so inner axes
-    land on ICI neighbours.
+    land on ICI neighbours (it raises where it cannot lay the mesh out);
+    other platforms take the devices in list order.
     """
     if config is None:
         config = MeshConfig(**{**dict(dp=-1), **axes}) if axes else MeshConfig()
     devices = list(devices if devices is not None else jax.devices())
     sizes = config.resolve(len(devices))
     shape = tuple(sizes[a] for a in CANONICAL_ORDER)
-    try:
+    if devices[0].platform == "tpu":
+        # the torus-aware layout; a failure is an error, never a silent
+        # reshape that puts tp/sp neighbours on distant chips
         from jax.experimental import mesh_utils
         dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
+    else:
+        # virtual CPU devices have no topology: list order is the layout
         dev_array = onp.array(devices).reshape(shape)
     return Mesh(dev_array, CANONICAL_ORDER)
 

@@ -26,12 +26,14 @@ versions in place with **zero** recompiles.
 """
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as onp
+from jax.sharding import SingleDeviceSharding
 
 from ..base import MXNetError
 from ..context import current_context
@@ -278,11 +280,17 @@ class CompiledModel:
     def _compile(self, key: tuple, sig) -> Callable:
         from .. import autotune as _autotune
         t0 = time.perf_counter()
-        avals = [jax.ShapeDtypeStruct(self._key_data.shape,
-                                      self._key_data.dtype)]
-        avals += [jax.ShapeDtypeStruct(s, jnp.dtype(d)) for s, d in sig]
-        avals += [jax.ShapeDtypeStruct(p.shape, p.dtype)
-                  for p in self._pvals]
+        # compiled for the device the parameters live on (an AOT
+        # executable refuses arguments committed anywhere else): a model
+        # initialized on mx.tpu(1) serves from chip 1, not from the
+        # process default device
+        dev = (next(iter(self._pvals[0].devices())) if self._pvals
+               else self._ctx.jax_device)
+        sds = functools.partial(jax.ShapeDtypeStruct,
+                                sharding=SingleDeviceSharding(dev))
+        avals = [sds(self._key_data.shape, self._key_data.dtype)]
+        avals += [sds(s, jnp.dtype(d)) for s, d in sig]
+        avals += [sds(p.shape, p.dtype) for p in self._pvals]
         with _autotune.applied(self.autotune_entry):
             # the trace reads tunable env knobs (flash block sizes,
             # embed-grad path) — the cached winner overlays exactly this

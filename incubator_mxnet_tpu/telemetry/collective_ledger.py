@@ -290,15 +290,14 @@ def _coord():
     """(client, process_index, num_processes) from the jax coordination
     service WITHOUT initializing any backend — ``(None, 0, 1)`` when the
     process never rendezvoused (single-host runs, unit tests)."""
-    try:
-        from jax._src.distributed import global_state
-        client = getattr(global_state, "client", None)
-        if client is None:
-            return None, 0, 1
-        return (client, int(global_state.process_id or 0),
-                int(global_state.num_processes or 1))
-    except Exception:  # noqa: BLE001 — jax version drift degrades to off
+    # private jax module: if the installed jax moves it this must raise,
+    # not turn the cross-process check off in silence
+    from jax._src.distributed import global_state
+    client = global_state.client
+    if client is None:
         return None, 0, 1
+    return (client, int(global_state.process_id or 0),
+            int(global_state.num_processes or 1))
 
 
 def _num_processes() -> int:

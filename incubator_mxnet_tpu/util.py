@@ -13,7 +13,8 @@ from typing import Any, Dict, Optional
 __all__ = ["getenv", "setenv", "env_var_doc", "makedirs", "use_np_shape",
            "is_np_shape", "is_np_array", "set_np", "reset_np", "np_shape",
            "nearest_rank_percentile", "parse_size", "hbm_budget_bytes",
-           "peak_tflops", "roofline_peaks", "PEAK_TFLOPS_BY_KIND"]
+           "device_peaks", "peak_tflops", "roofline_peaks",
+           "DEVICE_PEAKS_BY_KIND", "RANKING_NOMINAL_CHIP"]
 
 
 def parse_size(s: str) -> int:
@@ -52,42 +53,73 @@ def hbm_budget_bytes() -> Optional[int]:
     return parse_size(raw) if raw else None
 
 
-#: nominal per-chip bf16 peaks for MFU/roofline accounting (public
-#: specs) — THE single table ``bench.py``, ``benchmark/autotune.py``,
-#: and ``telemetry.goodput`` all read, so a chip-kind correction lands
-#: in every consumer at once. The unknown/CPU default keeps
-#: device-blind runs deterministic (rankings, not absolute MFU).
-PEAK_TFLOPS_BY_KIND = {"v5 lite": 197.0, "v5e": 197.0, "v5p": 459.0,
-                       "v5": 459.0, "v4": 275.0, "v3": 123.0,
-                       "v6e": 918.0, "v6 lite": 918.0, "trillium": 918.0}
-DEFAULT_PEAK_TFLOPS = 459.0
-DEFAULT_PEAK_GBPS = 1200.0       # nominal HBM bandwidth
-DEFAULT_ICI_GBPS = 90.0          # nominal inter-chip bandwidth
+#: per-chip peaks keyed by the lower-cased ``jax.Device.device_kind``,
+#: matched whole (never by substring: "tpu v5 lite" must not read the
+#: "tpu v5" row): ``(bf16 TFLOP/s, HBM GB/s, ICI GB/s)``. Source: Google
+#: Cloud documentation, "TPU v5e" (197 TFLOP/s, 819 GB/s, 1,600 Gbit/s
+#: of interconnect) and "TPU v5p" (459 TFLOP/s, 2,765 GB/s, 4,800
+#: Gbit/s). THE single table ``bench.py``, ``benchmark/autotune.py`` and
+#: ``telemetry.goodput`` read. A kind that is not here is an error on
+#: the measuring path: add its row, with its source, before measuring.
+DEVICE_PEAKS_BY_KIND = {
+    "tpu v5 lite": (197.0, 819.0, 200.0),
+    "tpu v5e": (197.0, 819.0, 200.0),
+    "tpu v5": (459.0, 2765.0, 600.0),
+    "tpu v5p": (459.0, 2765.0, 600.0),
+}
+#: the unit chip trace-only callers rank candidates against when no
+#: known chip is attached (``bench.py --proxy``, the autotuner's roofline
+#: score, goodput's predicted MFU on CPU): ``(TFLOP/s, HBM GB/s, ICI
+#: GB/s)``. It keeps rankings deterministic; it is no device's peak and
+#: nothing divided by it is a device metric.
+RANKING_NOMINAL_CHIP = (459.0, 1200.0, 90.0)
+
+
+def _device_kind() -> str:
+    import jax
+    return jax.devices()[0].device_kind
+
+
+def device_peaks(kind: Optional[str] = None) -> tuple:
+    """``(bf16 TFLOP/s, HBM GB/s, ICI GB/s)`` of the attached chip (or of
+    ``kind``) from :data:`DEVICE_PEAKS_BY_KIND`. Raises
+    :class:`~incubator_mxnet_tpu.base.MXNetError` for a device that has
+    no row — CPU included — so no utilization is ever computed against
+    another chip's peak."""
+    kind = _device_kind() if kind is None else kind
+    try:
+        return DEVICE_PEAKS_BY_KIND[kind.lower()]
+    except KeyError:
+        from .base import MXNetError
+        raise MXNetError(
+            f"no peak-table row for device kind {kind!r} (known: "
+            f"{sorted(DEVICE_PEAKS_BY_KIND)}); add one to "
+            "util.DEVICE_PEAKS_BY_KIND with its source, or set "
+            "MXTPU_PEAK_TFLOPS") from None
 
 
 def peak_tflops() -> float:
-    """Per-chip bf16 peak TFLOPs (``MXTPU_PEAK_TFLOPS`` overrides, else
-    by device kind; the deterministic default on unknown/CPU/no
-    backend)."""
+    """Per-chip bf16 peak TFLOP/s for a MEASURED utilization
+    (``MXTPU_PEAK_TFLOPS`` overrides, else :func:`device_peaks` of the
+    attached chip). An unknown device kind raises."""
     env = os.environ.get("MXTPU_PEAK_TFLOPS")
     if env:
         return float(env)
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind.lower()
-        return next((v for k, v in PEAK_TFLOPS_BY_KIND.items()
-                     if k in kind), DEFAULT_PEAK_TFLOPS)
-    except Exception:  # noqa: BLE001 — no backend: stay deterministic
-        return DEFAULT_PEAK_TFLOPS
+    return device_peaks()[0]
 
 
 def roofline_peaks() -> tuple:
     """``(peak_flops_per_s, hbm_bytes_per_s, ici_bytes_per_s)`` — the
-    roofline denominators (``MXTPU_PEAK_TFLOPS`` / ``MXTPU_PEAK_GBPS``
-    / ``MXTPU_ICI_GBPS`` override the per-kind defaults)."""
-    bw = float(os.environ.get("MXTPU_PEAK_GBPS", DEFAULT_PEAK_GBPS))
-    ici = float(os.environ.get("MXTPU_ICI_GBPS", DEFAULT_ICI_GBPS))
-    return peak_tflops() * 1e12, bw * 1e9, ici * 1e9
+    roofline denominators of the trace-only callers: the attached
+    chip's row when it has one, else :data:`RANKING_NOMINAL_CHIP`
+    (``MXTPU_PEAK_TFLOPS`` / ``MXTPU_PEAK_GBPS`` / ``MXTPU_ICI_GBPS``
+    override either)."""
+    tf, bw, ici = DEVICE_PEAKS_BY_KIND.get(_device_kind().lower(),
+                                           RANKING_NOMINAL_CHIP)
+    tf = float(os.environ.get("MXTPU_PEAK_TFLOPS") or tf)
+    bw = float(os.environ.get("MXTPU_PEAK_GBPS") or bw)
+    ici = float(os.environ.get("MXTPU_ICI_GBPS") or ici)
+    return tf * 1e12, bw * 1e9, ici * 1e9
 
 
 def nearest_rank_percentile(sorted_vals, q: float) -> float:

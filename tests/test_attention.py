@@ -264,3 +264,43 @@ def test_window_rejects_zero_and_ring():
         dot_product_attention(q, k, v, causal=True, window=0, impl="xla")
     with pytest.raises(ValueError, match="ring"):
         dot_product_attention(q, k, v, causal=True, window=8, impl="ring")
+
+
+@pytest.mark.parametrize("axes,masked", [(dict(dp=2, tp=2), True),
+                                         (dict(dp=4, tp=2), False),
+                                         (dict(dp=3, tp=1), True)],
+                         ids=["dp2_tp2_masked", "dp4_tp2", "dp3_indivisible"])
+def test_flash_under_a_mesh_runs_per_shard(axes, masked):
+    """GSPMD cannot partition a Mosaic kernel, so inside a step compiled
+    over a multi-device mesh the flash call is wrapped in a full-manual
+    shard_map (batch over dp, heads over tp where they divide; replicated
+    where they do not): same values and grads as the XLA path, and the
+    shard_map really is in the graph."""
+    from incubator_mxnet_tpu import parallel
+    from incubator_mxnet_tpu.parallel.mesh import active_mesh
+    n = axes["dp"] * axes["tp"]
+    mesh = parallel.make_mesh(devices=jax.devices()[:n], **axes)
+    rng = onp.random.RandomState(0)
+    B, H, L, D = 4, 4, 64, 16
+    q, k, v, do = (jnp.asarray(rng.randn(B, H, L, D).astype("float32"))
+                   for _ in range(4))
+    mask = None
+    if masked:
+        lens = onp.array([64, 40, 17, 33])
+        mask = jnp.asarray(onp.arange(L)[None, :] < lens[:, None]
+                           ).reshape(B, 1, 1, L)
+
+    def run(impl):
+        def f(q, k, v, do):
+            o, vjp = jax.vjp(lambda q, k, v: dot_product_attention(
+                q, k, v, mask, impl=impl), q, k, v)
+            return (o,) + vjp(do)
+        with active_mesh(mesh):
+            jaxpr = str(jax.make_jaxpr(f)(q, k, v, do))
+            return jax.jit(f)(q, k, v, do), jaxpr
+
+    got, jaxpr = run("flash")
+    want, ref_jaxpr = run("xla")
+    assert "shard_map" in jaxpr and "shard_map" not in ref_jaxpr
+    for g, w in zip(got, want):
+        onp.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)

@@ -367,6 +367,47 @@ def test_multihost_reshard_resume_allclose(tmp_path):
     assert res_losses == ref_losses   # stricter: same mesh ⇒ bit-identical
 
 
+def test_restore_pairs_parameters_when_a_prefix_counter_gains_a_digit(
+        tmp_path):
+    """Gluon numbers its layers from a process-wide counter and the
+    trainer orders parameters by name, as strings: ``dense9_`` sorts after
+    ``dense10_``. A checkpoint of ``dense8_/dense9_`` restored into
+    ``dense9_/dense10_`` must still pair first layer with first layer."""
+    def trainer(first, second):
+        net = gluon.nn.HybridSequential(prefix="")
+        net.add(gluon.nn.Dense(16, activation="relu", in_units=8,
+                               prefix=f"dense{first}_"),
+                gluon.nn.Dense(4, in_units=16, prefix=f"dense{second}_"))
+        net.initialize(mx.init.Xavier())
+        return parallel.ShardedTrainer(
+            net, gluon.loss.SoftmaxCrossEntropyLoss(), "adamw",
+            {"learning_rate": 1e-2},
+            mesh=parallel.make_mesh(devices=jax.devices()[:1]))
+
+    rng = onp.random.RandomState(3)
+    x = rng.randn(8, 8).astype("float32")
+    y = rng.randint(0, 4, (8,)).astype("float32")
+    mx.random.seed(21)
+    saved = trainer(8, 9)
+    saved.step(x, y)
+    saved.save_checkpoint(str(tmp_path))
+    mx.random.seed(22)
+    fresh = trainer(9, 10)
+    fresh.step(x, y)
+    assert [p.name for p in fresh._params][0].startswith("dense10_")
+    with warnings.catch_warnings():
+        warnings.simplefilter("ignore")     # "names differ; by position"
+        fresh.restore_checkpoint(str(tmp_path))
+    by_shape = lambda tr: {tuple(v.shape): jax.device_get(v)  # noqa: E731
+                           for v in tr._param_vals}
+    want, got = by_shape(saved), by_shape(fresh)
+    assert want.keys() == got.keys() and len(want) == 4
+    for shape in want:
+        onp.testing.assert_array_equal(got[shape], want[shape])
+    assert float(fresh.step(x, y).asnumpy()) == \
+        float(saved.step(x, y).asnumpy())
+
+
 # ---------------------------------------------------------------------------
 # guards + watchdog (chaos-driven)
 # ---------------------------------------------------------------------------

@@ -265,3 +265,51 @@ def test_gluon_trainer_batched_kvstore_exchange(monkeypatch):
     run(s2)
     assert len(s2.push_calls) == 2          # weight + bias, one push each
     assert all(not isinstance(k, list) for k in s2.push_calls)
+
+
+@pytest.mark.parametrize("mesh_axes", [None, dict(dp=4, tp=2)],
+                         ids=["one_device", "dp4_tp2"])
+def test_step_is_traced_once_across_steps_and_restore(mesh_axes, tmp_path):
+    """The jit entry keys its trace on argument TYPES: the step counter
+    comes back mesh-typed, and a bf16 parameter's multi-precision moments
+    come back fp32. State made with any other type makes step 2 a second
+    signature — a silent second trace and compile, outside the autotune
+    overlay and invisible to the compile ledger (which sees batch
+    signatures), with donated buffers that cannot alias. So: one trace,
+    through steps, a placed batch, and a checkpoint restore."""
+    mesh = (parallel.make_mesh(devices=jax.devices()[:1])
+            if mesh_axes is None else parallel.make_mesh(**mesh_axes))
+
+    def bf16_trainer():
+        mx.random.seed(13)
+        net = gluon.nn.HybridSequential(prefix="meshstep_")
+        with net.name_scope():
+            net.add(gluon.nn.Dense(32, activation="relu", in_units=24),
+                    gluon.nn.Dense(8, in_units=32))
+        net.initialize(mx.init.Xavier(rnd_type="gaussian"))
+        net.cast("bfloat16")
+        return parallel.ShardedTrainer(
+            net, gluon.loss.SoftmaxCrossEntropyLoss(), "adamw",
+            {"learning_rate": 1e-2, "multi_precision": True}, mesh=mesh,
+            rules=RULES)
+
+    x, y = _batch()
+    x = x.astype(jnp.bfloat16)
+    tr = bf16_trainer()
+    for _ in range(3):
+        tr.step(x, y)
+    tr.step(*tr.place(x, y))
+    assert tr._step_fn._cache_size() == 1
+    # masters and both moments are fp32 from the start, not after step 1
+    for states in tr._opt_states:
+        assert [s.dtype for s in states] == [jnp.float32] * 3
+    tr.save_checkpoint(str(tmp_path))
+    tr2 = bf16_trainer()
+    tr2.step(x, y)
+    tr2.restore_checkpoint(str(tmp_path))
+    tr2.step(x, y)
+    assert tr2._step_fn._cache_size() == 1
+    # and the donation the types now allow is whole: every state byte
+    g = hlo.trace_entry(tr, (x, y)).graphs[0]
+    assert all(d for d, r in zip(g.donated, g.roles)
+               if r in ("param", "state"))

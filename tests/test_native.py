@@ -147,3 +147,37 @@ def test_engine_async_exception_rethrown_at_sync_point():
     eng.wait_all()
     assert ran == [1, 2]
     eng.close()
+
+
+def test_stale_library_is_rebuilt_and_a_failed_make_says_why(monkeypatch,
+                                                           tmp_path):
+    """The library is ignored by git and the source is not, so a copied
+    tree can carry a ``.so`` older than ``mxtpu_native.cc``: ``_lib()``
+    rebuilds it. And a build that fails reports make's own words."""
+    import os
+    import shutil
+    from incubator_mxnet_tpu.base import MXNetError
+    if shutil.which("make") is None or shutil.which("g++") is None:
+        pytest.skip("native toolchain unavailable")
+    assert native.available()
+    work = tmp_path / "native"
+    shutil.copytree(native._NATIVE_DIR, work)
+    so, src = work / native._SO_NAME, work / "mxtpu_native.cc"
+    for name, value in (("_NATIVE_DIR", str(work)), ("_SO_PATH", str(so)),
+                        ("_SRC_PATH", str(src)), ("_LIB", None),
+                        ("_LOAD_FAILED", "")):
+        monkeypatch.setattr(native, name, value)
+    assert not native._stale()
+    os.utime(so, (1, 1))                    # older than its source
+    assert native._stale()
+    native._lib()
+    assert not native._stale() and os.path.getmtime(so) > 1
+    assert not [f for f in os.listdir(work) if ".tmp-" in f]
+    # a source that does not compile: the error carries the compiler's text
+    src.write_text("this is not C++\n")
+    monkeypatch.setattr(native, "_LIB", None)
+    with pytest.raises(MXNetError, match=r"make exited \d+(.|\n)*error"):
+        native._lib()
+    assert not native.available()           # and stays a clean False
+    with pytest.raises(MXNetError, match="make exited"):
+        native._lib()                       # remembered, not re-run blind

@@ -658,3 +658,48 @@ class TestServeLint:
                           os.path.join(REPO, "examples"), "--strict"])
         out = capsys.readouterr().out
         assert rc == 0, out
+
+
+def test_compiled_model_serves_from_the_context_its_block_lives_on():
+    """The chip path in miniature: a block initialized on a context that is
+    NOT the current one (``mx.tpu(0)`` on a TPU host; the second virtual
+    CPU device here) must warm up and serve there. Found on the chip by
+    ``chip_smoke.py``: the model's eager first forward made ``F.arange`` on
+    the current context and met ``valid_length`` across devices; and every
+    bucket was compiled for the process default device, which an
+    executable's committed parameters then refuse."""
+    import jax
+
+    from incubator_mxnet_tpu import models
+    ctx = mx.cpu(1)
+    assert ctx.jax_device != mx.current_context().jax_device
+    net = models.get_bert("bert_2_128_2", vocab_size=100, max_length=16,
+                          dropout=0.1, use_decoder=False,
+                          use_classifier=False)
+    net.initialize(ctx=ctx)
+    net.hybridize()
+    spec = models.serve_spec("bert_encoder")
+    table = serve.BucketTable({"batch": (2, 4), "seq": (8, 16)})
+    example = (nd.array(onp.ones((2, 8), "int32"), ctx=ctx),
+               nd.array(onp.zeros((2, 8), "int32"), ctx=ctx),
+               nd.array(onp.full((2,), 8, "float32"), ctx=ctx))
+    cm = serve.CompiledModel(net, table, spec["input_axes"],
+                             example_args=example,
+                             output_axes=spec["output_axes"],
+                             pad_values=spec["pad_values"])
+    assert cm.warmup()["compiled"] == 4
+    ids = onp.arange(5, dtype="int32")[None] + 1
+    seq, pooled = cm.predict(ids, onp.zeros((1, 5), "int32"),
+                             onp.asarray([5.0], "float32"))
+    assert seq.shape == (1, 5, 128) and pooled.shape == (1, 128)
+    assert seq.context == ctx
+    assert seq._data.devices() == {ctx.jax_device}
+    assert onp.isfinite(seq.asnumpy()).all()
+    # the same request inside a padded batch answers the same
+    ids4 = onp.concatenate([onp.pad(ids, ((0, 0), (0, 7))),
+                            onp.ones((2, 12), "int32")])
+    seq4, _ = cm.predict(ids4, onp.zeros((3, 12), "int32"),
+                         onp.asarray([5.0, 12.0, 12.0], "float32"))
+    onp.testing.assert_allclose(seq4.asnumpy()[0, :5], seq.asnumpy()[0],
+                                rtol=1e-4, atol=1e-5)
+    assert cm.cache_info()["post_warmup_compiles"] == 0

@@ -12,6 +12,14 @@ N identical workers:
     python tools/launch.py -n 4 python train.py --kv-store dist_sync
     python tools/launch.py -n 8 -H hostfile --launcher ssh python train.py
 
+One process per HOST, not per chip: on a TPU host one process drives every
+local chip (a mesh over ``jax.local_devices()``), and a chip belongs to one
+process at a time. ``--launcher local`` gives all n workers the same
+environment, so on a TPU host each would try to take every chip and all
+but one would fail or hang; it is for CPU rehearsals of the rendezvous
+(``JAX_PLATFORMS=cpu``, as the tests and ``tools/multichip_smoke.py --dist``
+run it) and for the ssh launcher's one-worker-per-host layout.
+
 Env vars set per worker (reference-compatible names):
   DMLC_PS_ROOT_URI / DMLC_PS_ROOT_PORT  coordinator host:port
   DMLC_NUM_WORKER                       total workers
@@ -51,7 +59,9 @@ def _worker_env(base: dict, uri: str, port: int, n: int, rank: int) -> dict:
 
 def launch_local(n: int, command: List[str], port: Optional[int] = None,
                  env: Optional[dict] = None) -> int:
-    """Spawn n workers on localhost; returns the first nonzero exit code."""
+    """Spawn n workers on localhost; returns the first nonzero exit code.
+    All share one environment: pin ``JAX_PLATFORMS=cpu`` in ``env`` on a
+    host with chips (see the module docstring)."""
     port = port or _free_port()
     base = dict(os.environ if env is None else env)
     procs = [subprocess.Popen(
