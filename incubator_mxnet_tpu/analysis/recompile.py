@@ -34,15 +34,16 @@ class RecompileWarning(UserWarning):
     """A hybridized block has compiled many distinct signatures."""
 
 
-def note_compile(block, signature) -> None:
+def note_compile(block, signature) -> bool:
     """Record one compile signature on ``block`` — the (static cache key,
     input shapes/dtypes) pair, since jax.jit re-traces per aval inside one
     cache entry. Dedupes; warns once when the distinct count crosses the
     threshold. Called by the CachedOp path on every compiled call, so the
-    steady-state cost is one set lookup (``signature`` must be hashable)."""
+    steady-state cost is one set lookup (``signature`` must be hashable).
+    True when the signature is new: the call about to run compiles."""
     seen = block.__dict__.setdefault("_compile_sigs", set())
     if signature in seen:
-        return
+        return False
     seen.add(signature)
     block.__dict__.setdefault("_compile_log", []).append(signature)
     n = len(seen)
@@ -63,6 +64,7 @@ def note_compile(block, signature) -> None:
             "value or input shape recompiles. Stabilize static kwargs and "
             "bucket input shapes (mx.analysis.recompile.cache_report(block) "
             "shows the signatures).", RecompileWarning, stacklevel=3)
+    return True
 
 
 def _blocks(block):

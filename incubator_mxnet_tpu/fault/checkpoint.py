@@ -285,29 +285,24 @@ def save_checkpoint(root: str, arrays: Dict[str, onp.ndarray],
     (``MXTPU_ELASTIC_COMMIT_TIMEOUT_S``) and a timeout names the missing
     process index instead of hanging.
 
-    Every successful save records one ``checkpoint.save`` profiler span,
-    a ``checkpoint.save`` telemetry event, and (when the goodput ledger
-    is on) a ``checkpoint`` attribution note — checkpointing is wall
-    time the training loop pays, so it must show up in the run's
-    goodput vector, not vanish into ``unattributed``.
+    The write runs inside one ``checkpoint.save`` profiler span (a
+    host that only writes its shard, or a save that raises, has paid
+    that time too); the committing host's successful save also emits a
+    ``checkpoint.save`` telemetry event and (when the goodput ledger is
+    on) a ``checkpoint`` attribution note — checkpointing is wall time
+    the training loop pays, so it must show up in the run's goodput
+    vector, not vanish into ``unattributed``.
     """
-    import time as _time
-    t_save0 = _time.perf_counter()
+    from .. import profiler as _prof
+    from ..telemetry import events as _tele
+    from ..telemetry import goodput as _goodput
     meta = dict(meta or {})
     from ..parallel.dist import world
     widx, wcount = world()
     idx = widx if process_index is None else int(process_index)
     count = wcount if process_count is None else int(process_count)
     final = os.path.join(root, _step_dirname(step))
-    if count > 1:
-        path = _save_multihost(root, arrays, meta, step=step,
-                               idx=idx, count=count,
-                               timeout_s=(_commit_timeout_s()
-                                          if commit_timeout_s is None
-                                          else commit_timeout_s))
-        if idx != 0:
-            return path
-    else:
+    if count <= 1:
         # SPMD election (the MX902 invariant): a lone process that still
         # carries a non-zero rank (pre-rendezvous launcher env) must not
         # race the writer it cannot coordinate with — the program does
@@ -315,33 +310,39 @@ def save_checkpoint(root: str, arrays: Dict[str, onp.ndarray],
         from ..parallel.dist import is_primary
         if not is_primary():
             return final
-        os.makedirs(root, exist_ok=True)
-        tmp = os.path.join(
-            root, f"{_TMP_PREFIX}{_step_dirname(step)}-{os.getpid()}")
-        if os.path.isdir(tmp):
-            shutil.rmtree(tmp)
-        os.makedirs(tmp)
-        try:
-            host, entries = _write_entries(arrays)
-            from ..ndarray.serialization import dmlc_save
-            dmlc_save(os.path.join(tmp, ARRAYS_FILE),
-                      list(host.values()), list(host.keys()))
-            inject.crash("checkpoint.arrays")  # died: arrays, no manifest
-            manifest = {"format": FORMAT_VERSION, "step": int(step),
-                        "meta": meta, "arrays": entries}
-            _write_json(os.path.join(tmp, MANIFEST_FILE), manifest)
-            _fsync_dir(tmp)
-            _finalize_rename(root, tmp, final)
-        except BaseException:
-            shutil.rmtree(tmp, ignore_errors=True)
-            raise
-    if keep is not None:
-        _prune(root, keep)
-    save_ms = (_time.perf_counter() - t_save0) * 1e3
-    from .. import profiler as _prof
-    from ..telemetry import events as _tele
-    from ..telemetry import goodput as _goodput
-    _prof.record_span("checkpoint.save", save_ms, t0=t_save0)
+    with _prof.Scope("checkpoint.save") as saved:
+        if count > 1:
+            path = _save_multihost(root, arrays, meta, step=step,
+                                   idx=idx, count=count,
+                                   timeout_s=(_commit_timeout_s()
+                                              if commit_timeout_s is None
+                                              else commit_timeout_s))
+            if idx != 0:
+                return path
+        else:
+            os.makedirs(root, exist_ok=True)
+            tmp = os.path.join(
+                root, f"{_TMP_PREFIX}{_step_dirname(step)}-{os.getpid()}")
+            if os.path.isdir(tmp):
+                shutil.rmtree(tmp)
+            os.makedirs(tmp)
+            try:
+                host, entries = _write_entries(arrays)
+                from ..ndarray.serialization import dmlc_save
+                dmlc_save(os.path.join(tmp, ARRAYS_FILE),
+                          list(host.values()), list(host.keys()))
+                inject.crash("checkpoint.arrays")  # died: arrays, no manifest
+                manifest = {"format": FORMAT_VERSION, "step": int(step),
+                            "meta": meta, "arrays": entries}
+                _write_json(os.path.join(tmp, MANIFEST_FILE), manifest)
+                _fsync_dir(tmp)
+                _finalize_rename(root, tmp, final)
+            except BaseException:
+                shutil.rmtree(tmp, ignore_errors=True)
+                raise
+        if keep is not None:
+            _prune(root, keep)
+    save_ms = saved.dur_ms
     _tele.emit("checkpoint.save", step=step, wall_ms=round(save_ms, 3),
                path=final, arrays=len(arrays), process_index=idx,
                process_count=count)

@@ -73,6 +73,10 @@ def all_finite(*trees) -> bool:
     if new:
         from ..telemetry import compile_log as _clog
         _clog.note("fault.guards.finite", sig)
+        # a new signature compiles inside the call: jax's account of it
+        # goes to this site (a seen one pays nothing for the bookkeeping)
+        with _clog.at("fault.guards.finite"):
+            return bool(_tree_finite(trees))
     return bool(_tree_finite(trees))
 
 

@@ -567,7 +567,7 @@ class HybridBlock(Block):
         # signature is a fresh XLA compile — the block-level cache key alone
         # undercounts because jax.jit re-traces per shape/dtype inside one
         # entry. mx.analysis warns past a threshold (MX201).
-        note_compile(self, (cache_key, tuple(self._last_sig[2])))
+        new_sig = note_compile(self, (cache_key, tuple(self._last_sig[2])))
 
         jit_fn = self._jit_cache[cache_key]
         info = self._cache_info[cache_key]
@@ -577,8 +577,16 @@ class HybridBlock(Block):
         def tape_fn(*vals):
             return jit_fn(key_val, *vals)
 
-        outs = dispatch_op(tape_fn, arr_args + list(params_data(params, ctx)),
-                           {}, ctx, name=f"cached_op_{self._name}")
+        op_args = arr_args + list(params_data(params, ctx))
+        op_name = f"cached_op_{self._name}"
+        if new_sig:
+            # this call compiles: jax's account of it goes to the site (a
+            # seen signature pays nothing for the bookkeeping)
+            from ..telemetry import compile_log
+            with compile_log.at("gluon.hybridize"):
+                outs = dispatch_op(tape_fn, op_args, {}, ctx, name=op_name)
+        else:
+            outs = dispatch_op(tape_fn, op_args, {}, ctx, name=op_name)
         if not isinstance(outs, (list, tuple)):
             outs = [outs]
         n_eff = len(info["effects"])

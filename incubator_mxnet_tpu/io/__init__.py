@@ -17,7 +17,6 @@ from __future__ import annotations
 import os
 import struct
 import threading
-import time
 import queue as _queue
 from collections import namedtuple
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
@@ -798,14 +797,13 @@ class PrefetchIter(DataIter):
             if self._exc is not None:
                 raise self._exc
             raise StopIteration
-        t0 = time.perf_counter()
-        b = self._queue.get()
-        wait_ms = (time.perf_counter() - t0) * 1e3
         # the blocked pop is the step's input starvation: an io.wait span
         # on the profiler timeline, the mxtpu_io_* metrics, and the
         # goodput ledger's input_wait bucket — all from the ONE timing
         from .. import profiler as _prof
-        _prof.record_span("io.wait", wait_ms)
+        with _prof.Scope("io.wait") as waited:
+            b = self._queue.get()
+        wait_ms = waited.dur_ms
         self._m_wait.observe(wait_ms)
         self._m_depth.set(self._queue.qsize())
         from ..telemetry import goodput as _goodput

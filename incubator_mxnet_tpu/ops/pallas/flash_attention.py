@@ -258,6 +258,7 @@ def _fwd(q, k, v, key_mask, causal, scale, window=None):
     kwargs = {} if interpret else {"compiler_params": _COMPILER_PARAMS}
     o, lse = pl.pallas_call(
         kern,
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -433,6 +434,7 @@ def _bwd(q, k, v, key_mask, causal, scale, o, lse, do, dlse=None,
         scale=scale, causal=causal, causal_off=Lk - Lq, window=window)
     dk, dv = pl.pallas_call(
         dkv_kern,
+        name="flash_bwd_dkv",
         grid=(BH, Lk // bk, Lq // bq),
         in_specs=dkv_specs,
         out_specs=[
@@ -469,6 +471,7 @@ def _bwd(q, k, v, key_mask, causal, scale, o, lse, do, dlse=None,
         scale=scale, causal=causal, causal_off=Lk - Lq, window=window)
     dq = pl.pallas_call(
         dq_kern,
+        name="flash_bwd_dq",
         grid=(BH, Lq // bq, Lk // bk),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),

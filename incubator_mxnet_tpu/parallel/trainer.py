@@ -45,7 +45,7 @@ from __future__ import annotations
 
 import os
 import re
-import time
+from contextlib import ExitStack as _ExitStack
 from contextlib import nullcontext as _nullcontext
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -667,7 +667,8 @@ class ShardedTrainer:
             warm_ctx = current_context()
             warm = [a if isinstance(a, NDArray) else NDArray(a, ctx=warm_ctx)
                     for a in batch[:n_data]]
-            self._init_state(warm, warm_ctx)
+            with _prof.Scope("trainer.init_state"):
+                self._init_state(warm, warm_ctx)
         vals = self.place(*batch)
         self._ensure_built(n_data, tuple(v.ndim for v in vals))
         self._refresh_scalars(self._t + 1)
@@ -752,68 +753,76 @@ class ShardedTrainer:
         from ..fault import inject as _inject
         from ..telemetry import compile_log as _clog
         from ..telemetry import events as _tele
-        t_step0 = time.perf_counter()
-        # elastic step-boundary hooks: poll() surfaces any host loss the
-        # lease watchdog detected since the last step (one lock-free list
-        # read when the pod is healthy — never I/O on the hot path), and
-        # note_step drives the seeded host_kill/host_stall chaos knobs
-        from . import elastic as _elastic
-        _elastic.poll()
-        _inject.note_step(self._t + 1)
-        if _inject.active() is not None:
-            # the poisoned batch belongs to the step about to run — bind
-            # its id so the chaos event and the guard verdict correlate
-            with _tele.step_scope(self._t + 1):
-                batch = self._chaos_batch(batch, n_data)
-        if self._params is None:
-            # Eager warmup runs wherever the parameters were initialized
-            # (current context), NOT on the mesh.
-            warm_ctx = current_context()
-            warm = [a if isinstance(a, NDArray) else NDArray(a, ctx=warm_ctx)
-                    for a in batch[:n_data]]
-            self._init_state(warm, warm_ctx)
-        t_place0 = time.perf_counter()
-        vals = self.place(*batch)
-        place_ms = (time.perf_counter() - t_place0) * 1e3
+        from ..telemetry import trace as _trace
+        from .mesh import active_mesh
+        attempted = self._t + 1      # event id even if a rollback resets _t
         # Dispatch: a configured mesh runs the ONE compiled pjit step
         # (explicit in/out PartitionSpecs, donated buffers) — the default
         # path. The per-parameter kvstore loop survives only behind the
         # MXTPU_KVSTORE_FALLBACK=1 opt-in (async-PS scenario).
         fallback = self.kv_fallback_active()
-        if not fallback:
-            # the jit entry's batch in_shardings are rank-pinned; a batch
-            # of NEW ranks rebuilds the entry (a fresh compile, noted in
-            # the ledger via its new signature — the same cost the
-            # re-trace paid before shardings were explicit)
-            self._ensure_built(n_data, tuple(v.ndim for v in vals))
-        if self._guard is not None:
-            self._maybe_snapshot()
-        self._t += 1
-        attempted = self._t          # event id even if a rollback resets _t
-        self._refresh_scalars(self._t)
-        # a new batch (shape, dtype) signature re-traces inside the jit
-        # entry — the classic silent recompile; the ledger makes it visible
-        sig = tuple((tuple(v.shape), str(v.dtype)) for v in vals)
-        new_sig = sig not in self._step_sigs
-        first_sig = not self._step_sigs
-        from .mesh import active_mesh
-        from ..telemetry import trace as _trace
-        wd = self._watchdog
         # one root span per step: the kvstore-fallback push/pull hops,
         # guard verdicts, chaos draws, and the profiler's step frame all
         # stitch under it — the training twin of the router's
-        # per-request tree (head sampling decides per step)
+        # per-request tree (head sampling decides per step). Inside it,
+        # one live "step" frame with its segments: in the profiler's ring
+        # (the raw material of profiler.step_report()'s host-gap
+        # attribution) and, under an XProf trace, in the host plane on
+        # the clock of the device's operations
         with _tele.step_scope(attempted), \
                 _trace.span("train.step", step=attempted,
                             path="kvstore_fallback" if fallback
-                            else "pjit"):
+                            else "pjit"), \
+                _ExitStack() as live:
+            frame = live.enter_context(_prof.Frame("step", step=attempted))
+            # XProf's step view groups host and device work by this number
+            live.enter_context(jax.profiler.StepTraceAnnotation(
+                "train", step_num=attempted))
+            # elastic step-boundary hooks: poll() surfaces any host loss
+            # the lease watchdog detected since the last step (one
+            # lock-free list read when the pod is healthy — never I/O on
+            # the hot path), and note_step drives the seeded
+            # host_kill/host_stall chaos knobs
+            from . import elastic as _elastic
+            _elastic.poll()
+            _inject.note_step(attempted)
+            if _inject.active() is not None:
+                # the poisoned batch belongs to the step about to run
+                batch = self._chaos_batch(batch, n_data)
+            if self._params is None:
+                # Eager warmup runs wherever the parameters were
+                # initialized (current context), NOT on the mesh.
+                warm_ctx = current_context()
+                warm = [a if isinstance(a, NDArray)
+                        else NDArray(a, ctx=warm_ctx)
+                        for a in batch[:n_data]]
+                with _prof.Scope("trainer.init_state"):
+                    self._init_state(warm, warm_ctx)
+            with _prof.Scope("step.place", step=attempted) as placed:
+                vals = self.place(*batch)
+            if not fallback:
+                # the jit entry's batch in_shardings are rank-pinned; a
+                # batch of NEW ranks rebuilds the entry (a fresh compile,
+                # noted in the ledger via its new signature — the same
+                # cost the re-trace paid before shardings were explicit)
+                self._ensure_built(n_data, tuple(v.ndim for v in vals))
+            if self._guard is not None:
+                self._maybe_snapshot()
+            self._t = attempted
+            self._refresh_scalars(self._t)
+            # a new batch (shape, dtype) signature re-traces inside the
+            # jit entry — the classic silent recompile; the ledger makes
+            # it visible
+            sig = tuple((tuple(v.shape), str(v.dtype)) for v in vals)
+            new_sig = sig not in self._step_sigs
+            first_sig = not self._step_sigs
+            wd = self._watchdog
             with wd.watch(step=self._t, block=self._block) if wd is not None \
                     else _nullcontext():
                 _inject.maybe_delay("slow_step")
                 # chaos leak site: retains device arrays so the memory
                 # ledger's leak watchdog is deterministically testable
                 _inject.maybe_leak("trainer.step")
-                t_disp0 = time.perf_counter()
                 self.last_step_graphs = 1       # the step executable
                 ok = None
                 # a NEW signature is about to trace: overlay the autotune
@@ -831,11 +840,16 @@ class ShardedTrainer:
                 # device sync below) writes ONE OOM flight bundle with
                 # the memory ledger + static peaks, then re-raises
                 from ..telemetry import memory as _memory
-                with _memory.oom_guard("trainer.step", step=attempted), \
-                        active_mesh(self._mesh), tune_ctx:
-                    # bound during (first-call) tracing so mesh-aware ops
-                    # lower to mesh collectives — e.g. attention → ring
-                    # over sp
+                with _prof.Scope("step.dispatch", step=attempted) as sent, \
+                        _memory.oom_guard("trainer.step", step=attempted), \
+                        active_mesh(self._mesh), tune_ctx, \
+                        _clog.at(self._retune_site if retuned_now
+                                 else "trainer.step"):
+                    # the mesh is bound during (first-call) tracing so
+                    # mesh-aware ops lower to mesh collectives — e.g.
+                    # attention → ring over sp; what jax traces, lowers
+                    # or compiles in here is this site's
+                    # (compile_log.phase_seconds)
                     stats_dev = None
                     num_cfg = self._numerics_cfg
                     num_on = (not fallback and num_cfg is not None
@@ -859,7 +873,7 @@ class ShardedTrainer:
                              self._opt_states, effects,
                              self._t_dev) = out
                 self.last_path = "kvstore_fallback" if fallback else "pjit"
-                dispatch_ms = (time.perf_counter() - t_disp0) * 1e3
+                dispatch_ms = sent.dur_ms
                 from ..telemetry import collective_ledger as _cledger
                 if new_sig:
                     self._step_sigs.add(sig)
@@ -889,8 +903,13 @@ class ShardedTrainer:
                 # sync — stats never add a host round trip of their own
                 read_stats = (num_on and stats_dev is not None
                               and (attempted - 1) % num_cfg.every == 0)
-                t_sync0 = time.perf_counter()
-                with _memory.oom_guard("trainer.step", step=attempted):
+                # the guard's loss/grad-norm device_get is the one point
+                # the host provably blocks on the device inside the step:
+                # a span of its own on guarded runs only
+                with _prof.Scope("step.device_wait", step=attempted) \
+                        if self._guard is not None \
+                        else _nullcontext() as synced, \
+                        _memory.oom_guard("trainer.step", step=attempted):
                     rolled_back = (self._guard is not None
                                    and self._apply_guard(
                                        loss, gnorm, ok,
@@ -904,10 +923,12 @@ class ShardedTrainer:
                         _numerics.record("trainer.step", attempted,
                                          jax.device_get(stats_dev),
                                          num_cfg)
-                sync_ms = (time.perf_counter() - t_sync0) * 1e3
-            wall_ms = (time.perf_counter() - t_step0) * 1e3
+            # the frame ends here, before the step's events go out: they
+            # and the spans come from one reading and cannot disagree
+            live.close()
+            wall_ms = frame.dur_ms
             fields = {"wall_ms": round(wall_ms, 3),
-                      "place_ms": round(place_ms, 3),
+                      "place_ms": round(placed.dur_ms, 3),
                       "dispatch_ms": round(dispatch_ms, 3),
                       "path": self.last_path,
                       "graphs": self.last_step_graphs,
@@ -917,7 +938,7 @@ class ShardedTrainer:
                 fields.update(loss=self.last_loss,
                               grad_norm=self.last_grad_norm,
                               rolled_back=rolled_back,
-                              device_wait_ms=round(sync_ms, 3))
+                              device_wait_ms=round(synced.dur_ms, 3))
             _tele.emit("train.step", step=attempted, **fields)
             # the goodput ledger folds the SAME timings into the run's
             # wall-clock attribution vector (compute/collective via the
@@ -927,27 +948,12 @@ class ShardedTrainer:
             if _goodput.enabled():
                 _goodput.note_step(
                     step=attempted, wall_ms=wall_ms,
-                    device_wait_ms=(sync_ms if self._guard is not None
-                                    else 0.0),
+                    device_wait_ms=(synced.dur_ms
+                                    if self._guard is not None else 0.0),
                     compile_ms=(dispatch_ms if (new_sig or retuned_now)
                                 else 0.0),
                     rolled_back=rolled_back,
                     rollback_to=(self._t if rolled_back else None))
-            # one "step" frame + its segments on the profiler timeline —
-            # the raw material of profiler.step_report()'s host-gap
-            # attribution (all from the timings measured above, so the
-            # event fields and the span trace can never disagree)
-            _prof.record_span("step.place", place_ms, parent="step",
-                              step=attempted, t0=t_place0)
-            _prof.record_span("step.dispatch", dispatch_ms, parent="step",
-                              step=attempted, t0=t_disp0)
-            if self._guard is not None:
-                # the guard's loss/grad-norm device_get is the one point
-                # the host provably blocks on the device inside the step
-                _prof.record_span("step.device_wait", sync_ms,
-                                  parent="step", step=attempted, t0=t_sync0)
-            _prof.record_span("step", wall_ms, kind="frame",
-                              step=attempted, t0=t_step0)
         self._m_steps.inc()
         self._m_step_ms.observe(wall_ms)
         if self._guard is not None and self.last_grad_norm is not None:

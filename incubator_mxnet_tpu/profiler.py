@@ -20,13 +20,18 @@ anchored to the wall clock once at import (``perf_counter`` + a fixed
 epoch), so nested spans provably nest on the merged chrome-trace timeline
 (``mx.telemetry.chrome_trace``) instead of drifting against each other.
 
-Runtime code that already measures its own phase timings (e.g.
-``parallel.ShardedTrainer.step``) publishes them with :func:`record_span`
-— same ring, same clock, explicit parent. :func:`step_report` then
-aggregates per-step frames into the host-gap attribution the whole-step-
-capture work (ROADMAP open item 2) is judged by: each step split into
-``place`` / ``dispatch`` / ``device_wait`` / ``python`` segments, plus the
-derived host-gap (everything the host spends not blocked on the device).
+``Scope`` / ``Task`` / ``Frame`` are the ONE way to make a span: each writes
+the ring record and a ``jax.profiler.TraceAnnotation``, so under an active
+XProf trace the span sits in the ``.xplane.pb`` host plane on the clock of
+the device's operations. Runtime code wraps its phases in them live
+(``parallel.ShardedTrainer.step`` opens ``Frame("step")`` round
+``step.place`` / ``step.dispatch`` / ``step.device_wait``) and reads the
+measured ``dur_ms`` back from the scope after exit, so its event fields
+and the span can never disagree. :func:`step_report` then aggregates
+per-step frames into the host-gap attribution the whole-step-capture work
+(ROADMAP open item 2) is judged by: each step split into ``place`` /
+``dispatch`` / ``device_wait`` / ``python`` segments, plus the derived
+host-gap (everything the host spends not blocked on the device).
 
 :func:`dumps` aggregates spans into a JSON document (count/total/mean/
 min/max/p50/p95/p99 per span name); :func:`dump` writes the merged
@@ -48,7 +53,7 @@ from .lockcheck import make_lock
 
 __all__ = ["set_config", "set_state", "pause", "resume", "dump", "dumps",
            "Scope", "Task", "Frame", "Marker", "scope", "span_records",
-           "reset_spans", "recent_spans", "record_span", "step_report",
+           "reset_spans", "recent_spans", "step_report",
            "SpanRecord"]
 
 _STATE = {"running": False, "dir": "profile_output", "aggregate": False,
@@ -126,26 +131,6 @@ def _append(rec: SpanRecord) -> None:
         if len(ent["samples"]) < _MAX_SAMPLES_PER_NAME:
             ent["samples"].append(rec.dur_ms)
         _RECENT.append(rec)
-
-
-def record_span(name: str, dur_ms: float, kind: str = "scope",
-                parent: Optional[str] = None, step: Optional[int] = None,
-                t0: Optional[float] = None, depth: Optional[int] = None
-                ) -> None:
-    """Publish one already-measured span into the recorder — the entry
-    point for runtime code that times its own phases (``ShardedTrainer``
-    publishes ``step.place``/``step.dispatch``/``step.device_wait`` under
-    the ``step`` frame this way). ``t0`` is the ``time.perf_counter()``
-    reading at the span's start (defaults to now − duration); ``step``
-    defaults to the telemetry step scope bound on this thread."""
-    if t0 is None:
-        t0 = time.perf_counter() - dur_ms / 1e3
-    if step is None:
-        step = _current_step()
-    if depth is None:
-        depth = 0 if parent is None else 1
-    _append(SpanRecord(name, kind, _EPOCH + t0, dur_ms, parent, depth,
-                       step, _trace_ids()))
 
 
 def recent_spans() -> List[SpanRecord]:
@@ -385,13 +370,16 @@ class Scope:
     """User annotation scope (reference: mx.profiler.Scope; NVTX parity).
     Entering pushes onto the per-thread scope stack; exiting records a
     named wall-time span carrying its parent scope and nesting depth, so
-    nested scopes nest — not interleave — on the merged trace timeline."""
+    nested scopes nest — not interleave — on the merged trace timeline.
+    ``dur_ms`` holds the measured duration after exit (None while open):
+    the caller's metrics and the span come from the one reading."""
 
     _kind = "scope"
 
     def __init__(self, name: str = "<unk>", step: Optional[int] = None):
         self._name = name
         self._step = step
+        self.dur_ms: Optional[float] = None
         self._ann = jax.profiler.TraceAnnotation(name)
         self._t0: Optional[float] = None
         self._tspan = None       # open trace.span manager, if sampled
@@ -435,10 +423,10 @@ class Scope:
             parent = st[i - 1]._name if i > 0 else None
             depth = i
             del st[i]
-        dur_ms = (time.perf_counter() - self._t0) * 1e3
+        self.dur_ms = (time.perf_counter() - self._t0) * 1e3
         step = self._step if self._step is not None else _current_step()
         _append(SpanRecord(self._name, self._kind, _EPOCH + self._t0,
-                           dur_ms, parent, depth, step, trace_ids))
+                           self.dur_ms, parent, depth, step, trace_ids))
         self._t0 = None
 
 
@@ -451,8 +439,9 @@ class Task(Scope):
 
     _kind = "task"
 
-    def __init__(self, name: str = "task", domain=None):
-        super().__init__(name)
+    def __init__(self, name: str = "task", domain=None,
+                 step: Optional[int] = None):
+        super().__init__(name, step=step)
 
     def start(self):
         self.__enter__()

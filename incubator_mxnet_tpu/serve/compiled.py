@@ -291,7 +291,9 @@ class CompiledModel:
         avals = [sds(self._key_data.shape, self._key_data.dtype)]
         avals += [sds(s, jnp.dtype(d)) for s, d in sig]
         avals += [sds(p.shape, p.dtype) for p in self._pvals]
-        with _autotune.applied(self.autotune_entry):
+        from ..telemetry import compile_log
+        with _autotune.applied(self.autotune_entry), \
+                compile_log.at("serve.compiled"):
             # the trace reads tunable env knobs (flash block sizes,
             # embed-grad path) — the cached winner overlays exactly this
             # scope; an explicitly user-set variable still wins
@@ -318,7 +320,6 @@ class CompiledModel:
             self.stats["warmup_compiles"] += 1
         # process-wide recompile ledger: a post-warmup entry here is the
         # "unbucketed shape reached the model" bug, assertable anywhere
-        from ..telemetry import compile_log
         compile_log.note("serve.compiled", sig,
                          wall_ms=(time.perf_counter() - t0) * 1e3,
                          warmup=not self._warmed)

@@ -296,7 +296,8 @@ class DecodeEngine:
                 # CompiledModel's donate="auto"
                 jit = self._jit_step if jax.default_backend() != "cpu" \
                     else jax.jit(self._flat_step)
-                self._exe = jit.lower(*self._step_avals()).compile()
+                with compile_log.at(DECODE_SITE):
+                    self._exe = jit.lower(*self._step_avals()).compile()
                 compile_log.note(
                     DECODE_SITE,
                     (("pool", tuple(self._pool_k.shape)),

@@ -19,18 +19,28 @@ steady-state contract the serve bench, the telemetry CI smoke job, and
 ``assert_zero_post_warmup()`` all enforce. Each note also publishes a
 ``compile`` event on the bus (with the current step/request correlation
 ids) and bumps ``mxtpu_compiles_total{phase=...}``.
+
+What a compile's wall time went on is asked of jax itself: one
+``jax.monitoring`` duration listener, registered at import, adds up per
+site the seconds jax reports for tracing, lowering, the backend compile
+and the persistent cache's read (:func:`phase_seconds`). The site is the
+innermost :func:`at` block open on the thread, which the ``note`` call
+sites put round their jit call; anything else is ``"other"``.
 """
 from __future__ import annotations
 
+import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
+
+import jax
 
 from ..lockcheck import make_lock
 
 __all__ = ["CompileRecord", "note", "mark_warmed", "is_warmed", "records",
            "summary", "post_warmup_compiles", "assert_zero_post_warmup",
-           "clear", "MAX_RECORDS"]
+           "at", "phase_seconds", "clear", "MAX_RECORDS"]
 
 #: ledger ring size — a recompile storm must not grow host memory unbounded
 MAX_RECORDS = 4096
@@ -66,6 +76,85 @@ _RECORDS: deque = deque(maxlen=MAX_RECORDS)
 _TOTALS = {"warmup": 0, "post_warmup": 0}
 _BY_SITE: Dict[str, Dict[str, int]] = {}
 _WARMED: set = set()
+
+
+#: jax's own account of a compile (jax 0.9.0 ``_src/dispatch.py``,
+#: ``_src/compiler.py``), by the key :func:`phase_seconds` reports it under
+_PHASE_OF = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval_s",
+}
+_PHASES: Dict[str, Dict[str, float]] = {}    # site -> seconds by phase
+#: ``.site``: the innermost open :func:`at` block; ``.ended``: this
+#: thread's reported events not yet inside a later one, ``(end, seconds)``
+_TLS = threading.local()
+#: a trace holds a thousand jits traced inside it, all of them pending
+#: until it ends; past this many the oldest is counted where it stands
+_MAX_PENDING = 16384
+
+
+def _zero_phases() -> Dict[str, float]:
+    return dict.fromkeys(_PHASE_OF.values(), 0.0) | {"events": 0}
+
+
+def _on_duration(event: str, duration: float, **_kw) -> None:
+    key = _PHASE_OF.get(event)
+    if key is None:
+        return
+    # jax reports an event as it ends, and events nest (a jit traced
+    # inside a trace, the cache read inside the backend compile): what
+    # ended after this one began lies inside it and has been counted, so
+    # each event adds its own time only and the phases sum to wall time
+    now = time.monotonic()
+    ended = _TLS.__dict__.setdefault("ended", deque(maxlen=_MAX_PENDING))
+    inner = 0.0
+    while ended and ended[-1][0] > now - duration:
+        inner += ended.pop()[1]
+    ended.append((now, duration))
+    where = getattr(_TLS, "site", None) or "other"
+    with _LOCK:
+        ent = _PHASES.setdefault(where, _zero_phases())
+        ent[key] += max(duration - inner, 0.0)
+        ent["events"] += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+class at:
+    """Attribute what jax traces, lowers and compiles on this thread inside
+    the block to the site ``name`` (the innermost block wins)."""
+
+    __slots__ = ("_name", "_outer")
+
+    def __init__(self, name: str):
+        self._name = name
+
+    def __enter__(self):
+        self._outer = getattr(_TLS, "site", None)
+        _TLS.site = self._name
+
+    def __exit__(self, *exc):
+        _TLS.site = self._outer
+
+
+def phase_seconds(site: Optional[str] = None) -> Dict[str, float]:
+    """Seconds jax reported since the last :func:`clear`, at one site or
+    (``None``) at all of them: ``{"trace_s", "lower_s",
+    "backend_compile_s", "cache_retrieval_s", "events"}``. Each event
+    counts its own time, less the events inside it (the jits traced
+    inside a trace; the cache read inside the backend compile, so warm
+    ``backend_compile_s`` is the key's hashing and the executable's
+    loading): the four add up to the wall time jax spent compiling."""
+    out = _zero_phases()
+    with _LOCK:
+        for ent in (_PHASES.values() if site is None
+                    else [_PHASES.get(site, {})]):
+            for k, v in ent.items():
+                out[k] += v
+    return out
 
 
 def mark_warmed(site: str) -> None:
@@ -128,6 +217,7 @@ def summary() -> Dict:
                 "warmup": _TOTALS["warmup"],
                 "post_warmup": _TOTALS["post_warmup"],
                 "by_site": {k: dict(v) for k, v in _BY_SITE.items()},
+                "phase_seconds": {k: dict(v) for k, v in _PHASES.items()},
                 "recent": recent}
 
 
@@ -159,3 +249,4 @@ def clear() -> None:
         _TOTALS["warmup"] = _TOTALS["post_warmup"] = 0
         _BY_SITE.clear()
         _WARMED.clear()
+        _PHASES.clear()
