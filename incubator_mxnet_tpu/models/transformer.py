@@ -145,9 +145,19 @@ class TransformerEncoderCell(HybridBlock):
                 weight_initializer=weight_initializer)
             self.ln2 = nn.LayerNorm(epsilon=layer_norm_eps, prefix="ln2_")
 
+    @staticmethod
+    def _add_norm(ln, x, y):
+        # the residual sum enters the norm unrounded: added in float32 (XLA
+        # fuses the casts and the add into the norm's own fusion, no HBM
+        # round trip), the result back in the stream's dtype. A bf16 sum
+        # rounded before the statistics costs a quarter more error over 12
+        # layers (PERF.md, PR 26). A float32 stream is returned as it is.
+        s = x.astype("float32", copy=False) + y.astype("float32", copy=False)
+        return ln(s).astype(x.dtype, copy=False)
+
     def hybrid_forward(self, F, x, mask=None):
-        x = self.ln1(x + self.attention(x, None, mask))
-        x = self.ln2(x + self.ffn(x))
+        x = self._add_norm(self.ln1, x, self.attention(x, None, mask))
+        x = self._add_norm(self.ln2, x, self.ffn(x))
         return x
 
 

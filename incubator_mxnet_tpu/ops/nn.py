@@ -329,16 +329,19 @@ def batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-5, momentum=0.
     output_mean_var=Field(bool, False, "Also return mean/var."),
 ))
 def layer_norm(data, gamma, beta, axis=-1, eps=1e-5, output_mean_var=False):
-    # statistics in fp32 regardless of activation dtype (bf16 mean/var
-    # loses ~3 decimal digits; the reference computes fp32 throughout and
-    # XLA fuses the casts into the same kernel)
+    # statistics, scale and shift in fp32 regardless of activation dtype
+    # (bf16 mean/var loses ~3 decimal digits; the reference computes fp32
+    # throughout and XLA fuses the casts into the same kernel), then ONE
+    # cast back: the layer keeps gamma/beta fp32 in a bf16 net, and a
+    # bf16 * f32 product would hand every later op a float32 activation
     x32 = data.astype(jnp.float32)
     m = jnp.mean(x32, axis=axis, keepdims=True)
     v = jnp.var(x32, axis=axis, keepdims=True)
-    out = ((x32 - m) * lax.rsqrt(v + eps)).astype(data.dtype)
     shape = [1] * data.ndim
     shape[axis] = data.shape[axis]
-    out = out * gamma.reshape(shape) + beta.reshape(shape)
+    out = ((x32 - m) * lax.rsqrt(v + eps)
+           * gamma.reshape(shape).astype(jnp.float32)
+           + beta.reshape(shape).astype(jnp.float32)).astype(data.dtype)
     if output_mean_var:
         return out, jnp.squeeze(m, axis), jnp.squeeze(v, axis)
     return out
@@ -383,10 +386,11 @@ def l2_normalization(data, eps=1e-10, mode="instance", **_):
 @register_op("RMSNorm", aliases=("rms_norm",))
 def rms_norm(data, gamma, axis=-1, eps=1e-6, **_):
     """TPU-era extension (not in reference): RMSNorm for LLaMA-family models.
-    Statistics in fp32 (see layer_norm)."""
+    Statistics and scale in fp32, one cast back (see layer_norm)."""
     x32 = data.astype(jnp.float32)
     v = jnp.mean(jnp.square(x32), axis=axis, keepdims=True)
-    return (x32 * lax.rsqrt(v + eps)).astype(data.dtype) * gamma
+    return (x32 * lax.rsqrt(v + eps)
+            * gamma.astype(jnp.float32)).astype(data.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -307,7 +307,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
         s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if mask_ref is not None:
-            s = jnp.where(mask_ref[0, 0].astype(bool)[None, :], s, _NEG)
+            # broadcast, then convert, as the forward does: converting the
+            # (bk,) row to bool first lowers to a relayout of every tile
+            # that tripled both backward kernels on bf16 operands (v5e, PR
+            # 26: dkv 2.47 -> 0.78 ms, dq 1.58 -> 0.51 ms a call at
+            # (384, 512, 64); gradients bit-identical)
+            s = jnp.where(mask_ref[0, 0][None, :].astype(bool), s, _NEG)
         if causal:
             rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
             cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + jk * bk
@@ -365,7 +370,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
         s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if mask_ref is not None:
-            s = jnp.where(mask_ref[0, 0].astype(bool)[None, :], s, _NEG)
+            s = jnp.where(mask_ref[0, 0][None, :].astype(bool), s, _NEG)
         if causal:
             rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
             cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + jk * bk

@@ -1,4 +1,6 @@
 """Operator numerics vs numpy golden (reference: tests/python/unittest/test_operator.py)."""
+import jax
+import jax.numpy as jnp
 import numpy as onp
 import pytest
 
@@ -241,6 +243,77 @@ def test_batchnorm_layernorm():
     ln = nd.LayerNorm(nd.array(x2), nd.array(g2), nd.array(b2), axis=-1)
     ref2 = (x2 - x2.mean(-1, keepdims=True)) / onp.sqrt(x2.var(-1, keepdims=True) + 1e-5)
     assert_almost_equal(ln, ref2, rtol=1e-4, atol=1e-4)
+
+
+def _layer_norm_formerly(x, g, b, eps=1e-5):
+    """``ops.nn.layer_norm`` as it stood before PR 26: the normalised value
+    cast to the input dtype, then scaled and shifted in whatever dtype the
+    product promotes to. Kept as the float32 reference."""
+    x32 = x.astype(jnp.float32)
+    m = jnp.mean(x32, axis=-1, keepdims=True)
+    v = jnp.var(x32, axis=-1, keepdims=True)
+    out = ((x32 - m) * jax.lax.rsqrt(v + eps)).astype(x.dtype)
+    return out * g.reshape(1, 1, -1) + b.reshape(1, 1, -1), m[..., 0], v[..., 0]
+
+
+def _rms_norm_formerly(x, g, b, eps=1e-6):
+    x32 = x.astype(jnp.float32)
+    v = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(v + eps)).astype(x.dtype) * g, None, None
+
+
+_NORMS = {
+    "layer_norm": (lambda x, g, b, **kw: nd.LayerNorm(x, g, b, axis=-1, **kw),
+                   _layer_norm_formerly),
+    "rms_norm": (lambda x, g, b, **kw: nd.RMSNorm(x, g, axis=-1),
+                 _rms_norm_formerly),
+}
+
+
+@pytest.mark.parametrize("norm,case", [
+    (n, c) for n in sorted(_NORMS)
+    for c in ("bf16_stays_bf16", "bf16_parameters", "float32_bit_identical",
+              "statistics_fp32")
+    if (n, c) != ("rms_norm", "statistics_fp32")])   # it returns none
+def test_norm_output_dtype_follows_input(norm, case):
+    """A norm's output has its input's dtype whatever its parameters' dtype
+    (the layers keep gamma/beta float32 in a bf16 net): statistics, scale
+    and shift in float32, one cast at the end."""
+    op, formerly = _NORMS[norm]
+    rng = onp.random.RandomState(7)
+    x = rng.normal(size=(2, 5, 64)).astype("float32") * 3 + 1
+    g = nd.array(rng.uniform(0.5, 1.5, 64).astype("float32"))
+    b = nd.array(rng.normal(size=64).astype("float32"))
+    if case == "bf16_stays_bf16":
+        xb = nd.array(x).astype("bfloat16")
+        out = op(xb, g, b)
+        assert out.dtype == jnp.bfloat16
+        # the all-float32 result on the same (bf16-valued) input, rounded once
+        want = op(xb.astype("float32"), g, b).asnumpy()
+        got = out.astype("float32").asnumpy()
+        assert onp.all(onp.abs(got - want) <= 2.0 ** -8 * onp.abs(want))
+    elif case == "bf16_parameters":
+        # parameters of the data's own dtype (a net cast whole) change nothing
+        gb, bb = g.astype("bfloat16"), b.astype("bfloat16")
+        xb = nd.array(x).astype("bfloat16")
+        assert op(xb, gb, bb).dtype == jnp.bfloat16
+        out = op(nd.array(x), gb, bb)
+        assert out.dtype == onp.float32
+        want, _, _ = formerly(jnp.asarray(x), gb._data, bb._data)
+        onp.testing.assert_array_equal(out.asnumpy(), onp.asarray(want))
+    elif case == "float32_bit_identical":
+        out = op(nd.array(x), g, b)
+        assert out.dtype == onp.float32
+        want, _, _ = formerly(jnp.asarray(x), g._data, b._data)
+        onp.testing.assert_array_equal(out.asnumpy(), onp.asarray(want))
+    else:
+        xb = nd.array(x).astype("bfloat16")
+        out, m, v = op(xb, g, b, output_mean_var=True)
+        _, want_m, want_v = formerly(xb._data, g._data, b._data)
+        assert out.dtype == jnp.bfloat16
+        assert m.dtype == onp.float32 and v.dtype == onp.float32
+        onp.testing.assert_array_equal(m.asnumpy(), onp.asarray(want_m))
+        onp.testing.assert_array_equal(v.asnumpy(), onp.asarray(want_v))
 
 
 def test_sequence_ops():
