@@ -24,6 +24,8 @@ from .transformer import (  # noqa: F401
 from .bert import (  # noqa: F401
     BERTModel, BERTEncoder, bert_sharding_rules, get_bert, bert_pretrain_loss,
 )
+from . import afmoe  # noqa: F401
+from .afmoe import AfmoeModel, get_afmoe, afmoe_lm_loss  # noqa: F401
 
 #: Serving axis specs per model family — the ``input_axes``/``pad_values``
 #: a ``serve.CompiledModel``/``ModelRegistry.load`` needs to bucket each

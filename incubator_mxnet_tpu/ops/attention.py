@@ -55,7 +55,8 @@ def _maybe_ring(query, key, value, mask, causal, scale):
         return None
     if not isinstance(query, jax.core.Tracer):
         return None
-    if query.ndim != 4 or key.shape != value.shape:
+    if query.ndim != 4 or key.shape != value.shape \
+            or key.shape[1] != query.shape[1]:      # grouped K/V: no ring path
         return None
     B, H, Lq, D = query.shape
     Lk = key.shape[2]
@@ -118,7 +119,10 @@ def _flash_on_mesh(query, key, value, mask, causal, scale, window):
     B, H, Lq, _ = query.shape
     dp, tp = mesh.shape.get("dp", 1), mesh.shape.get("tp", 1)
     bspec = "dp" if dp > 1 and B % dp == 0 else None
-    hspec = "tp" if tp > 1 and H % tp == 0 else None
+    # heads split over tp only where the K/V heads divide too (grouped K/V:
+    # a shard then holds whole groups)
+    hspec = "tp" if tp > 1 and H % tp == 0 and key.shape[1] % tp == 0 \
+        else None
     spec = P(bspec, hspec, None, None)
     if mask is None:
         return shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec),
@@ -137,7 +141,9 @@ def dot_product_attention(query, key, value, mask=None, causal=False,
                           scale=None, impl="auto", window=None, **_):
     """Fused scaled-dot-product attention.
 
-    Shapes: ``query (B, H, Lq, D)``, ``key/value (B, H, Lk, D)``,
+    Shapes: ``query (B, H, Lq, D)``, ``key/value (B, Hkv, Lk, D)`` with
+    ``H`` a multiple of ``Hkv`` (query head ``h`` reads K/V head
+    ``h // (H // Hkv)``; ``Hkv = H`` is plain multi-head attention),
     ``mask`` broadcastable to ``(B, H, Lq, Lk)`` (1 = attend). Returns
     ``(B, H, Lq, D)``.
 
@@ -179,6 +185,12 @@ def dot_product_attention(query, key, value, mask=None, causal=False,
             return _flash_on_mesh(query, key, value, mask, causal, scale,
                                   window)
     acc = jnp.float32
+    if key.shape[1] != query.shape[1]:
+        # grouped K/V heads: this dense path repeats them (the flash kernel
+        # reads K/V head h // group through its block index instead)
+        group = query.shape[1] // key.shape[1]
+        key = jnp.repeat(key, group, axis=1)
+        value = jnp.repeat(value, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", query, key,
                    preferred_element_type=acc) * scale
     if mask is not None:

@@ -27,6 +27,12 @@ axis (``parallel/ring.py``), which calls back into this kernel's ``_fwd``
 per K/V hop and merges the per-hop (o, lse) pairs; ``dot_product_attention``
 routes there automatically when the active mesh has sp>1.
 
+Grouped-query attention: ``k``/``v`` may carry fewer heads than ``q``
+(``H = Hkv * group``); query head ``h`` reads K/V head ``h // group`` through
+the block index, nothing is repeated in HBM, and the dkv kernel adds up the
+group's query heads. Plain multi-head attention is group 1 and compiles to
+the programs it always did.
+
 Masking: ``causal`` and/or a key-padding mask of shape (B, Lk) (1 = valid).
 The generic (B, H, Lq, Lk) mask case falls back to the XLA path in
 ``ops/attention.py`` — loading an L² mask would defeat the point.
@@ -66,7 +72,7 @@ def flash_supported(q, k, v, mask=None) -> bool:
         return False
     if _interpret_for(q):
         return False
-    if q.ndim != 4 or k.shape != v.shape:
+    if q.ndim != 4 or k.shape != v.shape or _kv_group(q, k) is None:
         return False
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
@@ -77,6 +83,17 @@ def flash_supported(q, k, v, mask=None) -> bool:
     if mask is not None and _as_key_mask(mask, B, H, Lq, Lk) is None:
         return False
     return True
+
+
+def _kv_group(q, k):
+    """Query heads per K/V head (grouped-query attention), or None where
+    the shapes are no such grouping: ``q (B, H, Lq, D)`` against ``k, v
+    (B, Hkv, Lk, D)`` with ``H = Hkv * group``; query head ``h`` reads K/V
+    head ``h // group``. Plain multi-head attention is group 1."""
+    if k.ndim != 4 or k.shape[0] != q.shape[0] or k.shape[3] != q.shape[3]:
+        return None
+    H, Hkv = q.shape[1], k.shape[1]
+    return H // Hkv if Hkv and H % Hkv == 0 else None
 
 
 def _auto_block(length: int) -> int:
@@ -164,6 +181,31 @@ def _band(rows, cols, causal_off, window):
     return live
 
 
+def _live_k(i, bq, bk, nk, causal_off, window):
+    """``j -> j`` held inside the k-blocks that q-block ``i`` can see: a dead
+    tile then names the block of the nearest live one, and Pallas, which
+    copies a block only when its index changes, fetches nothing for it. The
+    tile skipping saves the arithmetic; this saves the HBM traffic."""
+    hi = jnp.clip((i * bq + bq - 1 + causal_off) // bk, 0, nk - 1)
+    lo = 0 if window is None else jnp.clip(
+        (i * bq + causal_off - window + 1) // bk, 0, nk - 1)
+    return lambda j: jnp.clip(j, lo, hi)
+
+
+def _live_q(j, bq, bk, nq, causal_off, window):
+    """The same for the q-blocks that can see k-block ``j`` (dkv kernel)."""
+    lo = jnp.clip(-((bq - 1 + causal_off - j * bk) // bq), 0, nq - 1)
+    hi = nq - 1 if window is None else jnp.clip(
+        (j * bk + bk - 2 - causal_off + window) // bq, 0, nq - 1)
+    return lambda i: jnp.clip(i, lo, hi)
+
+
+def _kernel_name(base: str, window) -> str:
+    """Windowed calls are named apart, so a trace separates a model's
+    sliding layers from its full ones."""
+    return base if window is None else base + "_win"
+
+
 # ---------------------------------------------------------------------------
 # forward: grid (B·H, nq, nk) — K/V streamed block-by-block, state in scratch
 # ---------------------------------------------------------------------------
@@ -233,23 +275,34 @@ def _scratch(bq, d):
 def _fwd(q, k, v, key_mask, causal, scale, window=None):
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
+    G = _kv_group(q, k)                 # query heads per K/V head
     bq, bk = _bq(Lq), _bk(Lk)
     BH = B * H
+    nk = Lk // bk
     q3 = q.reshape(BH, Lq, D)
-    k3 = k.reshape(BH, Lk, D)
-    v3 = v.reshape(BH, Lk, D)
-    grid = (BH, Lq // bq, Lk // bk)
+    k3 = k.reshape(BH // G, Lk, D)
+    v3 = v.reshape(BH // G, Lk, D)
+    grid = (BH, Lq // bq, nk)
+    if causal:      # dead tiles fetch nothing (see _live_k)
+        def jj(i, j):
+            return _live_k(i, bq, bk, nk, Lk - Lq, window)(j)
+    else:
+        def jj(i, j):
+            return j
+    kv_spec = pl.BlockSpec(
+        (1, bk, D), (lambda b, i, j: (b, jj(i, j), 0)) if G == 1 else
+        (lambda b, i, j: (b // G, jj(i, j), 0)), memory_space=_VMEM)
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0), memory_space=_VMEM),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0), memory_space=_VMEM),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0), memory_space=_VMEM),
+        kv_spec, kv_spec,
     ]
     args = [q3, k3, v3]
     if key_mask is not None:
         # (B, 1, Lk): TPU block shapes need the trailing two dims to be
         # tile-divisible or whole, so the mask rides with a singleton row.
         in_specs.append(pl.BlockSpec(
-            (1, 1, bk), lambda b, i, j: (b // H, 0, j), memory_space=_VMEM))
+            (1, 1, bk), lambda b, i, j: (b // H, 0, jj(i, j)),
+            memory_space=_VMEM))
         args.append(key_mask.astype(jnp.int32).reshape(key_mask.shape[0], 1, Lk))
     kern = functools.partial(
         _fwd_kernel if key_mask is not None else _fwd_kernel_nomask,
@@ -258,7 +311,7 @@ def _fwd(q, k, v, key_mask, causal, scale, window=None):
     kwargs = {} if interpret else {"compiler_params": _COMPILER_PARAMS}
     o, lse = pl.pallas_call(
         kern,
-        name="flash_fwd",
+        name=_kernel_name("flash_fwd", window),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -285,14 +338,17 @@ def _fwd(q, k, v, key_mask, causal, scale, window=None):
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                    causal_off, window=None):
+                    causal_off, window=None, group=1):
     bk, d = k_ref.shape[1], k_ref.shape[2]
     bq = q_ref.shape[1]
     jk = pl.program_id(1)
-    iq = pl.program_id(2)
-    nq = pl.num_programs(2)
+    # the streamed dimension runs over the q-blocks of every query head of
+    # this K/V head's group, one head after another: dk and dv add them up
+    step = pl.program_id(2)
+    last = pl.num_programs(2) - 1
+    iq = step if group == 1 else step % (pl.num_programs(2) // group)
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -336,7 +392,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
     else:
         _step()
 
-    @pl.when(iq == nq - 1)
+    @pl.when(step == last)
     def _finish():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -403,8 +459,10 @@ def _bwd(q, k, v, key_mask, causal, scale, o, lse, do, dlse=None,
          window=None):
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
+    G = _kv_group(q, k)                 # query heads per K/V head
     bq, bk = _bq(Lq), _bk(Lk)
-    BH = B * H
+    BH, BHkv = B * H, B * H // G
+    nq, nk = Lq // bq, Lk // bk
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     if dlse is not None:
         # The lse output's cotangent enters the score gradient as
@@ -412,45 +470,59 @@ def _bwd(q, k, v, key_mask, causal, scale, o, lse, do, dlse=None,
         # backward kernels serve the (o, lse) block-attention entry used by
         # ring attention.
         delta = delta - dlse.astype(jnp.float32)
-    q3, k3, v3 = (x.reshape(BH, -1, D) for x in (q, k, v))
+    q3 = q.reshape(BH, Lq, D)
+    k3, v3 = k.reshape(BHkv, Lk, D), v.reshape(BHkv, Lk, D)
     do3 = do.reshape(BH, Lq, D)
     lse3 = lse.reshape(BH, 1, Lq)
     delta3 = delta.reshape(BH, 1, Lq)
     interpret = _interpret_for(q3)
     kwargs = {} if interpret else {"compiler_params": _COMPILER_PARAMS}
-
-    # ---- dk/dv: fixed k-block (parallel), stream q-blocks (arbitrary)
-    dkv_specs = [
-        pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0), memory_space=_VMEM),
-        pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0), memory_space=_VMEM),
-        pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0), memory_space=_VMEM),
-        pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0), memory_space=_VMEM),
-        pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i), memory_space=_VMEM),
-        pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i), memory_space=_VMEM),
-    ]
+    off = Lk - Lq
     args = [q3, k3, v3, do3, lse3, delta3]
     if key_mask is not None:
+        args.append(key_mask.astype(jnp.int32).reshape(-1, 1, Lk))
+
+    # ---- dk/dv: fixed k-block (parallel), stream q-blocks (arbitrary);
+    # grid over K/V heads, the stream covering the group's query heads
+    if G == 1:
+        def qh(b, t):                   # the query head of step t
+            return b
+    else:
+        def qh(b, t):
+            return b * G + t // nq
+    if causal:      # dead tiles fetch nothing (see _live_k)
+        def ii(j, t):
+            return _live_q(j, bq, bk, nq, off, window)(t if G == 1 else t % nq)
+    elif G == 1:
+        def ii(j, t):
+            return t
+    else:
+        def ii(j, t):
+            return t % nq
+    q_spec = pl.BlockSpec((1, bq, D), lambda b, j, t: (qh(b, t), ii(j, t), 0),
+                          memory_space=_VMEM)
+    row_spec = pl.BlockSpec((1, 1, bq), lambda b, j, t: (qh(b, t), 0, ii(j, t)),
+                            memory_space=_VMEM)
+    kv_spec = pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0),
+                           memory_space=_VMEM)
+    dkv_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+    if key_mask is not None:
         dkv_specs.append(pl.BlockSpec((1, 1, bk),
-                                      lambda b, j, i: (b // H, 0, j),
+                                      lambda b, j, t: (b // (H // G), 0, j),
                                       memory_space=_VMEM))
-        args = args + [key_mask.astype(jnp.int32).reshape(-1, 1, Lk)]
     dkv_kern = functools.partial(
         _bwd_dkv_kernel if key_mask is not None else _bwd_dkv_kernel_nomask,
-        scale=scale, causal=causal, causal_off=Lk - Lq, window=window)
+        scale=scale, causal=causal, causal_off=off, window=window,
+        **({} if G == 1 else {"group": G}))
     dk, dv = pl.pallas_call(
         dkv_kern,
-        name="flash_bwd_dkv",
-        grid=(BH, Lk // bk, Lq // bq),
+        name=_kernel_name("flash_bwd_dkv", window),
+        grid=(BHkv, nk, G * nq),
         in_specs=dkv_specs,
-        out_specs=[
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0),
-                         memory_space=_VMEM),
-            pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0),
-                         memory_space=_VMEM),
-        ],
+        out_specs=[kv_spec, kv_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Lk, D), k.dtype),
-            jax.ShapeDtypeStruct((BH, Lk, D), v.dtype),
+            jax.ShapeDtypeStruct((BHkv, Lk, D), k.dtype),
+            jax.ShapeDtypeStruct((BHkv, Lk, D), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
@@ -459,35 +531,40 @@ def _bwd(q, k, v, key_mask, causal, scale, o, lse, do, dlse=None,
     )(*args)
 
     # ---- dq: fixed q-block (parallel), stream k-blocks (arbitrary)
-    dq_specs = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0), memory_space=_VMEM),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0), memory_space=_VMEM),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0), memory_space=_VMEM),
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0), memory_space=_VMEM),
-        pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i), memory_space=_VMEM),
-        pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i), memory_space=_VMEM),
-    ]
+    if causal:
+        def jj(i, j):
+            return _live_k(i, bq, bk, nk, off, window)(j)
+    else:
+        def jj(i, j):
+            return j
+    q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
+                          memory_space=_VMEM)
+    row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i),
+                            memory_space=_VMEM)
+    kv_spec = pl.BlockSpec(
+        (1, bk, D), (lambda b, i, j: (b, jj(i, j), 0)) if G == 1 else
+        (lambda b, i, j: (b // G, jj(i, j), 0)), memory_space=_VMEM)
+    dq_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
     if key_mask is not None:
         dq_specs.append(pl.BlockSpec((1, 1, bk),
-                                     lambda b, i, j: (b // H, 0, j),
+                                     lambda b, i, j: (b // H, 0, jj(i, j)),
                                      memory_space=_VMEM))
     dq_kern = functools.partial(
         _bwd_dq_kernel if key_mask is not None else _bwd_dq_kernel_nomask,
-        scale=scale, causal=causal, causal_off=Lk - Lq, window=window)
+        scale=scale, causal=causal, causal_off=off, window=window)
     dq = pl.pallas_call(
         dq_kern,
-        name="flash_bwd_dq",
-        grid=(BH, Lq // bq, Lk // bk),
+        name=_kernel_name("flash_bwd_dq", window),
+        grid=(BH, nq, nk),
         in_specs=dq_specs,
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
-                               memory_space=_VMEM),
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
         **kwargs,
     )(*args)
-    return (dq.reshape(B, H, Lq, D), dk.reshape(B, H, Lk, D),
-            dv.reshape(B, H, Lk, D))
+    return (dq.reshape(B, H, Lq, D), dk.reshape(B, H // G, Lk, D),
+            dv.reshape(B, H // G, Lk, D))
 
 
 # ---------------------------------------------------------------------------

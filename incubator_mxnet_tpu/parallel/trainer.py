@@ -1104,6 +1104,17 @@ class ShardedTrainer:
         for p, v in zip(self._params, self._param_vals):
             p.set_data(NDArray(jax.device_get(v), ctx=self._ctx))
 
+    def release_block(self) -> None:
+        """Free the gluon Parameters' own device arrays. From its first step
+        on the trainer's copies are the weights, and the block's are a
+        second set the device holds for nothing: 1.4 GB of bf16 at 705 M
+        parameters, where the step's temporaries need the room.
+        ``sync_to_block()`` gives the block current values back."""
+        for p in self._params or ():
+            for arr in (p._data or {}).values():
+                if isinstance(arr._data, jax.Array) and not arr._data.is_deleted():
+                    arr._data.delete()
+
     def save_states(self, fname: str, backend: str = "pickle") -> None:
         """Checkpoint parameters + optimizer state + step counter.
 

@@ -25,6 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from incubator_mxnet_tpu import parallel
 from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
+from incubator_mxnet_tpu.ops.pallas import moe_gmm
 from incubator_mxnet_tpu.parallel import collectives, ring
 
 
@@ -48,6 +49,7 @@ def for_the_chip(monkeypatch):
     written for a described chip cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache as cc
     monkeypatch.setattr(fa, "_interpret_for", lambda x: False)
+    monkeypatch.setattr(moe_gmm, "_interpret_for", lambda x: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -113,6 +115,55 @@ def test_flash_fwd_bwd_compiles_for_v5e(topo, for_the_chip, shape, dtype,
                          text, re.M), kernel
 
 
+# (B, H, Hkv, L, D), window: Trinity-Mini's heads, 32 query heads over 4 K/V
+# heads at D=128, key-masked and causal, a sliding layer and a full one
+@pytest.mark.parametrize("shape,window", [
+    pytest.param((1, 32, 4, 4096, 128), 2048, id="gqa8_window2048_L4096"),
+    pytest.param((1, 32, 4, 2048, 128), None, id="gqa8_causal_L2048"),
+])
+def test_flash_grouped_heads_compile_for_v5e(topo, for_the_chip, shape, window):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    B, H, Hkv, L, D = shape
+    q = jax.ShapeDtypeStruct((B, H, L, D), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, Hkv, L, D), jnp.bfloat16, sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((B, L), jnp.bool_, sharding=one_chip)
+
+    def attn(q, k, v, mask):
+        return fa.flash_attention(q, k, v, mask=mask, causal=True, window=window)
+
+    text = _fwd_bwd(attn).lower(q, kv, kv, mask, q).compile().as_text()
+    # a windowed call names its kernels apart; dk and dv come out at the K/V
+    # heads' count (summed over each group inside the kernel)
+    for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        name = fa._kernel_name(kernel, window)
+        assert re.search(rf'^\s*%\S*{name}\S* = .*custom_call_target="tpu_custom_call"',
+                         text, re.M), name
+    assert re.search(rf'flash_bwd_dkv\S* = \(bf16\[{B * Hkv},{L},{D}\]', text), "dk, dv per K/V head"
+
+
+def test_grouped_matmul_compiles_for_v5e(topo, for_the_chip):
+    """The routed experts' kernels at Trinity-Mini's widths: 16 experts of
+    2,048 x 2,048 (gate and up stacked) over a buffer of 24 tiles, forward,
+    rows' gradient and weights' gradient, each under its name."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    tm = moe_gmm.TILE_ROWS
+    R, K, N, G = 24 * tm, 2048, 2048, 16
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def f(lhs, rhs, tile_group, n_tiles, dout):
+        out, vjp = jax.vjp(lambda a, w: moe_gmm.grouped_matmul(
+            a, w, tile_group, n_tiles, impl="pallas"), lhs, rhs)
+        return (out,) + vjp(dout)
+
+    text = jax.jit(f).lower(
+        sds((R, K), jnp.bfloat16), sds((G, N, K), jnp.bfloat16), sds((R // tm,), jnp.int32),
+        sds((1,), jnp.int32), sds((R, N), jnp.bfloat16)).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    for kernel in ("moe_gmm", "moe_tgmm"):
+        assert re.search(rf'^\s*%\S*{kernel}\S* = .*custom_call_target="tpu_custom_call"',
+                         text, re.M), kernel
+
+
 # mesh axes, (B, H, L, D) global, masked, causal
 _RING_CASES = [
     pytest.param(dict(dp=2, sp=2), (8, 12, 1024, 64), True, False,
@@ -150,18 +201,24 @@ def test_ring_attention_compiles_for_v5e_2x2(topo, for_the_chip, axes, shape,
     assert "collective-permute" in text     # K/V really go round the ring
 
 
-def test_flash_inside_a_dp2_tp2_step_compiles_for_v5e_2x2(topo, for_the_chip):
+# mesh axes, (B, H, L, D) global: a dp·tp mesh, and the four-chip benchmark
+# cell's (bert_base_pretrain.dp4: 32 rows a chip)
+@pytest.mark.parametrize("axes,shape", [
+    pytest.param(dict(dp=2, tp=2), (8, 12, 512, 64), id="dp2_tp2"),
+    pytest.param(dict(dp=4), (128, 12, 512, 64), id="dp4_bert_base_B128"),
+])
+def test_flash_inside_a_mesh_step_compiles_for_v5e_2x2(topo, for_the_chip, axes, shape):
     """What a BERT layer's attention is inside a step compiled over a
-    dp·tp mesh. GSPMD cannot partition a Mosaic kernel (lowering raises
+    mesh. GSPMD cannot partition a Mosaic kernel (lowering raises
     "Mosaic kernels cannot be automatically partitioned"), so
     ``dot_product_attention`` must hand it over per shard."""
     from incubator_mxnet_tpu.ops.attention import dot_product_attention
     from incubator_mxnet_tpu.parallel.mesh import active_mesh
-    mesh = parallel.make_mesh(devices=list(topo.devices), dp=2, tp=2)
-    spec = P("dp", "tp", None, None)
-    x = jax.ShapeDtypeStruct((8, 12, 512, 64), jnp.bfloat16,
+    mesh = parallel.make_mesh(devices=list(topo.devices), **axes)
+    spec = P("dp", "tp" if "tp" in axes else None, None, None)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16,
                              sharding=NamedSharding(mesh, spec))
-    mask = jax.ShapeDtypeStruct((8, 1, 1, 512), jnp.bool_,
+    mask = jax.ShapeDtypeStruct((shape[0], 1, 1, shape[2]), jnp.bool_,
                                 sharding=NamedSharding(mesh, P("dp")))
     with active_mesh(mesh):
         compiled = _fwd_bwd(dot_product_attention).lower(
