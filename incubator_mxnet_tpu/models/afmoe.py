@@ -299,8 +299,10 @@ class AfmoeModel(HybridBlock):
     def routing(self, ids, positions, valid_length, publish: bool = True) -> list:
         """One eager forward that records, for each MoE layer in order, what
         the layer's own routing function chose: ``idx (T, k)``, and from the
-        row plan ``counts`` (rows of each expert held), ``assignments_held``
-        and ``rows_placed``. With ``publish`` they go out as ``mxtpu_moe_*``
+        row plan ``counts`` (rows of each expert held), ``assignments_held``,
+        ``rows_placed`` and ``rows_live_share`` (the share of the worst-case
+        buffer in use, which is how much of it the routed half's passes
+        visit). With ``publish`` they go out as ``mxtpu_moe_*``
         gauges (``telemetry.metrics``); the compiled step itself calls
         nothing on the host, and leaves its own count in each layer's
         ``expert_rows`` buffer (:meth:`expert_rows`)."""
@@ -320,7 +322,8 @@ class AfmoeModel(HybridBlock):
             for name, value in (("assignments_held", held),
                                 ("assignments_dropped", held - placed),
                                 ("expert_rows_max", float(counts.max())),
-                                ("expert_rows_mean", float(counts.mean()))):
+                                ("expert_rows_mean", float(counts.mean())),
+                                ("rows_live_share", float(r["rows_live_share"]))):
                 metrics.gauge("mxtpu_moe_" + name, "Routing of one MoE layer, "
                               "last routing() call", layer=str(i)).set(value)
         return out

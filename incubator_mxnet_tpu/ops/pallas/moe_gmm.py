@@ -17,7 +17,9 @@ Work is in proportion to the rows present, not to the buffer: a tile past
 ``n_tiles`` computes nothing, and names the blocks of the last tile in use,
 so Pallas (which copies a block only when its index changes) moves nothing
 for it either. Output rows past ``n_tiles * tile_rows`` are left as they
-come: the caller masks them.
+come. On a TPU nothing reads them (``parallel/moe_dropless.py``: every pass
+round these kernels follows the same ``n_tiles``, ``moe_rows.py``); the XLA
+passes that stand in off the chip read the whole buffer and mask them.
 
 ``moe_gmm``: ``out[rows of g] = lhs[rows of g] @ rhs[g]``, one MXU matmul a
 tile in the input dtype with fp32 accumulation; the weights of a group are

@@ -12,21 +12,28 @@ their exchange.
 No assignment is dropped, whatever the skew. Rows are laid out for
 ``ops/pallas/moe_gmm.py`` (sorted by expert, each expert padded to whole
 tiles) in one buffer sized for the worst case: every one of the ``tokens *
-top_k`` assignments landing here. The kernels do work for the rows present;
-the gathers and elementwise passes round them cost what the buffer costs
-(PERF.md section 6, PR 27, has both), and the step stays one executable
-with one path through it.
+top_k`` assignments landing here. The step stays one executable with one
+path through it, and on a TPU every pass over that buffer costs what the
+rows present cost: a pass in row order visits the ``n_tiles`` tiles in use,
+as the grouped matmuls do, and a pass in token order fetches a row only for
+an assignment whose expert is held (``ops/pallas/moe_rows.py``). Rows past
+``rows_padded`` hold whatever was there and are read by nothing; the padding
+rows of a tile in use are zero from the dispatch on, and stay zero through
+the products and the gate. Off the chip the same moves are XLA gathers over
+the whole buffer and the products ``ragged_dot``, chosen by platform as
+``grouped_matmul`` chooses; the tests hold the kernels to them.
 
 Everything is a pure function of arrays; ``models/afmoe.py`` wraps it.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 
-from ..ops.pallas import moe_gmm
+from ..ops.pallas import moe_gmm, moe_rows
 
 __all__ = ["router_scores", "sigmoid_topk", "plan_rows", "RowPlan",
            "routed_experts", "buffer_rows", "placement"]
@@ -50,7 +57,10 @@ def sigmoid_topk(x, router_weight, select_bias, top_k: int,
     scores = router_scores(x, router_weight)
     _, idx = jax.lax.top_k(
         scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), top_k)
-    weight = jnp.take_along_axis(scores, idx, axis=1)
+    # the chosen scores by selection, not by a gather: a TPU gathers scalars
+    # an index at a time, and the gather's transpose is a scatter-add
+    chosen = idx[:, :, None] == jnp.arange(scores.shape[1])[None, None, :]
+    weight = jnp.where(chosen, scores[:, None, :], 0).sum(-1)
     if route_norm:
         weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), weight * route_scale
@@ -66,6 +76,7 @@ class RowPlan(NamedTuple):
     rows_padded: jax.Array  # (): rows in use, padding included
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2))
 def plan_rows(idx, held: Sequence[int], tile_rows: int) -> RowPlan:
     """Sort the ``(T, k)`` assignments by expert held and lay each expert's
     rows on whole tiles (an expert with no row keeps one empty tile, so the
@@ -88,9 +99,31 @@ def plan_rows(idx, held: Sequence[int], tile_rows: int) -> RowPlan:
                    sizes.sum().astype(jnp.int32))
 
 
+def _spread(values, plan: RowPlan, row_group, tile_rows: int):
+    """``values (T*k,)`` in sorted order, laid out as the buffer's rows are
+    (``row_group (R,)`` the expert of each): expert ``g``'s run, from
+    ``sorted_starts[g]``, lands from ``starts[g]``. The padded layout is the
+    sorted one with gaps let in, so each expert's rows are one slice moved
+    back by the padding before it: a slice an expert, where a gather of
+    ``R`` scalars costs a TPU ten times that. Rows that carry nothing get
+    whatever lies there."""
+    G, rows = plan.counts.shape[0], row_group.shape[0]
+    room = G * tile_rows                  # the most padding before any expert
+    wide = jnp.concatenate([jnp.zeros((room,), values.dtype), values,
+                            jnp.zeros((max(rows - values.shape[0], 0),), values.dtype)])
+    out = jnp.zeros((rows,), values.dtype)
+    for g in range(G):
+        moved = jax.lax.dynamic_slice(
+            wide, (room - (plan.starts[g] - plan.sorted_starts[g]),), (rows,))
+        out = jnp.where(row_group == g, moved, out)
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
 def _rows_of(plan: RowPlan, rows: int, tile_rows: int):
-    """For a buffer of ``rows``: each tile's expert, the tiles in use, and
-    for each row the assignment it carries (``_FAR``: padding)."""
+    """For a buffer of ``rows``: each tile's expert, the tiles in use, for
+    each row the assignment it carries (``_FAR``: padding), and the rows of
+    each tile that carry one (its leading rows; none past the tiles in use)."""
     G = plan.counts.shape[0]
     ends = plan.starts + jnp.maximum(
         (plan.counts + tile_rows - 1) // tile_rows, 1) * tile_rows
@@ -102,9 +135,12 @@ def _rows_of(plan: RowPlan, rows: int, tile_rows: int):
     off = jnp.arange(rows, dtype=jnp.int32) - plan.starts[g]
     valid = jnp.logical_and(off < plan.counts[g],
                             jnp.arange(rows) < plan.rows_padded)
-    pos = jnp.clip(plan.sorted_starts[g] + off, 0, plan.order.shape[0] - 1)
-    row_assign = jnp.where(valid, plan.order[pos], _FAR)
-    return tile_group, n_tiles, row_assign
+    row_assign = jnp.where(valid, _spread(plan.order, plan, g, tile_rows), _FAR)
+    tile_valid = jnp.where(
+        tile_first < plan.rows_padded,
+        jnp.clip(plan.counts[tile_group] - (tile_first - plan.starts[tile_group]),
+                 0, tile_rows), 0).astype(jnp.int32)
+    return tile_group, n_tiles, row_assign, tile_valid
 
 
 # The two moves between token order and sorted rows are each other's
@@ -159,6 +195,93 @@ def _combine_bwd(res, d_out):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+# The same two moves on a TPU, by the row kernels: what is fetched a row at a
+# time is packed into a slab first (``moe_rows``), the tiles in use only.
+
+class _Moves(NamedTuple):
+    """The indices the row kernels move rows by."""
+    n_tiles: jax.Array      # (1,): tiles in use
+    tile_valid: jax.Array   # (tiles,): leading rows of each tile that carry an assignment
+    row_token: jax.Array    # (R,): the token of each such row
+    row_assign: jax.Array   # (R,): its assignment (_FAR: padding)
+    dest: jax.Array         # (T, k): a token's rows, the assignments held in its leading slots
+    held: jax.Array         # (T,): how many
+    slot: jax.Array         # (T, k, k) fp32: 1 where assignment j is held and takes slot s
+    plan: RowPlan
+    row_group: jax.Array    # (R,): the expert of each row
+
+
+def _moves(plan: RowPlan, tile_group, n_tiles, row_assign, tile_valid, tile_rows) -> _Moves:
+    k = plan.dest.shape[1]
+    held = plan.dest < _FAR
+    slot = jnp.logical_and(held[:, :, None], (jnp.cumsum(held, axis=1) - 1)[:, :, None]
+                           == jnp.arange(k)[None, None, :])
+    dest = jnp.where(slot, plan.dest[:, :, None], 0).sum(1).astype(jnp.int32)
+    return _Moves(n_tiles, tile_valid, row_assign // k, row_assign, dest,
+                  held.sum(1).astype(jnp.int32), slot.astype(jnp.float32), plan,
+                  jnp.repeat(tile_group, tile_rows))
+
+
+def _like(a):
+    return a.shape[1], a.dtype
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _dispatch_rows(x, mv: _Moves, tile_rows: int):
+    return moe_rows.gather(moe_rows.pack(x, None, tile_rows), _like(x), mv.row_token,
+                           mv.tile_valid, mv.n_tiles, tile_rows)
+
+
+def _dispatch_rows_fwd(x, mv, tile_rows):
+    return _dispatch_rows(x, mv, tile_rows), mv
+
+
+def _dispatch_rows_bwd(tile_rows, mv, d_rows):
+    slab = moe_rows.pack(d_rows, mv.n_tiles, tile_rows)
+    return moe_rows.combine(slab, _like(d_rows), mv.dest, mv.held,
+                            jnp.ones(mv.dest.shape, jnp.float32)), None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine_rows(y, weight, mv: _Moves, tile_rows: int):
+    return _combine_rows_fwd(y, weight, mv, tile_rows)[0]
+
+
+def _combine_rows_fwd(y, weight, mv, tile_rows):
+    slab = moe_rows.pack(y, mv.n_tiles, tile_rows)
+    out = moe_rows.combine(slab, _like(y), mv.dest, mv.held,
+                           jnp.einsum("tjs,tj->ts", mv.slot, weight))
+    return out, (slab, weight, mv)
+
+
+def _combine_rows_bwd(tile_rows, res, d_out):
+    slab, weight, mv = res
+    like = _like(d_out)
+    # each row's weight: the weights sorted as the rows are (a sort by row is
+    # a tenth of a gather by assignment), then spread as the rows are
+    by_row = jax.lax.sort((mv.plan.dest.reshape(-1), weight.reshape(-1)), num_keys=1)[1]
+    row_weight = jnp.where(mv.row_assign < _FAR,
+                           _spread(by_row, mv.plan, mv.row_group, tile_rows), 0)
+    d_slab = moe_rows.pack(d_out, None, tile_rows)
+    dy = moe_rows.gather(d_slab, like, mv.row_token, mv.tile_valid, mv.n_tiles, tile_rows,
+                         scale=row_weight[:, None])
+    dw = jnp.einsum("tjs,ts->tj", mv.slot,
+                    moe_rows.dot(slab, like, mv.dest, mv.held, d_slab))
+    return dy, dw, None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+def _row_kernels(x) -> bool:
+    """The row kernels and the Pallas grouped matmuls on a TPU, XLA's gathers
+    and ``ragged_dot`` elsewhere: by platform, as ``grouped_matmul`` chooses."""
+    return not moe_gmm._interpret_for(x)
+
+
 def buffer_rows(tokens: int, top_k: int, groups: int, tile_rows: int) -> int:
     """Rows of the buffer the routed half is compiled for, the worst case:
     every assignment landing on the ``groups`` experts held, however they
@@ -169,13 +292,16 @@ def buffer_rows(tokens: int, top_k: int, groups: int, tile_rows: int) -> int:
 def placement(idx, held: Sequence[int], tile_rows: int) -> dict:
     """What :func:`routed_experts` does with ``idx``, counted (eagerly, on
     concrete arrays): ``counts`` (rows of each expert held),
-    ``assignments_held``, and ``rows_placed``, the rows of the buffer that
-    carry an assignment. Dropless means the last two agree."""
+    ``assignments_held``, ``rows_placed``, the rows of the buffer that carry
+    an assignment (dropless means these two agree), and ``rows_live_share``,
+    the share of the buffer in use (``rows_padded`` over its rows), which is
+    the share of it the passes in row order visit."""
     T, k = idx.shape
-    plan = plan_rows(idx, held, tile_rows)
+    plan = plan_rows(idx, tuple(held), tile_rows)
     rows = buffer_rows(T, k, held[1], tile_rows)
     return {"counts": plan.counts, "assignments_held": (plan.dest < _FAR).sum(),
-            "rows_placed": (_rows_of(plan, rows, tile_rows)[2] < _FAR).sum()}
+            "rows_placed": (_rows_of(plan, rows, tile_rows)[2] < _FAR).sum(),
+            "rows_live_share": plan.rows_padded / rows}
 
 
 def routed_experts(x, idx, weight, w13, w2, held: Sequence[int],
@@ -189,10 +315,31 @@ def routed_experts(x, idx, weight, w13, w2, held: Sequence[int],
     both ``(out, in)``; ``held = (first, G)``; ``plan`` the
     :func:`plan_rows` of ``idx`` where the caller has made it already."""
     T, k = idx.shape
+    held = tuple(held)
+    return _routed_half(x, idx, weight, w13, w2, plan, held, tile_rows,
+                        buffer_rows(T, k, held[1], tile_rows), _row_kernels(x))
+
+
+# One jitted function, so that a model's routed layers (the same shapes, each
+# traced for the forward pass, its recomputation and the backward pass) are
+# traced and lowered once between them, and an eager call is one dispatch.
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _routed_half(x, idx, weight, w13, w2, plan, held, tile_rows, rows, row_kernels):
+    k = idx.shape[1]
     if plan is None:
-        plan = plan_rows(idx, tuple(held), tile_rows)
-    rows = buffer_rows(T, k, held[1], tile_rows)
-    tile_group, n_tiles, row_assign = _rows_of(plan, rows, tile_rows)
+        plan = plan_rows(idx, held, tile_rows)
+    tile_group, n_tiles, row_assign, tile_valid = _rows_of(plan, rows, tile_rows)
+    if row_kernels:
+        mv = _moves(plan, tile_group, n_tiles, row_assign, tile_valid, tile_rows)
+        with jax.named_scope("moe_dispatch"):
+            xs = _dispatch_rows(x, mv, tile_rows)
+        with jax.named_scope("moe_experts"):
+            h = moe_gmm.grouped_matmul(xs, w13, tile_group, n_tiles, tile_rows, impl="pallas")
+            y = moe_gmm.grouped_matmul(moe_rows.gate(h, n_tiles, tile_rows), w2,
+                                       tile_group, n_tiles, tile_rows, impl="pallas")
+        with jax.named_scope("moe_combine"):
+            return _combine_rows(y, weight, mv, tile_rows)
     row_token = jnp.where(row_assign < _FAR, row_assign // k, _FAR)
     valid = (row_assign < _FAR)[:, None]
     with jax.named_scope("moe_dispatch"):

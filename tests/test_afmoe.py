@@ -16,10 +16,11 @@ import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import models, parallel
 from incubator_mxnet_tpu.ops.attention import dot_product_attention
 from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
-from incubator_mxnet_tpu.ops.pallas import moe_gmm
+from incubator_mxnet_tpu.ops.pallas import moe_gmm, moe_rows
 from incubator_mxnet_tpu.parallel import moe_dropless
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from benchmark import moe_gmm_probe as probe  # noqa: E402
 from chipbench.reference import afmoe as reference  # noqa: E402
 
 CFG = dict(hidden_size=64, num_attention_heads=4, num_key_value_heads=2, head_dim=16,
@@ -153,10 +154,25 @@ def test_shares_add_up_to_the_uncut_layer(pallas_gmm):
     assert float(jnp.abs(parts[0]).max()) > 0 and float(jnp.abs(parts[1]).max()) > 0
 
 
-@pytest.mark.parametrize("skew", ["all_on_one_held_expert", "all_held", "none_held"])
-def test_no_assignment_is_dropped_whatever_the_skew(skew):
+@pytest.fixture
+def row_kernels(monkeypatch):
+    """The path a TPU takes (``moe_rows`` kernels and the Pallas grouped
+    matmuls, all in interpret mode here) where the platform would choose
+    XLA's gathers and ``ragged_dot``."""
+    monkeypatch.setattr(moe_dropless, "_row_kernels", lambda x: True)
+
+
+_SKEWS = ["all_on_one_held_expert", "all_held", "none_held"]
+
+
+@pytest.mark.parametrize("passes", ["xla", "row_kernels"])
+@pytest.mark.parametrize("skew", _SKEWS)
+def test_no_assignment_is_dropped_whatever_the_skew(skew, passes, request):
     """Every token picks the same experts: the buffer for the worst case takes
-    them all, and the result is still the reference's."""
+    them all, and the result is still the reference's (``all_held`` fills 14
+    of the buffer's 16 tiles)."""
+    if passes == "row_kernels":
+        request.getfixturevalue("row_kernels")
     rng = onp.random.default_rng(2)
     E, k, T, held = 8, 2, 48, (4, 4)
     p = _moe_layer_params(rng, E)
@@ -188,6 +204,103 @@ def test_no_assignment_is_dropped_whatever_the_skew(skew):
     onp.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     for a, b in zip(got_vjp(cot), want_vjp(cot)):        # through the gathers' own VJPs
         onp.testing.assert_allclose(a, b, rtol=1e-3, atol=1e-4)
+
+
+# --- the row kernels against the XLA passes they replace on a TPU -------------------
+
+def _routed_case(skew, dtype=jnp.float32, T=48):
+    """``(idx, args, held)`` of one routed half at ``tile_rows=8``: the three
+    skews above, or each token's two experts drawn apart."""
+    rng = onp.random.default_rng(7)
+    E, k, held = 8, 2, (4, 4)
+    p = _moe_layer_params(rng, E)
+    x = jnp.asarray(rng.normal(0, 1, (T, CFG["hidden_size"])), dtype)
+    if skew == "drawn":
+        idx = jnp.asarray(onp.stack([rng.permutation(E)[:k] for _ in range(T)]), jnp.int32)
+    else:
+        picked = {"all_on_one_held_expert": [5, 0], "all_held": [4, 7], "none_held": [0, 3]}[skew]
+        idx = jnp.tile(jnp.asarray(picked, jnp.int32), (T, 1))
+    w = jnp.asarray(rng.uniform(0.2, 1.0, (T, k)), jnp.float32)
+    return idx, (x, w, p["experts_w13"][4:].astype(dtype), p["experts_w2"][4:].astype(dtype)), held
+
+
+def _value_and_grads(idx, args, held):
+    def f(x, w, w13, w2):
+        return moe_dropless.routed_experts(x, idx, w, w13, w2, held, tile_rows=8)
+    cot = jnp.asarray(onp.random.default_rng(8).normal(0, 1, args[0].shape), args[0].dtype)
+    out, vjp = jax.vjp(f, *args)
+    return (out,) + vjp(cot)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("skew", _SKEWS + ["drawn"])
+def test_row_kernels_give_what_the_xla_passes_give(skew, dtype, monkeypatch):
+    """The routed half by ``moe_rows`` and the Pallas grouped matmuls against
+    the same half by XLA's gathers over the whole buffer: the output and the
+    gradients of ``x``, the routing weights and both expert matrices."""
+    idx, args, held = _routed_case(skew, jnp.dtype(dtype))
+    monkeypatch.setattr(moe_gmm, "grouped_matmul",      # one product under both
+                        functools.partial(moe_gmm.grouped_matmul, impl="pallas"))
+    want = _value_and_grads(idx, args, held)
+    monkeypatch.setattr(moe_dropless, "_row_kernels", lambda x: True)
+    got = _value_and_grads(idx, args, held)
+    tol = dict(rtol=1e-5, atol=1e-5) if dtype == "float32" else dict(rtol=2e-2, atol=2e-2)
+    for name, a, b in zip(("out", "dx", "dweight", "dw13", "dw2"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        onp.testing.assert_allclose(onp.asarray(a, "float32"), onp.asarray(b, "float32"),
+                                    err_msg=name, **tol)
+
+
+@pytest.mark.parametrize("skew", ["all_on_one_held_expert", "drawn"])
+def test_no_pass_reads_a_dead_tile(skew, row_kernels):
+    """Rows at or past ``rows_padded`` of every intermediate buffer (the
+    gathered rows, both products, the gate, their gradients, the slabs) set
+    to NaN as each kernel hands them over: the output and every gradient
+    are finite and the very values of the unpoisoned run."""
+    idx, args, held = _routed_case(skew)
+    clean = _value_and_grads(idx, args, held)
+    tiles = moe_dropless.buffer_rows(idx.shape[0], idx.shape[1], held[1], 8) // 8
+    plan = moe_dropless.plan_rows(idx, held, 8)
+    assert int(plan.rows_padded) // 8 < tiles               # there are dead tiles to poison
+    with probe.dead_tiles_poisoned(tiles) as poisoned:
+        slab = moe_rows.pack(jnp.ones((tiles * 8, 16)), jnp.asarray([1], jnp.int32), 8)
+        assert int(slab[8 * 8, 0]) == 0xFFFFFFFF            # the poison is in place,
+        del poisoned[:]
+        dirty = _value_and_grads(idx, args, held)
+    assert {"pack", "gather", "_by_tile", "gmm"} <= set(poisoned)   # and the half ran on it
+    for name, a, b in zip(("out", "dx", "dweight", "dw13", "dw2"), dirty, clean):
+        assert onp.isfinite(onp.asarray(a)).all(), name
+        onp.testing.assert_array_equal(onp.asarray(a), onp.asarray(b), err_msg=name)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_slab_gives_its_rows_back_bit_for_bit(dtype):
+    """``pack`` then ``gather``: the rows named, in order, under the valid
+    rows of the tiles in use; zero in a tile's padding rows."""
+    rng = onp.random.default_rng(9)
+    dt, N, C, tm = jnp.dtype(dtype), 48, 64, 8
+    a = jnp.asarray(rng.normal(0, 1, (N, C)), dt)
+    idx = jnp.asarray(rng.integers(0, N, (10 * tm,)), jnp.int32)
+    tile_valid = jnp.asarray([8, 3, 0, 5] + [0] * 6, jnp.int32)
+    out = moe_rows.gather(moe_rows.pack(a, None, tm), (C, dt), idx, tile_valid,
+                          jnp.asarray([4], jnp.int32), tm)
+    live = (jnp.arange(10 * tm) % tm < jnp.repeat(tile_valid, tm))[:, None]
+    onp.testing.assert_array_equal(onp.asarray(out[:4 * tm], "float32"),
+                                   onp.asarray(jnp.where(live, a[idx], 0)[:4 * tm], "float32"))
+
+
+def test_the_live_share_of_the_buffer_is_counted(system_and_reference):
+    """``rows_padded`` over the buffer's rows, from the plan the layer makes:
+    in ``placement`` and as a gauge beside the other routing counters."""
+    from incubator_mxnet_tpu.telemetry import metrics
+    idx, _, held = _routed_case("all_on_one_held_expert")
+    place = moe_dropless.placement(idx, held, 8)
+    # 48 rows on one expert (6 tiles) and an empty tile for each of the other three
+    assert float(place["rows_live_share"]) == pytest.approx(9 * 8 / 128)
+    routes = system_and_reference["net"].routing(*system_and_reference["args"])
+    for i, r in enumerate(routes):
+        share = metrics.gauge("mxtpu_moe_rows_live_share", layer=str(i)).value
+        assert 0 < share <= 1 and share == pytest.approx(float(r["rows_live_share"]))
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["held", "recomputed"])

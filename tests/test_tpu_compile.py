@@ -25,8 +25,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from incubator_mxnet_tpu import parallel
 from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
-from incubator_mxnet_tpu.ops.pallas import moe_gmm
-from incubator_mxnet_tpu.parallel import collectives, ring
+from incubator_mxnet_tpu.ops.pallas import moe_gmm, moe_rows
+from incubator_mxnet_tpu.parallel import collectives, moe_dropless, ring
 
 
 @pytest.fixture(scope="module")
@@ -50,6 +50,7 @@ def for_the_chip(monkeypatch):
     from jax.experimental.compilation_cache import compilation_cache as cc
     monkeypatch.setattr(fa, "_interpret_for", lambda x: False)
     monkeypatch.setattr(moe_gmm, "_interpret_for", lambda x: False)
+    monkeypatch.setattr(moe_rows, "_interpret_for", lambda x: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -162,6 +163,33 @@ def test_grouped_matmul_compiles_for_v5e(topo, for_the_chip):
     for kernel in ("moe_gmm", "moe_tgmm"):
         assert re.search(rf'^\s*%\S*{kernel}\S* = .*custom_call_target="tpu_custom_call"',
                          text, re.M), kernel
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_routed_half_compiles_for_v5e_on_the_row_kernels(topo, for_the_chip, dtype):
+    """The routed half as a TPU runs it, forward and backward at
+    Trinity-Mini's widths (1,024 tokens, top 8, 16 experts held: a buffer of
+    48 tiles): the six row kernels and the grouped matmuls each under its
+    name, Mosaic taking the slabs' row DMAs, the strided loads and the index
+    blocks in SMEM; and no XLA gather of 2,048-wide rows left beside them."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    T, k, C, F, G = 1024, 8, 2048, 1024, 16
+    dt = jnp.dtype(dtype)
+    sds = lambda shape, d: jax.ShapeDtypeStruct(shape, d, sharding=one_chip)  # noqa: E731
+
+    def f(x, idx, weight, w13, w2, dout):
+        out, vjp = jax.vjp(lambda x, weight, w13, w2: moe_dropless.routed_experts(
+            x, idx, weight, w13, w2, (0, G)), x, weight, w13, w2)
+        return (out,) + vjp(dout)
+
+    text = jax.jit(f).lower(
+        sds((T, C), dt), sds((T, k), jnp.int32), sds((T, k), jnp.float32),
+        sds((G, 2 * F, C), dt), sds((G, C, F), dt), sds((T, C), dt)).compile().as_text()
+    for kernel in ("moe_rows_pack", "moe_rows_gather", "moe_rows_combine", "moe_rows_dot",
+                   "moe_rows_gate", "moe_rows_gate_bwd", "moe_gmm", "moe_tgmm"):
+        assert re.search(rf'^\s*%\S*{kernel}[.\d]* = .*custom_call_target="tpu_custom_call"',
+                         text, re.M), kernel
+    assert not re.search(rf"\[\d+,{C}\]\S* gather\(", text)
 
 
 # mesh axes, (B, H, L, D) global, masked, causal
