@@ -3,9 +3,8 @@
 
 TVM's argument (arXiv 1802.04799) applied to this runtime: the remaining
 MFU lives in *searching* configuration space over the compiled graph,
-not hand-picking one env recipe per round. ``bert_sweep.py`` runs eight
-hand-listed variants on real hardware; this driver generalizes that list
-into a declared search space (remat policy × flash block size ×
+not hand-picking one env recipe per round. This driver declares a
+search space (remat policy × flash block size ×
 batch/bucket geometry × embedding-gradient path), evaluates candidates
 **in-process with zero XLA compiles** — every candidate is traced
 (``ShardedTrainer.prepare`` + ``jax.make_jaxpr`` for train families, the
@@ -16,7 +15,7 @@ un-warmed ``CompiledModel`` for serving families) and priced by
 ``parallel.ShardedTrainer`` and ``serve.CompiledModel`` consult at build
 time. The search is a deterministic function of the graph, so the same
 space always elects the same winner — bankable and CI-gateable with no
-hardware, exactly like PERF_PROXY.json.
+hardware.
 
 Score: a roofline proxy over the cost table plus the compile-ledger
 dimensions (docs/architecture.md "Autotuning")::
@@ -42,9 +41,6 @@ the chip.
         --cache-dir autotune_cache
     python -m benchmark.autotune --families lenet --budget 6 \
         --cache-dir autotune_cache --gate      # the CI autotune-smoke job
-
-``bert_sweep.py`` now derives its hardware-sweep VARIANTS from this
-file's :func:`bench_variants` — one source of truth for the dimensions.
 """
 from __future__ import annotations
 
@@ -62,7 +58,7 @@ if REPO not in sys.path:  # `python benchmark/autotune.py` direct invocation
 
 
 # ---------------------------------------------------------------------------
-# the search space — ONE declaration, shared with bert_sweep.py
+# the search space — ONE declaration
 # ---------------------------------------------------------------------------
 
 @dataclass(frozen=True)
@@ -113,39 +109,6 @@ FAMILY_SPACES: Dict[str, Dict[str, Any]] = {
                              "quantize")},
 }
 
-#: real-hardware geometry the subprocess sweep (bert_sweep.py) probes —
-#: expressed through bench.py's env knobs, values from the same
-#: dimensions scaled to the headline workload
-BENCH_GEOMETRY = {"batch": (4, 8, 16, 32), "seq": (512, 1024)}
-
-
-def bench_variants() -> List[Tuple[str, Dict[str, str]]]:
-    """The bert_sweep.py VARIANTS list, derived from :data:`DIMS` and
-    :data:`BENCH_GEOMETRY` (BASELINE.md round-3 prepared sweep: batch/
-    remat rescan under the adaptive flash tiles, the BK=256 variant, and
-    the one-hot embedding-gradient path)."""
-    onehot = DIMS["embed_grad"].env
-    bk = DIMS["flash_bk"].env
-    assert "256" in DIMS["flash_bk"].values
-    batches, seqs = BENCH_GEOMETRY["batch"], BENCH_GEOMETRY["seq"]
-    return [
-        ("default-B8", {}),
-        ("embed-onehot-grad", {onehot: "1"}),
-        ("flash-BK256", {bk: "256"}),
-        (f"B{batches[2]}", {"MXTPU_BENCH_BATCH": str(batches[2])}),
-        (f"B{batches[2]}-remat", {"MXTPU_BENCH_BATCH": str(batches[2]),
-                                  "MXTPU_BENCH_REMAT": "1"}),
-        (f"B{batches[3]}-remat", {"MXTPU_BENCH_BATCH": str(batches[3]),
-                                  "MXTPU_BENCH_REMAT": "1"}),
-        (f"B{batches[1]}-onehot+BK256", {onehot: "1", bk: "256"}),
-        # same tokens/step as the headline config, doubled sequence:
-        # probes whether the flash tiles hold their efficiency as the
-        # attention share of credited FLOPs grows (L divides the tiles)
-        (f"B{batches[0]}-L{seqs[1]}", {"MXTPU_BENCH_BATCH": str(batches[0]),
-                                       "MXTPU_BENCH_SEQ": str(seqs[1])}),
-    ]
-
-
 def candidates(family: str,
                budget: Optional[int] = None) -> List[Dict[str, Any]]:
     """Deterministic candidate list: the cartesian product of the
@@ -168,9 +131,9 @@ _AMORTIZE_STEPS = 10000.0        # steps a banked config is expected to run
 
 
 def _peaks() -> Tuple[float, float, float]:
-    # THE shared peak table (util.roofline_peaks): bench.py's MFU
-    # accounting, this score, and telemetry.goodput's predicted_mfu all
-    # read one source, so a chip-kind correction can never diverge them
+    # THE shared peak table (util.roofline_peaks): this score and
+    # telemetry.goodput's predicted_mfu read one source, so a chip-kind
+    # correction can never diverge them
     from incubator_mxnet_tpu.util import roofline_peaks
     return roofline_peaks()
 
@@ -537,7 +500,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     # device-blind by design: pin cpu so the search never takes the chip,
-    # which one process owns at a time (same as bench.py --proxy)
+    # which one process owns at a time
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

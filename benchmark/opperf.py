@@ -3,8 +3,8 @@
 Reference parity: ``benchmark/opperf/`` (opperf.py + nd_operations/*) — run
 every registered operator (or a chosen subset) on representative shapes,
 timing forward and forward+backward, and emit a machine-readable report.
-This is the perf-regression gate the headline ``bench.py`` is too coarse
-for.
+Per-operator timings on the host or device it runs on; whole-step speed is
+``chipbench/run.py``'s to measure.
 
 TPU-native design: each measurement jits the op once (fwd, and
 ``jax.value_and_grad`` over a sum-reduction for bwd), warms the executable,

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """serve_bench — offline throughput/latency sweep + dynamic-batching demo.
 
-The serving counterpart of ``bench.py`` (which measures training steps):
-one command produces a BENCH-style JSON record covering
+A CPU closed loop over the serving path (no cell of the benchmark serves
+yet: ROADMAP R7): one command produces a JSON record covering
 
 1. **offline sweep**: for each batch-bucket size, steady-state
    ``CompiledModel.predict`` latency and throughput (rows/sec) — the
@@ -15,8 +15,8 @@ one command produces a BENCH-style JSON record covering
 3. per-stage wall time from the profiler span recorder
    (pad / compute / unpad / batch), a ``serve.predict`` host-gap
    attribution (``profiler.step_report``), and a device-blind perf-proxy
-   record (``analysis.hlo.cost`` FLOPs/bytes/fusion per bucket graph —
-   the serving sibling of ``bench.py --proxy``), also emitted as one
+   record (``analysis.hlo.cost`` FLOPs/bytes/fusion per bucket graph),
+   also emitted as one
    ``perf.proxy`` telemetry event.
 
 Usage::
@@ -836,8 +836,8 @@ def main(argv=None) -> int:
               f"finding(s): {[d.code for d in analysis_rep.errors]}",
               file=sys.stderr)
         return 1
-    # device-blind perf-proxy record (the serving sibling of bench.py
-    # --proxy): price every bucket graph before warmup — trace-only, so
+    # device-blind perf-proxy record: price every bucket graph before
+    # warmup — trace-only, so
     # a cost explosion is visible even if warmup would then be slow
     cost_rep = _hlo.cost(model, max_graphs=max(8, table.num_buckets()))
     # SLO burn-rate monitoring brackets the run: the pre-run evaluation

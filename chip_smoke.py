@@ -9,7 +9,7 @@ parameters with fp32 masters, random weights from a seed), in ONE process:
 device      ``jax.devices()`` first; anything but a TPU fails at once
 kernel      the Pallas flash kernel, forward and grads, compiled, against
             the XLA attention path on the same inputs
-train       ``bench.bert_trainer`` (``parallel.ShardedTrainer``, AdamW)
+train       ``bert_trainer`` below (``parallel.ShardedTrainer``, AdamW)
             at B=8, L=512: twenty steps on one repeated batch, one compile
 sync        a few steps timed with ``wait_to_read`` and with ``asnumpy``
 serve       ``serve.CompiledModel`` over the ``bert_encoder`` family,
@@ -38,7 +38,7 @@ A rehearsal proves nothing about the chip: it ends in ``"ok": false`` and
 exit code 3, whatever it ran on.
 
 The persistent compilation cache is where ``JAX_COMPILATION_CACHE_DIR``
-says, else ``<checkout>/.jax_cache`` (``bench.use_compile_cache``); the
+says, else ``<checkout>/.jax_cache`` (``use_compile_cache`` below); the
 ``cache`` line and every phase's ``compile_requests`` / ``cache_hits`` say
 whether a second run of the same command found the first one's programs.
 """
@@ -63,8 +63,8 @@ _BF16_TOL = 2e-2
 #: times what two correct ONE-device programs (flash vs XLA attention)
 #: drift apart over four steps at _MESH_LR (1.6e-4, my chip run, PR 21)
 _MESH_LOSS_RTOL = 2e-3
-#: a tenth of the bench's learning rate. At 1e-4 with no warm-up the first
-#: AdamW updates overshoot (the loss rises, gradients reverse, and the
+#: a tenth of ``bert_trainer``'s default learning rate. At 1e-4 with no
+#: warm-up the first AdamW updates overshoot (the loss rises, gradients reverse, and the
 #: second update is a near-cancellation of two moments), which amplifies
 #: bf16 rounding: the same two one-device programs differ by 2e-5, 7e-4,
 #: 1.8e-2 over three steps there. At 1e-5 the loss falls from the first
@@ -121,6 +121,65 @@ def _rel_err(a, ref) -> float:
     a = onp.asarray(a, "float32")
     ref = onp.asarray(ref, "float32")
     return float(onp.max(onp.abs(a - ref)) / max(onp.max(onp.abs(ref)), 1e-6))
+
+
+def use_compile_cache() -> str:
+    """Turn JAX's persistent compilation cache on, before the first
+    compile. ``JAX_COMPILATION_CACHE_DIR``, when set, is the only
+    location (jax reads it itself; no directory is set in code). Unset,
+    the cache is ``<checkout>/.jax_cache`` — a fixed path, because the
+    path is part of what a later process must find again. Returns the
+    directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+_BERT_VOCAB = 30522
+
+
+def bert_batch(B: int, L: int, vocab: int = _BERT_VOCAB) -> tuple:
+    """THE seeded synthetic pretraining batch: ``(ids, token_types,
+    valid_length, masked_positions, mlm_labels, mlm_weights, nsp_labels)``
+    with BERT's 15% masking rate."""
+    P = max(1, round(0.15 * L))
+    rng = onp.random.RandomState(0)
+    ids = rng.randint(0, vocab, (B, L)).astype("int32")
+    tt = rng.randint(0, 2, (B, L)).astype("int32")
+    vl = onp.full((B,), L, "float32")
+    pos = rng.randint(0, L, (B, P)).astype("int32")
+    mlm_lab = rng.randint(0, vocab, (B, P)).astype("float32")
+    mlm_w = onp.ones((B, P), "float32")
+    nsp = rng.randint(0, 2, (B,)).astype("float32")
+    return (ids, tt, vl, pos, mlm_lab, mlm_w, nsp)
+
+
+def bert_trainer(model_name: str, L: int, mesh, vocab: int = _BERT_VOCAB,
+                 dropout: float = 0.1, learning_rate: float = 1e-4,
+                 **overrides):
+    """``(net, trainer)`` of THE BERT pretraining job: bf16 parameters
+    with fp32 masters under AdamW, Megatron sharding rules, the whole
+    step one compiled program on ``mesh``. ``overrides`` reach
+    ``models.get_bert`` (``remat=``, a ``num_layers=`` cut)."""
+    from incubator_mxnet_tpu import models, parallel
+
+    net = models.get_bert(model_name, vocab_size=vocab, max_length=L,
+                          dropout=dropout, dtype="bfloat16", **overrides)
+    net.initialize()
+    trainer = parallel.ShardedTrainer(
+        net, models.bert_pretrain_loss, "adamw",
+        {"learning_rate": learning_rate, "multi_precision": True}, mesh=mesh,
+        rules=models.bert_sharding_rules(), n_labels=3,
+        # banked autotune winners (MXTPU_AUTOTUNE_DIR) apply at build —
+        # a tuned config is reproducible per key, not a one-off env
+        # recipe pasted into a shell
+        autotune_key="bert")
+    return net, trainer
 
 
 # ---------------------------------------------------------------------------
@@ -212,10 +271,9 @@ def phase_kernel(cfg, on_chip: bool, counter) -> None:
 
 
 def phase_train(cfg, devs, on_chip: bool, counter):
-    """BERT pretraining through ``bench.bert_trainer``: finite, falling
+    """BERT pretraining through ``bert_trainer``: finite, falling
     loss, ONE compile, flash in the step, state on the device."""
     import jax
-    import bench
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import models, parallel
     from incubator_mxnet_tpu.parallel.mesh import active_mesh
@@ -223,9 +281,9 @@ def phase_train(cfg, devs, on_chip: bool, counter):
 
     mx.random.seed(0)
     t0 = time.perf_counter()
-    net, trainer = bench.bert_trainer(
+    net, trainer = bert_trainer(
         cfg["model"], cfg["L"], parallel.make_mesh(devices=devs[:1]))
-    batch = bench.bert_batch(cfg["B"], cfg["L"])
+    batch = bert_batch(cfg["B"], cfg["L"])
     t1 = time.perf_counter()
     losses = [float(trainer.step(*batch).asnumpy())]      # init + compile
     first_s = time.perf_counter() - t1
@@ -284,7 +342,7 @@ def phase_train(cfg, devs, on_chip: bool, counter):
 def phase_sync(cfg, trainer, placed) -> None:
     """Which sync is honest here: does ``wait_to_read`` (block_until_ready)
     wait for the device, as ``asnumpy`` (a host copy) must? Read by eye and
-    recorded in the README; ``bench._measure`` is not changed here."""
+    recorded in the README."""
     n = cfg["sync_steps"]
     times = {}
     for how in ("wait_to_read", "asnumpy", "wait_to_read", "asnumpy"):
@@ -393,7 +451,6 @@ def phase_checkpoint(cfg, devs, trainer, placed, counter) -> None:
     """save -> new trainer -> restore -> the same next step."""
     import jax
     import jax.numpy as jnp
-    import bench
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import parallel
 
@@ -402,7 +459,7 @@ def phase_checkpoint(cfg, devs, trainer, placed, counter) -> None:
         path = trainer.save_checkpoint(root)
         save_s = time.perf_counter() - t0
         mx.random.seed(99)              # other weights than the saved ones
-        _, fresh = bench.bert_trainer(
+        _, fresh = bert_trainer(
             cfg["model"], cfg["L"], parallel.make_mesh(devices=devs[:1]))
         fresh.step(*placed)             # builds its state; overwritten next
         t1 = time.perf_counter()
@@ -430,7 +487,6 @@ def phase_checkpoint(cfg, devs, trainer, placed, counter) -> None:
 
 def phase_mesh(cfg, devs, on_chip: bool, counter) -> None:
     import jax
-    import bench
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import models, parallel
     from incubator_mxnet_tpu.parallel import ring
@@ -444,10 +500,10 @@ def phase_mesh(cfg, devs, on_chip: bool, counter) -> None:
         # dropout off: on the TPU the RNG is XLA's RngBitGenerator, whose
         # bits depend on how the program is partitioned, so two meshes
         # would drop different units and the losses could not be compared
-        _, tr = bench.bert_trainer(cfg["model"], L, mesh, dropout=0.0,
+        _, tr = bert_trainer(cfg["model"], L, mesh, dropout=0.0,
                                    learning_rate=_MESH_LR,
                                    num_layers=n_layers)
-        batch = bench.bert_batch(B, L)
+        batch = bert_batch(B, L)
         losses = [float(tr.step(*batch).asnumpy()) for _ in range(3)]
         assert all(onp.isfinite(losses)), losses
         assert tr.last_path == "pjit" and tr._step_fn._cache_size() == 1
@@ -537,8 +593,7 @@ def main(argv=None) -> int:
     t_start = time.perf_counter()
 
     devs, on_chip = phase_device(args.rehearse, args.chips)
-    import bench
-    cache_dir = bench.use_compile_cache()
+    cache_dir = use_compile_cache()
     _say("cache", dir=cache_dir,
          from_env=bool(os.environ.get("JAX_COMPILATION_CACHE_DIR")),
          entries_at_start=(len(os.listdir(cache_dir))

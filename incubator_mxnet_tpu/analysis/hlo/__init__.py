@@ -17,9 +17,8 @@ Programmatic entry point (called by ``serve.ModelRegistry.load`` and
 
 The same traced graphs feed the device-blind cost model
 (:mod:`~.cost`): ``mx.analysis.hlo.cost(model, sample_args)`` prices
-FLOPs / bytes / transcendentals / fusion groups per graph — the numbers
-``bench.py --proxy`` banks in ``PERF_PROXY.json`` and the CI
-``perf-proxy`` job gates with a ±5% tolerance. ``verify(...,
+FLOPs / bytes / transcendentals / fusion groups per graph: counts from
+a CPU trace that rank graphs, never device metrics. ``verify(...,
 cost=True)`` surfaces the table as informational MX707 diagnostics.
 
 CLI::

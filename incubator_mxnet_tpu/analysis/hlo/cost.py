@@ -10,9 +10,8 @@ input / output / activation bytes, and fusion statistics (maximal
 def-use-connected groups of elementwise ops — the metric "Operator
 Fusion in XLA" (arXiv 2301.13062) shows tracks realized performance).
 Every count is a deterministic function of the traced graph, so two runs
-of the same code produce byte-identical tables — the property the CI
-``perf-proxy`` gate (``bench.py --proxy`` vs the banked
-``PERF_PROXY.json``) relies on.
+of the same code produce byte-identical tables — the property the
+autotuner's deterministic election and the MX709 budget gate rely on.
 
 Entry points::
 
@@ -40,8 +39,7 @@ Memory: :func:`peak_live_bytes` is a donation-aware last-use liveness
 scan over the same jaxpr — args + consts + the maximal simultaneously-
 live eqn outputs — the deterministic static twin of the runtime
 ``telemetry.memory`` ledger. It feeds the ``peak`` column of ``mxlint
---cost``, the banked ``peak_live_bytes`` perf-proxy gate, the autotune
-memory-feasibility constraint, and the MX709 ``hlo_memory`` pass that
+--cost``, the autotune memory-feasibility constraint, and the MX709 ``hlo_memory`` pass that
 errors when a graph (or a whole bucket ladder,
 :func:`ladder_peak_bytes`) exceeds ``MXTPU_HBM_BUDGET``.
 """
@@ -207,8 +205,7 @@ class GraphCost:
         must simultaneously hold. Residency (what OOMs a chip) is
         :attr:`peak_live_bytes`; ``activation_bytes`` is likewise a
         traffic proxy (every eqn output, even values that die
-        immediately), kept byte-identical to the banked PERF_PROXY
-        families."""
+        immediately)."""
         return self.param_bytes + self.input_bytes + self.output_bytes
 
     def to_dict(self) -> dict:
@@ -533,8 +530,8 @@ def peak_live_bytes(g: TracedGraph) -> int:
     """Deterministic peak live device bytes of one traced graph —
     args + consts + the maximal simultaneously-live eqn outputs under a
     donation-aware last-use liveness scan. Zero XLA compiles; same
-    graph → same number, the property the MX709 budget gate and the
-    banked PERF_PROXY ``peak_live_bytes`` rely on."""
+    graph → same number, the property the MX709 budget gate relies
+    on."""
     return _open_jaxpr_peak(g.closed.jaxpr, _donated_mask(g))
 
 
@@ -587,7 +584,7 @@ def _fmt_mib(n: int) -> str:
 def graph_cost(g: TracedGraph) -> GraphCost:
     """Price one :class:`~.trace.TracedGraph` — THE cost function every
     surface (``analysis.hlo.cost``, the MX707 pass, ``mxlint --cost``,
-    ``bench.py --proxy``) shares, so they can never disagree."""
+    the autotuner) shares, so they can never disagree."""
     jaxpr = g.closed.jaxpr
     acc = _fresh_acc()
     _walk_jaxpr(jaxpr, 1.0, acc, g.mesh_axes)
@@ -625,7 +622,7 @@ def cost_table(graphs: List[TracedGraph]) -> List[GraphCost]:
 @dataclass
 class CostReport:
     """Cost rows for every traced graph of one entry, plus the derived
-    headline metrics the perf-proxy gate banks."""
+    headline metrics."""
 
     rows: List[GraphCost] = field(default_factory=list)
     skipped: List[str] = field(default_factory=list)

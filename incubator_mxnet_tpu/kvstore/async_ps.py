@@ -135,6 +135,7 @@ class AsyncPSServer:
         self.port = self._sock.getsockname()[1]
         self._stop = threading.Event()
         self._conns: set = set()       # live worker connections (for stop)
+        self._handlers: list = []      # their threads (stop joins them)
         self._thread = threading.Thread(target=self._serve,
                                         name="mx-kvstore-ps-accept",
                                         daemon=True)
@@ -168,6 +169,9 @@ class AsyncPSServer:
                 target=self._handle, args=(conn,),
                 name=f"mx-kvstore-ps-handler-{conn.fileno()}",
                 daemon=True)
+            with self._lock:
+                self._handlers = [h for h in self._handlers
+                                  if h.is_alive()] + [t]
             t.start()
         self._sock.close()
 
@@ -345,8 +349,8 @@ class AsyncPSServer:
 
     def stop(self, checkpoint: Optional[str] = None) -> None:
         """Graceful shutdown: optionally checkpoint the store first, then
-        stop accepting and join the accept loop (in-flight handler threads
-        finish their current reply; they are daemons)."""
+        stop accepting, join the accept loop, close the workers'
+        connections and join their handler threads."""
         if checkpoint is not None:
             self.save_checkpoint(checkpoint)
         self._stop.set()
@@ -356,6 +360,7 @@ class AsyncPSServer:
         # talking to this one's zombie handler threads.
         with self._lock:
             conns, self._conns = list(self._conns), set()
+            handlers, self._handlers = self._handlers, []
         for conn in conns:
             try:
                 conn.shutdown(socket.SHUT_RDWR)
@@ -365,6 +370,15 @@ class AsyncPSServer:
                 conn.close()
             except OSError:
                 pass
+        # A handler still inside a reply (the server-side optimizer runs
+        # jax) when the interpreter tears down is a daemon thread killed
+        # inside native frames: the process aborts ("FATAL: exception not
+        # rethrown", rc -6) after its work was done. Wait for them; each
+        # ends at its closed connection.
+        me = threading.current_thread()
+        for t in handlers:
+            if t is not me:
+                t.join(timeout=5)
 
 
 class _Client:

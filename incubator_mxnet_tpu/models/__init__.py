@@ -76,7 +76,7 @@ SERVE_SPECS = {
 #: encoder has no per-layer Dense children, so its "quantized" twin
 #: would be a float copy. This is the quantized zoo every int8 consumer
 #: iterates (``mxlint --hlo --quantized``, ``serve_bench --int8``,
-#: ``bench.py --proxy`` int8 records, ``benchmark/int8_probe.py``).
+#: the autotuner's ``quantize`` dimension).
 QUANT_FAMILIES = ("bert", "bert_encoder", "lenet", "nmt_encoder")
 
 

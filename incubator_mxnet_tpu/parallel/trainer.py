@@ -23,9 +23,8 @@ host-side per-parameter exchange over a kvstore backend (``dist_async``
 keeps its reconnect/exactly-once-resend semantics untouched) — every other
 configuration runs ONE compiled call with zero per-parameter host work.
 
-Whole-step capture (default, ``MXTPU_FUSED_STEP=0`` opts out) finishes
-the job: the guard's finite verdict and the LR-schedule position are
-computed INSIDE that one donated graph (loss/grad-norm/ok come back as
+Whole-step capture finishes the job: the guard's finite verdict and the
+LR-schedule position are computed INSIDE that one donated graph (loss/grad-norm/ok come back as
 pinned replicated outputs; the rollback decision stays on host), so a
 guarded, LR-scheduled step is still exactly one jitted graph + one host
 sync per step. Builds consult the on-disk autotune cache
@@ -93,7 +92,6 @@ class ShardedTrainer:
                  n_labels: int = 1, seq_axis: Optional[int] = None,
                  donate: bool = True, zero1: Optional[bool] = None,
                  kvstore=None, guard=None, watchdog=None,
-                 fused: Optional[bool] = None,
                  autotune_key: Optional[str] = None,
                  numerics=None):
         self._block = block
@@ -130,18 +128,15 @@ class ShardedTrainer:
         #: survives a director-driven rebuild
         self._retune_site: Optional[str] = None
         self.last_path: Optional[str] = None
-        #: whole-step capture (default on, MXTPU_FUSED_STEP=0 opts out):
-        #: the guard's finite verdict and the LR-schedule position are
-        #: computed INSIDE the one donated pjit step — loss/grad-norm/ok
-        #: come back as pinned replicated outputs, so a guarded,
-        #: scheduled step runs exactly ONE jitted graph with one host
-        #: sync; the unfused path keeps the PR-2-era shape (separate
-        #: jitted finite check, per-step host LR eval + transfer)
-        self._fused = (os.environ.get("MXTPU_FUSED_STEP", "1") == "1"
-                       if fused is None else bool(fused))
+        #: whole-step capture: the guard's finite verdict and the
+        #: LR-schedule position are computed INSIDE the one donated pjit
+        #: step — loss/grad-norm/ok come back as pinned replicated
+        #: outputs, so a guarded, scheduled step runs exactly ONE jitted
+        #: graph with one host sync
         self._lr_fold = False        # schedule folded into the step graph
-        #: jitted-executable invocations the last step() made (fused: 1;
-        #: unfused + guard: 2 — the bench.py --proxy fused_step record)
+        #: jitted-executable invocations the last step() made (the
+        #: compiled step: 1; the kvstore fallback with a guard: 2, its
+        #: fwd+bwd and the separate finite check)
         self.last_step_graphs = 0
         #: autotune-cache key (benchmark/autotune.py winners); default =
         #: the block's class name lowercased — drivers pass the family
@@ -319,10 +314,10 @@ class ShardedTrainer:
         """The explicit pjit resource contract of the compiled step:
         ``(in_shardings, out_shardings)`` NamedSharding pytrees matching
         ``step(param_vals, opt_states, key, lr, t, *batch)`` →
-        ``(loss, gnorm, new_vals, new_states, effects, t+1[, ok][, stats])``
-        (``ok`` — the in-graph guard verdict — only on the fused path;
-        ``stats`` — the per-site numerics pytree — only when numerics
-        telemetry is enabled for this build). Scalars and the RNG key
+        ``(loss, gnorm, new_vals, new_states, effects, t+1, ok[, stats])``
+        (``ok`` is the in-graph guard verdict; ``stats`` — the per-site
+        numerics pytree — only when numerics telemetry is enabled for
+        this build). Scalars and the RNG key
         replicate; parameters/optimizer shards carry their rule (+ zero1
         ``dp``) layouts in AND out, so the optimizer update is compiled
         cross-replica sharded and the next call sees identical
@@ -337,11 +332,9 @@ class ShardedTrainer:
         in_shardings = (params_sh, states_sh, repl, repl, repl) + batch_sh
         # effects (aux state: batchnorm running stats) replicate — a repl
         # prefix broadcasts over that subtree whatever its arity
-        out_shardings = (repl, repl, params_sh, states_sh, repl, repl)
-        if self._fused:
-            # the guard verdict: a pinned replicated scalar, read back in
-            # the SAME host sync as loss/grad-norm
-            out_shardings = out_shardings + (repl,)
+        # the last slot is the guard verdict: a pinned replicated scalar,
+        # read back in the SAME host sync as loss/grad-norm
+        out_shardings = (repl, repl, params_sh, states_sh, repl, repl, repl)
         if self._numerics_cfg is not None and self._numerics_cfg.enabled:
             # numerics stats: a dict subtree of small replicated vectors
             # — one repl prefix broadcasts over it whatever its arity
@@ -431,7 +424,6 @@ class ShardedTrainer:
         # (and their weak memory-ledger providers) alive past refcount
         step_info = self._info
         loss_grads = self._make_loss_grads(n_data)
-        fused = self._fused
         # LR-schedule position folded into the graph (whole-step capture):
         # with a traceable scheduler the per-step LR is a function of the
         # device-resident update counter — no host schedule eval, no
@@ -440,8 +432,7 @@ class ShardedTrainer:
         # rescales without a re-trace); the schedule position is t-1,
         # this step's optimizer.num_update.
         sched = getattr(opt, "lr_scheduler", None)
-        fold_lr = bool(fused and sched is not None
-                       and hasattr(sched, "jax_lr"))
+        fold_lr = sched is not None and hasattr(sched, "jax_lr")
         self._lr_fold = fold_lr
         base_lr = (float(getattr(sched, "base_lr", 0.0) or 0.0)
                    if fold_lr else None)
@@ -497,17 +488,13 @@ class ShardedTrainer:
                             for a, sh in zip(nst, state_shardings[i]))
                 new_vals.append(nv)
                 new_states.append(nst)
-            if fused:
-                # the guard's finite check, captured in-graph: one fused
-                # reduction instead of a separate jitted call — the
-                # rollback DECISION stays on host (_apply_guard)
-                ok = jnp.logical_and(jnp.isfinite(loss).all(),
-                                     jnp.isfinite(gnorm))
-                out = (loss, gnorm, tuple(new_vals), tuple(new_states),
-                       effects, t + 1, ok)
-            else:
-                out = (loss, gnorm, tuple(new_vals), tuple(new_states),
-                       effects, t + 1)
+            # the guard's finite check, captured in-graph: one fused
+            # reduction instead of a separate jitted call — the rollback
+            # DECISION stays on host (_apply_guard)
+            ok = jnp.logical_and(jnp.isfinite(loss).all(),
+                                 jnp.isfinite(gnorm))
+            out = (loss, gnorm, tuple(new_vals), tuple(new_states),
+                   effects, t + 1, ok)
             if num_on:
                 out = out + (stats,)
             return out
@@ -864,14 +851,9 @@ class ShardedTrainer:
                         if num_on:
                             stats_dev = out[-1]
                             out = out[:-1]
-                        if self._fused:
-                            (loss, gnorm, self._param_vals,
-                             self._opt_states, effects, self._t_dev,
-                             ok) = out
-                        else:
-                            (loss, gnorm, self._param_vals,
-                             self._opt_states, effects,
-                             self._t_dev) = out
+                        (loss, gnorm, self._param_vals,
+                         self._opt_states, effects, self._t_dev,
+                         ok) = out
                 self.last_path = "kvstore_fallback" if fallback else "pjit"
                 dispatch_ms = sent.dur_ms
                 from ..telemetry import collective_ledger as _cledger
@@ -931,8 +913,7 @@ class ShardedTrainer:
                       "place_ms": round(placed.dur_ms, 3),
                       "dispatch_ms": round(dispatch_ms, 3),
                       "path": self.last_path,
-                      "graphs": self.last_step_graphs,
-                      "fused": self._fused and not fallback}
+                      "graphs": self.last_step_graphs}
             if self._guard is not None:
                 # guard runs synced loss/grad-norm to host — free to report
                 fields.update(loss=self.last_loss,
@@ -1023,11 +1004,11 @@ class ShardedTrainer:
     def _apply_guard(self, loss, gnorm, ok=None, stats_dev=None,
                      step=None) -> bool:
         """Returns True when the step was rolled back. ``ok`` is the
-        fused step's in-graph finite verdict — everything comes back in
-        ONE host sync (``stats_dev``, the decimated numerics outputs,
-        joins that same sync when due). Without ``ok`` (unfused/fallback
-        path) the finite check is the PR-2-era SEPARATE jitted
-        reduction, one more graph on this step's dispatch count.
+        compiled step's in-graph finite verdict — everything comes back
+        in ONE host sync (``stats_dev``, the decimated numerics outputs,
+        joins that same sync when due). Without ``ok`` (the kvstore
+        fallback) the finite check is a SEPARATE jitted reduction, one
+        more graph on this step's dispatch count.
 
         Escalation ordering: a real non-finite/limit verdict always
         wins; otherwise a sustained ``numerics.drift`` verdict (under

@@ -45,8 +45,7 @@ triggers one correct remediation and can never oscillate A→B→A.
 Everything is **off by default** (``MXTPU_DIRECTOR`` unset):
 :func:`install` is one env read and returns ``None``; nothing subscribes,
 no hot path changes, and the compiled graphs are untouched either way
-(host-side bookkeeping only — the perf-proxy CI gate proves banked
-PERF_PROXY.json stays byte-identical, same as numerics/goodput).
+(host-side bookkeeping only, same as numerics/goodput).
 
 Usage::
 

@@ -48,9 +48,8 @@ predicted-vs-measured divergence is itself a tracked metric
 Everything is **off by default** (``MXTPU_GOODPUT`` unset): the hooks in
 the trainer/io/checkpoint hot paths are one :func:`enabled` check, the
 compiled graphs are untouched either way (the ledger is host-side
-bookkeeping only — the perf-proxy CI gate proves banked PERF_PROXY.json
-stays byte-identical, and the fused step still runs exactly one jitted
-graph with the ledger on).
+bookkeeping only, and the step still runs exactly one jitted graph with
+the ledger on).
 
 Usage::
 
@@ -68,8 +67,8 @@ Every ``MXTPU_GOODPUT_WINDOW`` steps the ledger emits one
 ``goodput.window`` event and refreshes the ``mxtpu_goodput_*`` gauges;
 ``telemetry.snapshot()``, flight bundles, and ``tools/postmortem.py``
 all carry the full report. ``tools/perf_history.py`` is the offline
-twin: it merges the banked ``BENCH_r*.json`` / ``MULTICHIP_r*.json`` /
-``PERF_PROXY.json`` artifacts into one trajectory with regression flags.
+twin: it merges the banked ``BENCH_r*.json`` / ``MULTICHIP_r*.json``
+artifacts into one trajectory with regression flags.
 """
 from __future__ import annotations
 

@@ -37,8 +37,8 @@ allowlist over those names (empty = all), so a 300-parameter model can
 watch just ``grad:*attn*``.
 
 Everything is **off by default** (``MXTPU_NUMERICS`` unset): the traced
-graphs are bit-identical to a build that never imported this module —
-the perf-proxy CI gate proves banked PERF_PROXY.json stays byte-equal.
+graphs are those of a build that never imported this module (the step
+keeps its arity and no stat output is traced).
 
 Usage::
 

@@ -58,7 +58,7 @@ def hbm_budget_bytes() -> Optional[int]:
 #: "tpu v5" row): ``(bf16 TFLOP/s, HBM GB/s, ICI GB/s)``. Source: Google
 #: Cloud documentation, "TPU v5e" (197 TFLOP/s, 819 GB/s, 1,600 Gbit/s
 #: of interconnect) and "TPU v5p" (459 TFLOP/s, 2,765 GB/s, 4,800
-#: Gbit/s). THE single table ``bench.py``, ``benchmark/autotune.py`` and
+#: Gbit/s). THE single table ``benchmark/autotune.py`` and
 #: ``telemetry.goodput`` read. A kind that is not here is an error on
 #: the measuring path: add its row, with its source, before measuring.
 DEVICE_PEAKS_BY_KIND = {
@@ -68,8 +68,8 @@ DEVICE_PEAKS_BY_KIND = {
     "tpu v5p": (459.0, 2765.0, 600.0),
 }
 #: the unit chip trace-only callers rank candidates against when no
-#: known chip is attached (``bench.py --proxy``, the autotuner's roofline
-#: score, goodput's predicted MFU on CPU): ``(TFLOP/s, HBM GB/s, ICI
+#: known chip is attached (the autotuner's roofline score, goodput's
+#: predicted MFU on CPU): ``(TFLOP/s, HBM GB/s, ICI
 #: GB/s)``. It keeps rankings deterministic; it is no device's peak and
 #: nothing divided by it is a device metric.
 RANKING_NOMINAL_CHIP = (459.0, 1200.0, 90.0)
@@ -232,20 +232,6 @@ ENV_VARS: Dict[str, tuple] = {
                                   "back to the queue at most this many "
                                   "times before the stream is shed with "
                                   "CacheExhausted."),
-    "MXTPU_BENCH_MODEL": ("bert_12_768_12", "bench.py model config."),
-    "MXTPU_BENCH_TRACE": ("", "bench.py: capture one profiled step into this "
-                          "directory (jax.profiler trace)."),
-    "MXTPU_BENCH_RETRIES": ("1", "bench.py device-init watchdog: extra "
-                            "bounded windows granted after the first "
-                            "MXTPU_BENCH_TIMEOUT expiry before aborting "
-                            "with rc=75 (0 = abort on the first expiry). "
-                            "The abort record's 'attempts' field counts "
-                            "the windows waited."),
-    "MXTPU_BENCH_RETRY_BACKOFF_S": ("60", "Seconds ADDED to the watchdog "
-                                    "budget for each retry window — a "
-                                    "pool grant that lands late becomes "
-                                    "a recovered round, not a blind "
-                                    "one."),
     "MXTPU_PEAK_TFLOPS": ("", "Override per-chip peak for MFU accounting."),
     "MXTPU_FLASH_ATTENTION": ("1", "Enable the Pallas flash-attention path."),
     "MXTPU_FLASH_BK": ("", "Flash-attention key/value block size override "
@@ -259,15 +245,6 @@ ENV_VARS: Dict[str, tuple] = {
     "MXTPU_EMBED_ONEHOT_GRAD": ("0", "Embedding weight gradient as a one-hot "
                                 "MXU matmul instead of scatter-add (sweep "
                                 "candidate; numerically identical)."),
-    "MXTPU_FUSED_STEP": ("1", "Whole-step capture (ShardedTrainer): the "
-                         "guard finite verdict and the LR-schedule "
-                         "position are computed INSIDE the one donated "
-                         "pjit step — a guarded, scheduled step runs "
-                         "exactly one jitted graph with one host sync. "
-                         "0 restores the unfused shape (separate jitted "
-                         "finite check, per-step host LR eval + "
-                         "transfer) for A/B probes and bit-parity "
-                         "tests."),
     "MXTPU_AUTOTUNE_DIR": ("", "On-disk autotune cache root. When set, "
                            "ShardedTrainer and serve.CompiledModel "
                            "consult it at build time and overlay the "
@@ -331,8 +308,8 @@ ENV_VARS: Dict[str, tuple] = {
                        "accumulates log2-magnitude histograms per site "
                        "(quantization.Observer calibration tables). "
                        "Unset/other = off: the traced graphs are "
-                       "byte-identical to an uninstrumented build "
-                       "(the perf-proxy gate proves it). Resolved at "
+                       "byte-identical to an uninstrumented build. "
+                       "Resolved at "
                        "build time like the autotune consult."),
     "MXTPU_NUMERICS_EVERY": ("16", "Host-side decimation of numerics "
                              "stats: the stat outputs are synced (and "
@@ -370,9 +347,7 @@ ENV_VARS: Dict[str, tuple] = {
                       "goodput-smoke CI job), with a measured-vs-"
                       "roofline MFU headline. Host-side bookkeeping "
                       "only — the compiled graphs are untouched either "
-                      "way (the perf-proxy gate proves banked "
-                      "PERF_PROXY.json stays byte-identical). Default "
-                      "off: the trainer/io/checkpoint hooks are one "
+                      "way. Default off: the trainer/io/checkpoint hooks are one "
                       "env read."),
     "MXTPU_GOODPUT_WINDOW": ("32", "Steps per goodput attribution "
                              "window: each window closes with one "

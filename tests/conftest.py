@@ -30,8 +30,12 @@ else:
 
     jax.config.update("jax_platforms", "cpu")
 
+import importlib.util
+
 import numpy as onp
 import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def pytest_configure(config):
@@ -73,6 +77,22 @@ def _seed_all(request):
     failed = getattr(getattr(request.node, "rep_call", None), "failed", False)
     if failed:
         print(f"To reproduce: MXNET_TEST_SEED={seed}")
+
+
+@pytest.fixture
+def load_script():
+    """``load_script("examples/train_mnist.py")``: a script of the repo,
+    by its path from the root, as a module (the one-example-per-file
+    ``test_example_*.py``, ``chip_smoke.py``, ``benchmark/int8_probe.py``).
+    Importing one starts nothing: each runs under its ``__main__`` check."""
+    def load(relpath):
+        name = os.path.splitext(os.path.basename(relpath))[0]
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(REPO, relpath))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+    return load
 
 
 @pytest.hookimpl(tryfirst=True, hookwrapper=True)

@@ -5,10 +5,9 @@ winner twice), the CRC-manifested autotune cache (roundtrip + corrupt
 eviction), consult-on-build by BOTH ShardedTrainer and CompiledModel
 (ledger site attribution + a graph-level proof the winner's env knob
 actually applied), fused whole-step capture (ONE jitted graph per
-guarded+scheduled step, bit-identical first losses vs the unfused path,
-MX704/MX708 clean), the LR-schedule fold, the device PrefetchIter
-(ordering + shutdown under chaos slow_step), the recalibrated adaptive
-watchdog default, and the bert_sweep VARIANTS derivation."""
+guarded+scheduled step, MX704/MX708 clean), the LR-schedule fold against
+the host-evaluated schedule, the device PrefetchIter (ordering + shutdown
+under chaos slow_step) and the recalibrated adaptive watchdog default."""
 import json
 import os
 
@@ -154,17 +153,6 @@ class TestSearchDriver:
         assert full == driver.candidates("bert")
         assert driver.candidates("bert", 5) == full[:5]
 
-    def test_bench_variants_derived(self):
-        from benchmark import bert_sweep
-        assert bert_sweep.VARIANTS == driver.bench_variants()
-        names = [n for n, _ in bert_sweep.VARIANTS]
-        assert "default-B8" in names and "flash-BK256" in names \
-            and "B4-L1024" in names
-        # the derived env deltas reference the declared dims
-        deltas = dict(bert_sweep.VARIANTS)
-        assert deltas["flash-BK256"] == {"MXTPU_FLASH_BK": "256"}
-        assert deltas["embed-onehot-grad"] == {"MXTPU_EMBED_ONEHOT_GRAD": "1"}
-
     def test_quantize_dim_searched_and_deterministic(self):
         """quantize ∈ {off, int8} is a real searched dimension: it is
         declared LAST so it varies fastest, and a budget-truncated
@@ -289,28 +277,11 @@ class TestConsultOnBuild:
 # ---------------------------------------------------------------------------
 
 class TestFusedStep:
-    def test_bit_identical_first_two_losses(self, monkeypatch):
-        """The fused step (guard verdict + LR position in-graph) must be
-        numerically invisible: first two losses bit-identical to the
-        unfused path."""
-        x, y = _batch()
-        monkeypatch.setenv("MXTPU_FUSED_STEP", "1")
-        tr_f = _trainer(guard=fault.StepGuard(policy="warn"))
-        lf = [float(tr_f.step(x, y).asnumpy()) for _ in range(2)]
-        assert tr_f.last_step_graphs == 1
-        monkeypatch.setenv("MXTPU_FUSED_STEP", "0")
-        tr_u = _trainer(guard=fault.StepGuard(policy="warn"))
-        lu = [float(tr_u.step(x, y).asnumpy()) for _ in range(2)]
-        # the unfused path pays the PR-2-era separate jitted finite check
-        assert tr_u.last_step_graphs == 2
-        assert lf == lu
-
-    def test_one_postwarmup_graph_on_ledger(self, monkeypatch):
+    def test_one_postwarmup_graph_on_ledger(self):
         """The acceptance contract: a guarded + LR-scheduled fused step
         runs steady state with exactly ONE jitted graph — no
         fault.guards.finite entries, zero post-warmup compiles at
         trainer.step."""
-        monkeypatch.setenv("MXTPU_FUSED_STEP", "1")
         tr = _trainer(guard=fault.StepGuard(policy="warn"),
                       optimizer_params={
                           "learning_rate": 1e-3,
@@ -329,40 +300,38 @@ class TestFusedStep:
         assert len(compile_log.records("fault.guards.finite")) \
             == before_finite
 
-    def test_unfused_guard_lands_on_ledger(self, monkeypatch):
-        monkeypatch.setenv("MXTPU_FUSED_STEP", "0")
-        tr = _trainer(guard=fault.StepGuard(policy="warn"))
-        x, y = _batch()
-        before = len(compile_log.records("fault.guards.finite"))
-        tr.step(x, y)
-        assert len(compile_log.records("fault.guards.finite")) >= before
-
-    def test_lr_fold_matches_host_schedule(self, monkeypatch):
+    def test_lr_fold_matches_host_schedule(self):
         """Folded LR follows the host scheduler's trajectory: two
-        trainers (folded vs unfused host-mirror LR) track each other
-        across a moving schedule."""
-        sched = dict(optimizer_params={
-            "learning_rate": 0.05,
-            "lr_scheduler": lr_scheduler.FactorScheduler(
-                step=2, factor=0.5, base_lr=0.05)})
+        trainers track each other across a moving schedule, one with
+        the schedule folded into the step, one whose scheduler is a
+        plain callable (no ``jax_lr``: what a custom scheduler is), so
+        its LR is evaluated on the host and sent each time it moves."""
+        def params(wrap):
+            return dict(optimizer_params={
+                "learning_rate": 0.05,
+                "lr_scheduler": wrap(lr_scheduler.FactorScheduler(
+                    step=2, factor=0.5, base_lr=0.05))})
+
+        def plain(inner):
+            def sched(num_update):
+                return inner(num_update)
+            return sched
+
         x, y = _batch()
-        monkeypatch.setenv("MXTPU_FUSED_STEP", "1")
-        tr_f = _trainer(**sched)
+        tr_f = _trainer(**params(lambda inner: inner))
         lf = [float(tr_f.step(x, y).asnumpy()) for _ in range(6)]
         assert tr_f._lr_fold
-        monkeypatch.setenv("MXTPU_FUSED_STEP", "0")
-        tr_u = _trainer(**sched)
-        lu = [float(tr_u.step(x, y).asnumpy()) for _ in range(6)]
-        assert not tr_u._lr_fold
+        tr_h = _trainer(**params(plain))
+        lh = [float(tr_h.step(x, y).asnumpy()) for _ in range(6)]
+        assert not tr_h._lr_fold
         # float32-device vs float64-host schedule eval: tight allclose,
         # first step (schedule still at base) bit-identical
-        assert lf[0] == lu[0]
-        onp.testing.assert_allclose(lf, lu, rtol=1e-5, atol=1e-7)
+        assert lf[0] == lh[0]
+        onp.testing.assert_allclose(lf, lh, rtol=1e-5, atol=1e-7)
 
-    def test_lr_fold_live_base_override(self, monkeypatch):
+    def test_lr_fold_live_base_override(self):
         """A mid-run ``sched.base_lr`` override reaches the folded
         schedule through the lr input — no re-trace."""
-        monkeypatch.setenv("MXTPU_FUSED_STEP", "1")
         sched = lr_scheduler.FactorScheduler(step=1000, factor=1.0,
                                              base_lr=0.05)
         tr = _trainer(optimizer_params={"learning_rate": 0.05,
@@ -432,10 +401,9 @@ class TestFusedStep:
         rep = hlo.cost(tr, sample_args=(x, y))
         assert rep.model_flops_per_step() > 0
 
-    def test_guard_rollback_still_works_fused(self, monkeypatch):
+    def test_guard_rollback_still_works_fused(self):
         """The rollback decision stays on host: a NaN batch under
         skip_and_rollback restores the snapshot exactly as before."""
-        monkeypatch.setenv("MXTPU_FUSED_STEP", "1")
         tr = _trainer(guard=fault.StepGuard(policy="skip_and_rollback"))
         x, y = _batch()
         tr.step(x, y)
@@ -588,48 +556,3 @@ class TestWatchdogRecalibration:
         assert wd._ema_s is not None
         assert 0.002 <= wd._ema_s < 0.05
 
-
-# ---------------------------------------------------------------------------
-# bench.py --proxy fused_step record
-# ---------------------------------------------------------------------------
-
-def _bench():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_autotune", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    return bench
-
-
-class TestFusedStepProxyRecord:
-    def test_record_shape_and_gate_keys(self):
-        bench = _bench()
-        rec = bench._fused_step_record(steps=2)
-        assert rec["graphs_per_step"] == 1
-        assert rec["graphs_per_step_unfused"] == 2
-        assert rec["flops_per_step"] > 0
-        # deterministic metrics are gated; wall-times are volatile
-        assert "graphs_per_step" in bench._PROXY_GATE_KEYS
-        assert "host_gap_ms_fused" in bench._PROXY_VOLATILE_KEYS
-        banked_like = {k: v for k, v in rec.items()
-                       if k not in bench._PROXY_VOLATILE_KEYS}
-        failures, warns = bench._proxy_compare(
-            {"fused_step": rec}, {"fused_step": banked_like}, 0.05)
-        assert failures == [] and warns == []
-
-    def test_banked_train_section_matches_current_tree(self):
-        # PERF_PROXY.json's train section must gate clean against the
-        # current code — the CI perf-proxy job's exact contract for the
-        # fused-step metrics
-        bench = _bench()
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        with open(os.path.join(repo, "PERF_PROXY.json")) as f:
-            banked = json.load(f)
-        assert "fused_step" in banked.get("train", {})
-        rec = bench._fused_step_record(steps=2)
-        failures, warns = bench._proxy_compare(
-            {"fused_step": rec}, banked["train"], banked["tolerance"])
-        assert failures == [], failures
-        assert warns == [], warns

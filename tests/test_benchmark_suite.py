@@ -1,0 +1,64 @@
+"""The yardstick's own tests, in tier-1: every case of ``chipbench/tests/``
+is collected here under a test ID of its own, so ``pytest tests/`` runs the
+code every PR is judged by.
+
+``chipbench/tests/`` is not on ``pytest tests/``'s path, and run on their
+own its rehearsals find one CPU device where ``bert_base_pretrain.dp4``
+needs four: ``tests/conftest.py`` forces eight, and the children a case
+starts inherit that. The modules are loaded by path under names of their
+own (``chipbench/tests/test_chipbench.py`` would clash by basename with a
+``tests/test_chipbench.py``), and each ``test_*`` is bound here as
+``test_<module>__<case>``: two of the modules have a case of the same name.
+Fixtures keep their names. Nothing under ``chipbench/`` is edited.
+
+One case is left out (``_LEFT_OUT``): the rehearsal of
+``bert_base_pretrain.dp4``. Its falling-loss check compares the first ten
+losses with the last ten, and a one-second window of four-device CPU steps
+holds 14-19 of them on an idle machine and under eight beside five busy
+workers, where the two tens are the same losses: it passes alone and fails
+in tier-1. The repair (a floor of steps in a rehearsal, or a check that
+knows how many it has) is an edit under ``chipbench/``: ROADMAP R13.
+"""
+import glob
+import importlib.util
+import os
+
+import pytest
+from _pytest.fixtures import getfixturemarker
+
+_HERE = os.path.dirname(os.path.abspath(__file__))
+_SUITE = os.path.join(os.path.dirname(_HERE), "chipbench", "tests")
+
+#: case -> (its ``parametrize`` argument, the values left out)
+_LEFT_OUT = {"test_rehearsal_prints_the_contract_line_and_exits_3":
+             ("cell", {"bert_base_pretrain.dp4"})}
+
+
+def _without(fn, arg, dropped):
+    """``fn`` with ``dropped`` taken out of its ``parametrize(arg, ...)``."""
+    fn.pytestmark = [
+        pytest.mark.parametrize(
+            arg, [v for v in m.args[1] if v not in dropped]).mark
+        if m.name == "parametrize" and m.args[0] == arg else m
+        for m in fn.pytestmark]
+    return fn
+
+
+def _bind(path):
+    short = os.path.basename(path)[len("test_"):-len(".py")]
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_tests_{short}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    for name, obj in vars(mod).items():
+        if name.startswith("test_") and callable(obj):
+            if name in _LEFT_OUT:
+                obj = _without(obj, *_LEFT_OUT[name])
+            globals()[f"test_{short}__{name[len('test_'):]}"] = obj
+        elif getfixturemarker(obj) is not None:
+            globals()[name] = obj
+
+
+for _path in sorted(glob.glob(os.path.join(_SUITE, "test_*.py"))):
+    _bind(_path)
+

@@ -74,3 +74,35 @@ def test_fails_alone_in_a_directory(tmp_path):
     assert proc.returncode not in (0, 3)
     assert _SUCCESS not in proc.stdout and '"ok"' not in proc.stdout
     assert "ModuleNotFoundError" in proc.stderr
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_bert_trainer_is_the_bf16_job_with_fp32_masters(remat,
+                                                        load_script):
+    """The job every chip phase trains, at ``bert_2_128_2``: bf16
+    weights, an fp32 master first in each one's optimizer state,
+    one finite compiled step; ``remat=`` reaches ``get_bert`` (the fork a
+    BERT-large run needs to fit must not be first exercised there)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as onp
+    from incubator_mxnet_tpu import parallel
+    # importing it starts no backend and makes no cache: main() does both
+    smoke = load_script("chip_smoke.py")
+    overrides = {"remat": True} if remat else {}
+    net, trainer = smoke.bert_trainer(
+        "bert_2_128_2", 64, parallel.make_mesh(devices=jax.devices()[:1]),
+        **overrides)
+    assert net.encoder._remat is remat
+    loss = float(trainer.step(*smoke.bert_batch(2, 64)).asnumpy())
+    assert onp.isfinite(loss)
+    assert trainer.last_path == "pjit" and trainer.last_step_graphs == 1
+    names = sorted(net.collect_params())
+    half = [(n, v, s) for n, v, s in zip(names, trainer._param_vals,
+                                         trainer._opt_states)
+            if v.dtype == jnp.bfloat16]
+    # every matmul weight is bf16 (layer norms keep fp32 scales, PR 26)
+    assert {n for n in names if n.endswith("weight")} \
+        <= {n for n, _, _ in half}
+    assert all(s[0].dtype == jnp.float32 and s[0].shape == v.shape
+               for _, v, s in half)

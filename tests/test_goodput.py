@@ -478,12 +478,19 @@ def test_perf_history_flags_seeded_regression(tmp_path):
     assert ph.main(["--dir", str(tmp_path), "--check"]) == 0
 
 
+def _watchdog_record(budget, attempts=1):
+    return {"error": "device_init_timeout", "attempts": attempts,
+            "goodput": None, "metric": None, "value": None, "unit": None,
+            "vs_baseline": None,
+            "extra": {"timeout_s": budget, "rc": 75, "workload": "bert",
+                      "model": "bert_12_768_12"}}
+
+
 def test_perf_history_renders_goodput_null_abort_record(tmp_path):
     ph = _ph()
-    # the new structured rc=75 abort record (bench._watchdog_record)
-    import bench
-    rec = bench._watchdog_record(1500)
-    assert rec["goodput"] is None and rec["error"] == "device_init_timeout"
+    # the structured rc=75 abort record the deleted bench script's
+    # watchdog printed, as the driver's old records hold it
+    rec = _watchdog_record(1500)
     (tmp_path / "BENCH_r07.json").write_text(json.dumps(
         {"n": 7, "rc": 75, "parsed": rec}))
     doc = ph.collect(str(tmp_path))
@@ -498,13 +505,11 @@ def test_perf_history_renders_retry_attempts(tmp_path):
     never having been given one; pre-retry records (no field) render
     neither."""
     ph = _ph()
-    import bench
     (tmp_path / "BENCH_r01.json").write_text(json.dumps(
-        {"n": 1, "rc": 75, "parsed": bench._watchdog_record(900,
-                                                            attempts=2)}))
+        {"n": 1, "rc": 75, "parsed": _watchdog_record(900, attempts=2)}))
     (tmp_path / "BENCH_r02.json").write_text(json.dumps(
-        {"n": 2, "rc": 75, "parsed": bench._watchdog_record(900)}))
-    legacy = bench._watchdog_record(900)
+        {"n": 2, "rc": 75, "parsed": _watchdog_record(900)}))
+    legacy = _watchdog_record(900)
     legacy.pop("attempts")
     (tmp_path / "BENCH_r03.json").write_text(json.dumps(
         {"n": 3, "rc": 75, "parsed": legacy}))
@@ -528,8 +533,8 @@ def test_bench_gate_embeds_perf_history(tmp_path):
 
 def test_perf_history_check_passes_on_the_repo_root():
     """CI runs ``perf_history.py --check`` on the checkout, which keeps no
-    BENCH_r*/MULTICHIP_r* records: it must still render (PERF_PROXY.json
-    and BASELINE.md's dated tables) and exit 0."""
+    BENCH_r*/MULTICHIP_r* records: it must still render (BASELINE.md's
+    dated tables) and exit 0."""
     ph = _ph()
     assert not ph.collect_bench(REPO_ROOT)
     assert not ph.collect_multichip(REPO_ROOT)

@@ -48,7 +48,7 @@ def _mlp(units=16, in_units=32, prefix="memmlp_"):
 class TestLiveness:
     def test_serve_family_peaks_deterministic(self):
         # two independent builds of the same zoo family price to the
-        # SAME peak — the property the banked PERF_PROXY peak gate needs
+        # SAME peak — the property the MX709 budget gate needs
         from incubator_mxnet_tpu import models
         from incubator_mxnet_tpu.analysis import hlo
         reps = [hlo.cost(models.hlo_smoke("lenet")["compiled"],

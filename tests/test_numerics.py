@@ -45,11 +45,11 @@ def _net(prefix, in_units=12, units=16, classes=4):
     return net
 
 
-def _trainer(prefix, guard=None, numerics_cfg=None, fused=None, **kw):
+def _trainer(prefix, guard=None, numerics_cfg=None, **kw):
     return parallel.ShardedTrainer(
         _net(prefix), gluon.loss.SoftmaxCrossEntropyLoss(), "sgd",
         {"learning_rate": 0.05}, mesh=parallel.make_mesh(dp=4, tp=2),
-        guard=guard, numerics=numerics_cfg, fused=fused, **kw)
+        guard=guard, numerics=numerics_cfg, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -168,7 +168,7 @@ def test_trainer_off_path_unchanged():
     x, y = _batch()
     tr.step(x, y)
     _, outs = tr.step_shardings(tuple(v.ndim for v in tr.place(x, y)))
-    assert len(outs) == 7        # fused: ... + ok, NO stats slot
+    assert len(outs) == 7        # ... + ok, NO stats slot
     on = NumericsConfig(mode="summary")
     tr2 = _trainer("numo2_", numerics_cfg=on)
     tr2.step(x, y)
@@ -336,27 +336,6 @@ def test_drift_fires_before_nonfinite_guard_fused():
     assert drift and guard_evs
     assert drift[0].seq < guard_evs[0].seq
     assert tr.last_step_graphs == 1
-
-
-@pytest.mark.chaos
-def test_drift_fires_before_nonfinite_guard_unfused():
-    """Unfused path (MXTPU_FUSED_STEP=0 shape): guard runs its separate
-    jitted finite check (2 graphs/step) — numerics stats still ride the
-    ONE step graph and the drift ordering holds."""
-    cfg = NumericsConfig(mode="summary", every=1)
-    guard = fault.StepGuard(policy="halt")
-    tr = _trainer("numu_", guard=guard, numerics_cfg=cfg, fused=False)
-    x, y = _batch()
-    before = len(compile_log.records("trainer.step"))
-    with fault.inject.chaos(seed=7, grad_blowup=1.0, blowup_factor=16.0):
-        with pytest.raises(fault.NonFiniteError):
-            for _ in range(120):
-                tr.step(x, y)
-    assert tr.last_step_graphs == 2     # step + separate finite check
-    assert len(compile_log.records("trainer.step")) == before + 1
-    drift = telemetry.get_events("numerics.drift")
-    guard_evs = telemetry.get_events("guard")
-    assert drift and guard_evs and drift[0].seq < guard_evs[0].seq
 
 
 @pytest.mark.chaos

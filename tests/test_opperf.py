@@ -20,7 +20,30 @@ def test_run_subset():
         assert "gflops" in r
 
 
-def test_every_config_entry_is_well_formed():
+class _ShapesOnly:
+    """Stands in for the builders' ``RandomState``: what they draw has the
+    asked shape and dtype and no element of its own (a zero-strided view).
+    Drawn for real, the operands are over a billion normals through one
+    generator (``LayerNorm`` alone is 64x512x32768): five minutes, to
+    check names and a ``dict``."""
+
+    class _Drawn:
+        def __init__(self, shape):
+            self.shape = tuple(shape)
+
+        def astype(self, dtype):
+            return onp.broadcast_to(onp.zeros((), dtype), self.shape)
+
+    def randn(self, *shape):
+        return self._Drawn(shape)
+
+    def randint(self, low, high, size):
+        return self._Drawn(size)
+
+
+def test_every_config_entry_is_well_formed(monkeypatch):
+    import benchmark.opperf as opperf
+    monkeypatch.setattr(opperf, "_rng", _ShapesOnly)
     cfg = op_configs()
     from incubator_mxnet_tpu.ops.registry import OPS
     for name, cases in cfg.items():

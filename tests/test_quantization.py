@@ -393,3 +393,17 @@ def test_registry_rejects_mx711_dirty_version_while_active_serves():
     assert reg.active_version("m") == 1          # v1 kept serving
     onp.testing.assert_array_equal(reg.get("m").predict(x).asnumpy(),
                                    before)
+
+
+def test_int8_probe_contract(monkeypatch, capsys, load_script):
+    # tiny shapes: the contract (one JSON dict, finite timings, HLO verdict
+    # booleans) is what's under test — a chip run uses the real sizes
+    for k, v in (("MXTPU_INT8_BATCH", "64"), ("MXTPU_INT8_IN", "64"),
+                 ("MXTPU_INT8_OUT", "64"), ("MXTPU_INT8_ITERS", "2")):
+        monkeypatch.setenv(k, v)
+    import json
+    load_script("benchmark/int8_probe.py").main()
+    rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rec["metric"] == "int8_dense_vs_bf16"
+    assert rec["int8_ms"] > 0 and rec["bf16_ms"] > 0
+    assert isinstance(rec["hlo_has_int8_dot"], bool)

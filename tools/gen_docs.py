@@ -601,9 +601,10 @@ def gen_profiler() -> str:
            "frame total minus all children) |", "",
            "`host_gap_ms_*` = frame time minus device-side segments "
            "(`device_wait`, `serve.compute`) — the host dispatch tax "
-           "ROADMAP open item 2 drives toward zero. The `bench.py "
-           "--proxy` record and the CI `perf-proxy` gate report it per "
-           "serving family; `telemetry.snapshot()` embeds both reports.",
+           "ROADMAP open item 2 drives toward zero. On the chip the "
+           "benchmark reads the same spans from the device trace "
+           "(`host_step_ms.train`, `step_*_ms.train`); "
+           "`telemetry.snapshot()` embeds both reports.",
            "", "## API", "", "| Name | Summary |", "|---|---|"]
     for name in profiler.__all__:
         obj = getattr(profiler, name, None)

@@ -11,8 +11,7 @@ the whole contract end to end:
 2. **one_graph_per_step** / **ledger_clean** — with the ledger ON the
    fused step still runs exactly ONE jitted executable and the compile
    ledger stays clean post-warmup (goodput is host-side bookkeeping:
-   the compiled graphs are untouched — the perf-proxy CI job proves
-   the byte-identity half with the ledger OFF);
+   the compiled graphs are untouched);
 3. **mfu_reconciled** — ``price()`` installs the cost-model roofline
    and the report carries measured vs predicted MFU plus their
    divergence (the "why is MFU stuck" number);
@@ -23,8 +22,8 @@ the whole contract end to end:
 5. **window_events** — ``goodput.window`` events landed on the bus
    (the stream is then independently validated by telemetry_check);
 6. **perf_history** — ``tools/perf_history.py`` renders the banked
-   trajectory from the repo artifacts (BASELINE.md's dated sweep tables
-   and PERF_PROXY.json; the checkout keeps no per-round records): the
+   trajectory from the repo artifacts (BASELINE.md's dated sweep
+   tables; the checkout keeps no per-round records): the
    0.3789-MFU best config is reproduced and no regression flags.
 
 Prints one JSON line of gates; exit 0 = all green, 1 = any gate red.

@@ -16,9 +16,7 @@ chips: ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` gives an
 4. runs the mxlint gates on the live trainer step graph: the MX7xx HLO
    passes (incl. MX708, the per-param-host-round-trip/donation contract)
    must report zero errors, and the MX3xx sharding pass must accept the
-   rule table against the mesh;
-5. measures the host dispatch gap of the mesh step vs the per-param loop
-   (``bench._mesh_step_record``) and asserts mesh <= loop.
+   rule table against the mesh.
 
 Prints ONE strict-JSON line; exit 0 = every gate held. ``hlo_target()``
 doubles as an ``mxlint --hlo tools.multichip_smoke:hlo_target`` factory
@@ -304,17 +302,6 @@ def main() -> int:
                              for n, p in tr._block.collect_params().items()})
     gate("sharding_rules_clean", srep.ok,
          {"codes": sorted({d.code for d in srep.diagnostics})})
-
-    # -- gate 5: host gap at or below the per-param loop path -----------
-    import importlib.util
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "bench_smoke", os.path.join(repo, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    rec = bench._mesh_step_record()
-    gate("mesh_host_gap_at_or_below_loop",
-         rec["host_gap_ms_mesh"] <= rec["host_gap_ms_unsharded"], rec)
 
     out["ok"] = not fails
     out["failed"] = fails

@@ -326,8 +326,8 @@ def main(argv=None) -> int:
             n_hlo += 1
             try:
                 # one trace per target: the MX7xx passes and the cost
-                # table price the SAME TracedGraph records, so the CLI
-                # and the CI perf-proxy gate can never disagree
+                # table price the SAME TracedGraph records, so the
+                # diagnostics and the cost rows can never disagree
                 traced = analysis.hlo.trace_entry(entry, sample)
                 report.extend(analysis.hlo.verify_trace(
                     traced, cost=args.cost, quant=args.quantized))

@@ -22,9 +22,9 @@ contract end to end:
    byte-for-byte.
 
 Prints one JSON line of gates; exit 0 = all green, 1 = any gate red.
-The companion perf-proxy CI job proves the OTHER half of the contract:
-with ``MXTPU_NUMERICS`` unset (the default) the traced graphs — hence
-banked PERF_PROXY.json — are byte-identical to an uninstrumented build.
+With ``MXTPU_NUMERICS`` unset (the default) the traced graphs are those
+of an uninstrumented build (``tests/test_numerics.py::
+test_trainer_off_path_unchanged``).
 
     MXTPU_TELEMETRY_JSONL=events.jsonl python tools/numerics_smoke.py
 """

@@ -1,17 +1,17 @@
 #!/usr/bin/env python
 """Merge the banked perf artifacts into one trajectory report.
 
-The repo banks performance evidence in three disconnected shapes: the
+The repo banked performance evidence in disconnected shapes: the
 driver's device-bench rounds (``BENCH_r*.json`` — one JSON record per
 round, ``parsed: null`` or ``goodput: null`` when device init never
-returned and the bench watchdog exited with rc=75), the multichip dryrun rounds (``MULTICHIP_r*.json``), and
-the device-blind cost-model bank (``PERF_PROXY.json``), plus the
-measured sweep tables in ``BASELINE.md`` (where the best banked config —
+returned and the bench watchdog exited with rc=75), the multichip
+dryrun rounds (``MULTICHIP_r*.json``), the decode-bench rounds
+(``DECODE_r*.json``), plus the measured sweep tables in ``BASELINE.md`` (where the best banked config —
 flash BQ=512 BK=512 at 0.3789 MFU — actually lives). Until this tool
 nothing read them together, so "is the MFU trajectory still pointed at
 the 0.40 north star, and did any round regress" required a human diff.
 
-This tool folds all four into one report:
+This tool folds them into one report:
 
 - every device round renders — **blind rounds included**, with their
   reason (a wall of rc=75 wedges must read as "no device data since
@@ -21,8 +21,7 @@ This tool folds all four into one report:
   north star;
 - measured rounds are swept for ±5% regressions against the best
   preceding round (``--tolerance``); ``--check`` turns any flag into
-  exit 1 — the CI ``goodput-smoke`` job's trajectory gate, and
-  ``bench.py --proxy --check`` embeds the same summary in its output.
+  exit 1 — the CI ``goodput-smoke`` job's trajectory gate.
 
     python tools/perf_history.py                  # text report, repo root
     python tools/perf_history.py --dir /path      # another artifact root
@@ -185,21 +184,6 @@ def decode_regressions(rows: List[Dict],
     return flags
 
 
-def collect_proxy(root: str) -> Optional[Dict[str, Any]]:
-    """The banked device-blind baseline (``PERF_PROXY.json``): per-family
-    deterministic cost metrics — the perf ground truth while the device
-    bench is blind."""
-    doc = _load_json(os.path.join(root, "PERF_PROXY.json"))
-    if not isinstance(doc, dict):
-        return None
-    fams = {f: {k: rec.get(k) for k in ("flops_per_step", "bytes_per_step",
-                                        "comm_bytes_per_step",
-                                        "peak_live_bytes", "graphs")}
-            for f, rec in sorted((doc.get("families") or {}).items())}
-    return {"jax": doc.get("jax"), "tolerance": doc.get("tolerance"),
-            "families": fams, "train": doc.get("train") or {}}
-
-
 #: a BASELINE.md sweep row: |config|step ms|MFU| — cells may carry
 #: ``**bold**`` / trailing ``*`` contention marks
 _MD_ROW = re.compile(r"^\s*\|([^|]+)\|([^|]+)\|([^|]+)\|\s*$")
@@ -288,7 +272,6 @@ def collect(root: str, tolerance: float = 0.05) -> Dict[str, Any]:
         "bench_rounds": bench,
         "blind_rounds": sum(1 for r in bench if r.get("blind")),
         "multichip_rounds": collect_multichip(root),
-        "proxy": collect_proxy(root),
         "baseline_sweeps": sweeps,
         "decode_rounds": decode,
         "best_banked": best_banked(bench, sweeps),
@@ -299,8 +282,8 @@ def collect(root: str, tolerance: float = 0.05) -> Dict[str, Any]:
 
 
 def summary(root: str, tolerance: float = 0.05) -> Dict[str, Any]:
-    """The compact form ``bench.py --proxy --check`` embeds in its gate
-    output: best banked config, round counts, regression flags."""
+    """The compact form: best banked config, round counts, regression
+    flags."""
     doc = collect(root, tolerance)
     return {"best_banked": doc["best_banked"],
             "rounds": len(doc["bench_rounds"]),
@@ -369,32 +352,6 @@ def render(doc: Dict[str, Any]) -> str:
     if not doc["multichip_rounds"]:
         out.append("  (no MULTICHIP_r*.json artifacts)")
 
-    proxy = doc.get("proxy")
-    section("device-blind proxy bank (PERF_PROXY.json)")
-    if proxy:
-        out.append(f"  banked on jax {proxy.get('jax')}, tolerance "
-                   f"±{(proxy.get('tolerance') or 0) * 100:.0f}%")
-
-        def num(v, spec):
-            # a pre-PR-12 bank may lack peak_live_bytes etc. — a missing
-            # metric renders as "?", never a TypeError (the tool's
-            # render-anything contract)
-            return format(v, spec) if isinstance(v, (int, float)) else "?"
-
-        for fam, rec in (proxy.get("families") or {}).items():
-            out.append(
-                f"  {fam:<22} flops/step "
-                f"{num(rec.get('flops_per_step'), '>14,.0f')}"
-                f"  bytes/step {num(rec.get('bytes_per_step'), '>12,')}"
-                f"  peak {num(rec.get('peak_live_bytes'), '>12,')}")
-        train = proxy.get("train") or {}
-        for fam, rec in sorted(train.items()):
-            out.append(f"  train:{fam:<16} graphs/step "
-                       f"{rec.get('graphs_per_step')} "
-                       f"(unfused {rec.get('graphs_per_step_unfused')})")
-    else:
-        out.append("  (no PERF_PROXY.json)")
-
     section("verdict")
     if best:
         out.append(f"  best banked MFU {best['mfu']:.4f} "
@@ -436,10 +393,9 @@ def main(argv=None) -> int:
         return 2
     doc = collect(args.dir, args.tolerance)
     if not doc["bench_rounds"] and not doc["multichip_rounds"] \
-            and doc["proxy"] is None and not doc["baseline_sweeps"] \
-            and not doc["decode_rounds"]:
+            and not doc["baseline_sweeps"] and not doc["decode_rounds"]:
         print(f"perf_history: no BENCH_r*/MULTICHIP_r*/DECODE_r*/"
-              f"PERF_PROXY.json/BASELINE.md artifacts under {args.dir}",
+              f"BASELINE.md artifacts under {args.dir}",
               file=sys.stderr)
         return 2
     if args.json:

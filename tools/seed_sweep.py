@@ -30,7 +30,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # it starts (the _DRIVER source below, the pytest gates) pins the CPU.
 
 # Metric gates: (key, example file, argv, threshold, direction).
-# argv mirrors tests/test_examples.py — keep in sync with the test file.
+# argv mirrors tests/test_example_*.py — keep in sync with the test files.
 METRIC_GATES = [
     ("mnist", "train_mnist.py",
      ["--num-epochs", "3", "--num-synthetic", "600", "--lr", "0.05"],
