@@ -37,7 +37,10 @@ the other chips. With ``experts_held == num_experts`` it is the whole model.
 The whole training step (embedding, layers on the flash and grouped-matmul
 kernels, head, loss, gradients, AdamW) compiles to one executable through
 ``parallel.ShardedTrainer``; ``remat=True`` recomputes each layer in the
-backward pass, as ``BERTEncoder`` does.
+backward pass, as ``BERTEncoder`` does, and holds only what the attention
+kernel returned (``ops.attention.checkpoint_layer``): its output and
+log-sum-exp are 68 MB a layer at 8,192 tokens, and rebuilding them is a
+second run of the step's most expensive kernel.
 """
 from __future__ import annotations
 
@@ -47,7 +50,7 @@ import jax.numpy as jnp
 from ..gluon.block import HybridBlock, _is_tracing
 from ..gluon import nn
 from ..ndarray import NDArray
-from ..ops.attention import dot_product_attention
+from ..ops.attention import checkpoint_layer, dot_product_attention
 from ..ops.pallas.moe_gmm import TILE_ROWS
 from ..parallel import moe_dropless
 
@@ -278,14 +281,15 @@ class AfmoeModel(HybridBlock):
         x = self.embed(ids)
         x = NDArray(x._data * jnp.asarray(self._embed_scale, x._data.dtype), ctx=ctx)
         # checkpoint only under a real jit trace (see BERTEncoder): a layer's
-        # activations are then rebuilt in the backward pass, not held
+        # activations are then rebuilt in the backward pass, not held, except
+        # the flash kernel's output and log-sum-exp (checkpoint_layer)
         remat = (self._remat and _is_tracing()
                  and isinstance(x._data, jax.core.Tracer))
         for layer in self.layers:
             def body(xv, layer=layer):
                 out, rows = layer(NDArray(xv, ctx=ctx), positions, key_mask)
                 return out._data, None if rows is None else rows._data
-            xv, rows = (jax.checkpoint(body) if remat else body)(x._data)
+            xv, rows = (checkpoint_layer(body) if remat else body)(x._data)
             x = NDArray(xv, ctx=ctx)
             if rows is not None:
                 layer.ffn.expert_rows._deposit_aux(rows, ctx)

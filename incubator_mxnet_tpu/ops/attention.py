@@ -18,6 +18,7 @@ so ported GluonNLP model code runs unchanged.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -28,6 +29,7 @@ from .registry import register_op
 
 __all__ = [
     "dot_product_attention",
+    "checkpoint_layer",
     "interleaved_matmul_selfatt_qk",
     "interleaved_matmul_selfatt_valatt",
     "interleaved_matmul_encdec_qk",
@@ -134,6 +136,29 @@ def _flash_on_mesh(query, key, value, mask, causal, scale, window):
         lambda q, k, v, m: attn(q, k, v, mask=m), mesh=mesh,
         in_specs=(spec, spec, spec, P(bspec, None)),
         out_specs=spec)(query, key, value, key_mask)
+
+
+@functools.cache
+def _keep_flash_result():
+    """The one policy object every recomputed layer shares: jax keys its
+    caches of a split, transposed or lowered sub-jaxpr by the policy too,
+    so a policy made anew for each layer would have the layers' common
+    jitted parts (the routed half, the kernels) traced and lowered once a
+    layer and not once."""
+    from .pallas.flash_attention import REMAT_KEEP
+    return jax.checkpoint_policies.save_only_these_names(*REMAT_KEEP)
+
+
+def checkpoint_layer(body):
+    """``jax.checkpoint`` as the models' ``remat=True`` means it: the layer
+    is rebuilt in the backward pass from its input, all but the flash
+    kernel's output and log-sum-exp, which are held (``REMAT_KEEP``). They
+    are the step's dearest values to rebuild and among the cheapest to hold:
+    at 32 heads of 128 and 8,192 tokens, 68 MB a layer against a second run
+    of the forward kernel. Where attention went the XLA way the names are
+    not in the trace, the policy keeps nothing, and this is a bare
+    ``jax.checkpoint``."""
+    return jax.checkpoint(body, policy=_keep_flash_result())
 
 
 @register_op()

@@ -45,12 +45,20 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _VMEM = pltpu.VMEM
 
-__all__ = ["flash_attention", "flash_supported"]
+__all__ = ["flash_attention", "flash_supported", "REMAT_KEEP"]
+
+#: the names the forward rules give the kernel's output and log-sum-exp
+#: (``jax.ad_checkpoint.checkpoint_name``): a ``jax.checkpoint`` whose policy
+#: saves them holds the two and does not run the forward kernel again in the
+#: backward pass (``ops.attention.checkpoint_layer``). Outside a checkpoint
+#: the naming is the identity.
+REMAT_KEEP = ("flash_out", "flash_lse")
 
 _NEG = -1e30
 
@@ -577,8 +585,12 @@ def _flash(q, k, v, key_mask, causal, scale, window=None):
     return o
 
 
+def _named(o, lse):
+    return checkpoint_name(o, REMAT_KEEP[0]), checkpoint_name(lse, REMAT_KEEP[1])
+
+
 def _flash_fwd(q, k, v, key_mask, causal, scale, window=None):
-    o, lse = _fwd(q, k, v, key_mask, causal, scale, window)
+    o, lse = _named(*_fwd(q, k, v, key_mask, causal, scale, window))
     return o, (q, k, v, key_mask, o, lse)
 
 
@@ -605,7 +617,7 @@ def flash_block(q, k, v, key_mask, causal, scale):
 
 
 def _flash_block_fwd(q, k, v, key_mask, causal, scale):
-    o, lse = _fwd(q, k, v, key_mask, causal, scale)
+    o, lse = _named(*_fwd(q, k, v, key_mask, causal, scale))
     return (o, lse), (q, k, v, key_mask, o, lse)
 
 
