@@ -54,16 +54,31 @@ from ..ops.attention import checkpoint_layer, dot_product_attention
 from ..ops.pallas.moe_gmm import TILE_ROWS
 from ..parallel import moe_dropless
 
-__all__ = ["AfmoeModel", "AfmoeDecoderLayer", "AfmoeAttention", "AfmoeMoE",
+__all__ = ["AfmoeModel", "DecoderLM", "AfmoeDecoderLayer", "AfmoeAttention", "AfmoeMoE",
            "GatedFFN", "RMSNorm", "get_afmoe", "afmoe_lm_loss", "rotary"]
 
 
-def rotary(x, positions, theta: float):
+def rotary(x, positions, theta: float, interleaved: bool = False):
     """Rotary position embedding of ``x (B, L, H, D)`` at ``positions (B,
-    L)``: the two halves of the head dimension are the pairs' two parts
-    (the ``rotate_half`` convention), computed in fp32."""
+    L)``, computed in fp32. A pair's two parts are the two halves of the
+    head dimension (the ``rotate_half`` convention) or, ``interleaved``,
+    the neighbours ``(2i, 2i + 1)``; a pair stays where it was."""
     D = x.shape[-1]
     inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    if interleaved:
+        # every lane meets its pair's other lane through a 0/1 matrix on the
+        # MXU (exact: one product by 1.0 a lane). On a TPU a strided slice of
+        # the lanes (x[..., 0::2]) is a gather and a shift by one lane a
+        # misaligned pass: 0.8 ms each over 32 heads of 64 at 8,192 tokens
+        # (v5e, PR 31) against the matmul's few tens of microseconds
+        angle = (positions.astype(jnp.float32)[:, :, None, None]
+                 * jnp.repeat(inv_freq, 2))
+        lane = jnp.arange(D)
+        swap = (lane[:, None] == (lane ^ 1)[None, :]).astype(x.dtype)
+        other = jnp.einsum("blhd,de->blhe", x, swap, precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+        return (x.astype(jnp.float32) * jnp.cos(angle)
+                + other * jnp.where(lane % 2 == 0, -1.0, 1.0) * jnp.sin(angle)).astype(x.dtype)
     angle = positions.astype(jnp.float32)[:, :, None, None] * inv_freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -229,47 +244,34 @@ class AfmoeDecoderLayer(HybridBlock):
         return x + self.norm4(y), rows
 
 
-class AfmoeModel(HybridBlock):
-    """Embedding, decoder layers, final norm and an untied LM head over the
-    vocabulary held. ``cfg`` carries the source's own keys (see
-    :func:`get_afmoe`)."""
+class DecoderLM(HybridBlock):
+    """What the decoder-only language models here share: embedding, a list
+    of decoder layers, final norm and an untied LM head over the vocabulary
+    held, with the forward contract of the module docstring. A family gives
+    its layers (:meth:`decoder_layers`): each is called as ``layer(x,
+    positions, key_mask)``, returns ``(x', rows)`` (``rows`` what a MoE FFN
+    counted, else ``None``) and keeps its FFN as ``layer.ffn``. ``cfg``
+    carries the source's own keys."""
 
     def __init__(self, cfg: dict, dtype="float32", remat: bool = False,
-                 **kwargs):
+                 embed_scale: float = 1.0, **kwargs):
         super().__init__(**kwargs)
         units, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
-        self._units, self._remat = units, remat
-        self._embed_scale = units ** 0.5 if cfg.get("mup_enabled") else 1.0
-        held = (cfg.get("expert_first", 0),
-                cfg.get("experts_held", cfg["num_experts"]))
+        self._units, self._remat, self._embed_scale = units, remat, embed_scale
         with self.name_scope():
             self.embed = nn.Embedding(cfg["vocab_size"], units, dtype=dtype,
                                       prefix="embed_")
             self.layers = []
-            for i, kind in enumerate(cfg["layer_types"]):
-                pre = f"layer{i}_"
-                attention = AfmoeAttention(
-                    units, cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                    cfg["head_dim"],
-                    window=cfg["sliding_window"] if kind == "sliding_attention" else None,
-                    rope_theta=cfg["rope_theta"], epsilon=eps, dtype=dtype,
-                    prefix=pre + "attn_")
-                if i < cfg["num_dense_layers"]:
-                    ffn = GatedFFN(units, cfg["intermediate_size"], dtype,
-                                   prefix=pre + "ffn_")
-                else:
-                    ffn = AfmoeMoE(
-                        units, cfg["moe_intermediate_size"], cfg["num_experts"],
-                        cfg["num_experts_per_tok"], held,
-                        num_shared=cfg["num_shared_experts"],
-                        route_norm=cfg["route_norm"], route_scale=cfg["route_scale"],
-                        tile_rows=cfg.get("moe_tile_rows", TILE_ROWS), dtype=dtype,
-                        prefix=pre + "moe_")
-                layer = AfmoeDecoderLayer(units, attention, ffn, eps, prefix=pre)
+            for i, layer in enumerate(self.decoder_layers(cfg, dtype)):
                 self.register_child(layer, f"layer{i}")
                 self.layers.append(layer)
             self.norm = RMSNorm(units, eps, prefix="norm_")
             self.lm_head = _dense(cfg["vocab_size"], units, dtype, "lm_head_")
+
+    def decoder_layers(self, cfg: dict, dtype):
+        """The family's layers in order, layer ``i`` under the prefix
+        ``layer{i}_`` (called inside the model's name scope)."""
+        raise NotImplementedError
 
     def hidden(self, ids, positions, valid_length):
         """``(hidden, valid)``: the final normed hidden state ``(B, L, C)``
@@ -297,7 +299,7 @@ class AfmoeModel(HybridBlock):
 
     def hybrid_forward(self, F, ids, positions, valid_length):
         x, valid = self.hidden(ids, positions, valid_length)
-        with jax.named_scope("afmoe_lm_head"):
+        with jax.named_scope("lm_head"):
             return self.lm_head(x), valid
 
     def routing(self, ids, positions, valid_length, publish: bool = True) -> list:
@@ -338,6 +340,43 @@ class AfmoeModel(HybridBlock):
         not read here, so a training loop can keep them without a sync."""
         return [l.ffn.expert_rows.data(ctx)._data for l in self.layers
                 if isinstance(l.ffn, AfmoeMoE)]
+
+
+class AfmoeModel(DecoderLM):
+    """The AFMoE decoder (module docstring); ``cfg`` as :func:`get_afmoe`
+    lists it."""
+
+    def __init__(self, cfg: dict, dtype="float32", remat: bool = False,
+                 **kwargs):
+        super().__init__(
+            cfg, dtype, remat,
+            embed_scale=cfg["hidden_size"] ** 0.5 if cfg.get("mup_enabled") else 1.0,
+            **kwargs)
+
+    def decoder_layers(self, cfg, dtype):
+        units, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
+        held = (cfg.get("expert_first", 0),
+                cfg.get("experts_held", cfg["num_experts"]))
+        for i, kind in enumerate(cfg["layer_types"]):
+            pre = f"layer{i}_"
+            attention = AfmoeAttention(
+                units, cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                cfg["head_dim"],
+                window=cfg["sliding_window"] if kind == "sliding_attention" else None,
+                rope_theta=cfg["rope_theta"], epsilon=eps, dtype=dtype,
+                prefix=pre + "attn_")
+            if i < cfg["num_dense_layers"]:
+                ffn = GatedFFN(units, cfg["intermediate_size"], dtype,
+                               prefix=pre + "ffn_")
+            else:
+                ffn = AfmoeMoE(
+                    units, cfg["moe_intermediate_size"], cfg["num_experts"],
+                    cfg["num_experts_per_tok"], held,
+                    num_shared=cfg["num_shared_experts"],
+                    route_norm=cfg["route_norm"], route_scale=cfg["route_scale"],
+                    tile_rows=cfg.get("moe_tile_rows", TILE_ROWS), dtype=dtype,
+                    prefix=pre + "moe_")
+            yield AfmoeDecoderLayer(units, attention, ffn, eps, prefix=pre)
 
 
 def get_afmoe(cfg: dict, dtype="float32", remat: bool = False,

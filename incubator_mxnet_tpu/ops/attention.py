@@ -97,7 +97,7 @@ def _maybe_ring(query, key, value, mask, causal, scale):
     return fn(query, key, value, key_mask)
 
 
-def _flash_on_mesh(query, key, value, mask, causal, scale, window):
+def _flash_on_mesh(query, key, value, mask, causal, scale, window, shared=None):
     """The Pallas flash kernel, run per shard where a mesh is active.
 
     GSPMD cannot partition a Mosaic kernel ("wrap the call in a
@@ -115,27 +115,30 @@ def _flash_on_mesh(query, key, value, mask, causal, scale, window):
     mesh = current_active_mesh()
     if mesh is None or mesh.devices.size == 1 \
             or not isinstance(query, jax.core.Tracer):
-        return attn(query, key, value, mask=mask)
+        return attn(query, key, value, mask=mask, shared=shared)
     from jax.sharding import PartitionSpec as P
     from ..parallel.collectives import shard_map
     B, H, Lq, _ = query.shape
     dp, tp = mesh.shape.get("dp", 1), mesh.shape.get("tp", 1)
     bspec = "dp" if dp > 1 and B % dp == 0 else None
     # heads split over tp only where the K/V heads divide too (grouped K/V:
-    # a shard then holds whole groups)
+    # a shard then holds whole groups); the shared pair's one key head does
+    # not divide, so such a call keeps its heads together
     hspec = "tp" if tp > 1 and H % tp == 0 and key.shape[1] % tp == 0 \
-        else None
+        and shared is None else None
     spec = P(bspec, hspec, None, None)
-    if mask is None:
-        return shard_map(attn, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec)(query, key, value)
-    key_mask = _as_key_mask(mask, B, H, Lq, key.shape[2])
-    if key_mask is None:
-        return attn(query, key, value, mask=mask)   # raises: not a key mask
+    key_mask = None
+    if mask is not None:
+        key_mask = _as_key_mask(mask, B, H, Lq, key.shape[2])
+        if key_mask is None:
+            return attn(query, key, value, mask=mask)   # raises: not a key mask
+    # the optional operands ride as one pytree: None has no leaves and
+    # needs no spec
     return shard_map(
-        lambda q, k, v, m: attn(q, k, v, mask=m), mesh=mesh,
-        in_specs=(spec, spec, spec, P(bspec, None)),
-        out_specs=spec)(query, key, value, key_mask)
+        lambda q, k, v, m, s: attn(q, k, v, mask=m, shared=s), mesh=mesh,
+        in_specs=(spec, spec, spec, None if key_mask is None else P(bspec, None),
+                  None if shared is None else (spec, spec)),
+        out_specs=spec)(query, key, value, key_mask, shared)
 
 
 @functools.cache
@@ -163,7 +166,8 @@ def checkpoint_layer(body):
 
 @register_op()
 def dot_product_attention(query, key, value, mask=None, causal=False,
-                          scale=None, impl="auto", window=None, **_):
+                          scale=None, impl="auto", window=None, shared=None,
+                          **_):
     """Fused scaled-dot-product attention.
 
     Shapes: ``query (B, H, Lq, D)``, ``key/value (B, Hkv, Lk, D)`` with
@@ -179,10 +183,22 @@ def dot_product_attention(query, key, value, mask=None, causal=False,
     ``window`` (with ``causal=True``): causal sliding-window attention over
     the ``window`` most recent keys — O(L·window) on the flash path (dead
     tiles skipped), a banded mask on the XLA path.
+
+    ``shared=(q_s, k_s)``: a second score term over one key head that all
+    query heads read, ``q_s (B, H, Lq, Ds)`` against ``k_s (B, 1, Lk, Ds)``
+    (latent attention's rotary part): the score is ``(q . k + q_s . k_s) *
+    scale``, ``scale`` by default ``(D + Ds) ** -0.5``. The flash path reads
+    the one key through its block index; nothing is repeated or
+    concatenated in HBM. Ring attention does not carry it.
     """
     import os
     impl = os.environ.get("MXTPU_ATTN_IMPL", impl)
-    scale = (query.shape[-1] ** -0.5) if scale is None else scale
+    width = query.shape[-1] + (0 if shared is None else shared[0].shape[-1])
+    scale = (width ** -0.5) if scale is None else scale
+    if shared is not None and impl == "ring":
+        raise ValueError(
+            "impl='ring' does not support shared= (the hops carry one key "
+            "per head); use impl='auto'/'flash'")
     if window is not None:
         window = int(window)
         if not causal:
@@ -198,7 +214,7 @@ def dot_product_attention(query, key, value, mask=None, causal=False,
     # shards rotate over the sp axis, the per-hop block attention is the
     # Pallas flash kernel. See parallel/ring.py. (A sliding window stays on
     # the local paths: the band doesn't decompose over ring hops.)
-    if impl in ("auto", "ring") and window is None:
+    if impl in ("auto", "ring") and window is None and shared is None:
         ring_out = _maybe_ring(query, key, value, mask, causal, scale)
         if ring_out is not None:
             return ring_out
@@ -206,9 +222,9 @@ def dot_product_attention(query, key, value, mask=None, causal=False,
         # no catch here: a kernel that fails to import or lower must be
         # seen, not replaced by the XLA path in silence
         from .pallas.flash_attention import flash_supported
-        if impl == "flash" or flash_supported(query, key, value, mask):
+        if impl == "flash" or flash_supported(query, key, value, mask, shared):
             return _flash_on_mesh(query, key, value, mask, causal, scale,
-                                  window)
+                                  window, shared)
     acc = jnp.float32
     if key.shape[1] != query.shape[1]:
         # grouped K/V heads: this dense path repeats them (the flash kernel
@@ -216,8 +232,11 @@ def dot_product_attention(query, key, value, mask=None, causal=False,
         group = query.shape[1] // key.shape[1]
         key = jnp.repeat(key, group, axis=1)
         value = jnp.repeat(value, group, axis=1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", query, key,
-                   preferred_element_type=acc) * scale
+    s = jnp.einsum("bhqd,bhkd->bhqk", query, key, preferred_element_type=acc)
+    if shared is not None:
+        s = s + jnp.einsum("bhqd,bkd->bhqk", shared[0], shared[1][:, 0],
+                           preferred_element_type=acc)
+    s = s * scale
     if mask is not None:
         s = s + _mask_bias(mask, acc)
     if causal:

@@ -33,6 +33,15 @@ the block index, nothing is repeated in HBM, and the dkv kernel adds up the
 group's query heads. Plain multi-head attention is group 1 and compiles to
 the programs it always did.
 
+A second pair of operands (``shared=(q_s, k_s)``, latent attention's rotary
+part): ``q_s (B, H, Lq, Ds)`` against ``k_s (B, 1, Lk, Ds)``, one key head
+that every query head reads. The score is ``(q . k + q_s . k_s) * scale``;
+``k_s`` is fetched with the block index ``b // H`` as a grouped K/V head is,
+so neither a ``(B, H, Lk, D + Ds)`` key nor ``H`` copies of ``k_s`` exist in
+HBM, forward or backward. ``dk_s`` is the sum over the heads, which the dkv
+kernel streams one after another past an fp32 accumulator. Such calls are
+named ``flash_*_mla``.
+
 Masking: ``causal`` and/or a key-padding mask of shape (B, Lk) (1 = valid).
 The generic (B, H, Lq, Lk) mask case falls back to the XLA path in
 ``ops/attention.py`` — loading an L² mask would defeat the point.
@@ -74,7 +83,7 @@ def _interpret_for(x) -> bool:
     return next(iter(x.devices())).platform != "tpu"
 
 
-def flash_supported(q, k, v, mask=None) -> bool:
+def flash_supported(q, k, v, mask=None, shared=None) -> bool:
     """Shape/backend gate used by dot_product_attention(impl='auto')."""
     if os.environ.get("MXTPU_FLASH_ATTENTION", "1") == "0":
         return False
@@ -85,6 +94,8 @@ def flash_supported(q, k, v, mask=None) -> bool:
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
     if D % 8 or D > 256:
+        return False
+    if shared is not None and not _shared_ok(q, k, *shared):
         return False
     if Lq % _bq(Lq) or Lk % _bk(Lk):
         return False
@@ -102,6 +113,17 @@ def _kv_group(q, k):
         return None
     H, Hkv = q.shape[1], k.shape[1]
     return H // Hkv if Hkv and H % Hkv == 0 else None
+
+
+def _shared_ok(q, k, q_s, k_s) -> bool:
+    """Is ``(q_s, k_s)`` a second score term for ``q`` against ``k``:
+    ``q_s (B, H, Lq, Ds)``, ``k_s (B, 1, Lk, Ds)``, and a key head a query
+    head in the first pair (group 1: the dkv kernel's stream over the heads
+    is then the shared key's alone)?"""
+    B, H, Lq, _ = q.shape
+    Ds = q_s.shape[-1]
+    return (q_s.shape == (B, H, Lq, Ds) and k_s.shape == (B, 1, k.shape[2], Ds)
+            and k.shape[1] == H and Ds % 8 == 0 and Ds <= 256)
 
 
 def _auto_block(length: int) -> int:
@@ -208,17 +230,42 @@ def _live_q(j, bq, bk, nq, causal_off, window):
     return lambda i: jnp.clip(i, lo, hi)
 
 
-def _kernel_name(base: str, window) -> str:
+def _kernel_name(base: str, window, shared=None) -> str:
     """Windowed calls are named apart, so a trace separates a model's
-    sliding layers from its full ones."""
-    return base if window is None else base + "_win"
+    sliding layers from its full ones; so are calls with the shared second
+    pair (latent attention)."""
+    return (base + ("" if window is None else "_win")
+            + ("" if shared is None else "_mla"))
+
+
+def _optional_inputs(kernel, fixed: int, present: tuple):
+    """Pallas hands a kernel its refs by position. ``kernel`` takes
+    ``fixed`` inputs, then one ref for each entry of ``present``, then
+    outputs and scratch: the absent ones are passed as ``None``."""
+    def call(*refs, **kw):
+        refs = list(refs)
+        given = iter(refs[fixed:fixed + sum(present)])
+        optional = [next(given) if p else None for p in present]
+        return kernel(*refs[:fixed], *optional, *refs[fixed + sum(present):], **kw)
+    return call
+
+
+def _scores(qb, kb, qs_ref, ks_ref):
+    """``q k^T`` of one tile in fp32, plus the shared pair's product where
+    the call has one."""
+    s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if qs_ref is not None:
+        s = s + jax.lax.dot_general(qs_ref[0], ks_ref[0], (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+    return s
 
 
 # ---------------------------------------------------------------------------
 # forward: grid (B·H, nq, nk) — K/V streamed block-by-block, state in scratch
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
+def _fwd_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, mask_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, scale, causal, causal_off,
                 window=None):
     bq, d = q_ref.shape[1], q_ref.shape[2]
@@ -237,8 +284,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         q = q_ref[0]                       # input dtype (bf16 in training)
         kb = k_ref[0]
         # MXU dot in input dtype, fp32 accumulate; scale applied in fp32
-        s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _scores(q, kb, qs_ref, ks_ref) * scale
         if mask_ref is not None:
             mb = mask_ref[0, 0]
             s = jnp.where(mb[None, :].astype(bool), s, _NEG)
@@ -268,19 +314,13 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         lse_ref[0, 0] = (m_ref[...] + jnp.log(l))[:, 0]
 
 
-def _fwd_kernel_nomask(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                       acc_ref, m_ref, l_ref, **kw):
-    _fwd_kernel(q_ref, k_ref, v_ref, None, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, **kw)
-
-
 def _scratch(bq, d):
     return [pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32)]
 
 
-def _fwd(q, k, v, key_mask, causal, scale, window=None):
+def _fwd(q, k, v, key_mask, causal, scale, window=None, shared=None):
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
     G = _kv_group(q, k)                 # query heads per K/V head
@@ -305,6 +345,15 @@ def _fwd(q, k, v, key_mask, causal, scale, window=None):
         kv_spec, kv_spec,
     ]
     args = [q3, k3, v3]
+    if shared is not None:
+        # the one key head all H query heads read: block b // H, as a
+        # grouped K/V head is read through b // G
+        Ds = shared[0].shape[-1]
+        in_specs += [
+            pl.BlockSpec((1, bq, Ds), lambda b, i, j: (b, i, 0), memory_space=_VMEM),
+            pl.BlockSpec((1, bk, Ds), lambda b, i, j: (b // H, jj(i, j), 0),
+                         memory_space=_VMEM)]
+        args += [shared[0].reshape(BH, Lq, Ds), shared[1].reshape(B, Lk, Ds)]
     if key_mask is not None:
         # (B, 1, Lk): TPU block shapes need the trailing two dims to be
         # tile-divisible or whole, so the mask rides with a singleton row.
@@ -313,13 +362,14 @@ def _fwd(q, k, v, key_mask, causal, scale, window=None):
             memory_space=_VMEM))
         args.append(key_mask.astype(jnp.int32).reshape(key_mask.shape[0], 1, Lk))
     kern = functools.partial(
-        _fwd_kernel if key_mask is not None else _fwd_kernel_nomask,
+        _optional_inputs(_fwd_kernel, 3, (shared is not None, shared is not None,
+                                          key_mask is not None)),
         scale=scale, causal=causal, causal_off=Lk - Lq, window=window)
     interpret = _interpret_for(q3)
     kwargs = {} if interpret else {"compiler_params": _COMPILER_PARAMS}
     o, lse = pl.pallas_call(
         kern,
-        name=_kernel_name("flash_fwd", window),
+        name=_kernel_name("flash_fwd", window, shared),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -344,9 +394,13 @@ def _fwd(q, k, v, key_mask, causal, scale, window=None):
 # delta = rowsum(do * o) precomputed with plain jnp.
 # ---------------------------------------------------------------------------
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                    causal_off, window=None, group=1):
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    qs_ref, ks_ref, mask_ref, dk_ref, dv_ref, *more, scale,
+                    causal, causal_off, window=None, group=1):
+    if qs_ref is None:
+        dk_acc, dv_acc = more
+    else:
+        dks_ref, dk_acc, dv_acc, dks_acc = more
     bk, d = k_ref.shape[1], k_ref.shape[2]
     bq = q_ref.shape[1]
     jk = pl.program_id(1)
@@ -355,11 +409,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
     step = pl.program_id(2)
     last = pl.num_programs(2) - 1
     iq = step if group == 1 else step % (pl.num_programs(2) // group)
+    # with the shared pair the group is the shared key's (all the heads of a
+    # row): k and v are a head's own, so dk and dv start and end with each
+    # head's q-blocks, and only dk_s adds up the whole stream
+    per_head = qs_ref is not None
 
-    @pl.when(step == 0)
+    @pl.when(iq == 0 if per_head else step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    if qs_ref is not None:
+        @pl.when(step == 0)
+        def _init_shared():
+            dks_acc[...] = jnp.zeros_like(dks_acc)
 
     def _step():
         kb = k_ref[0]
@@ -368,8 +431,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
         dob = do_ref[0]
         lseb = lse_ref[0, 0]
         deltab = delta_ref[0, 0]
-        s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _scores(qb, kb, qs_ref, ks_ref) * scale
         if mask_ref is not None:
             # broadcast, then convert, as the forward does: converting the
             # (bk,) row to bool first lowers to a relayout of every tile
@@ -394,26 +456,34 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
         dk_acc[...] += jax.lax.dot_general(
             ds, qb, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if qs_ref is not None:
+            dks_acc[...] += jax.lax.dot_general(
+                ds, qs_ref[0], (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     if causal:
         pl.when(_causal_live(iq, jk, bq, bk, causal_off, window))(_step)
     else:
         _step()
 
-    @pl.when(step == last)
+    @pl.when(iq == pl.num_programs(2) // group - 1 if per_head else step == last)
     def _finish():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
+    if qs_ref is not None:
+        @pl.when(step == last)
+        def _finish_shared():
+            dks_ref[0] = dks_acc[...].astype(dks_ref.dtype)
 
-def _bwd_dkv_kernel_nomask(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           dk_ref, dv_ref, dk_acc, dv_acc, **kw):
-    _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
-                    dk_ref, dv_ref, dk_acc, dv_acc, **kw)
 
-
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
-                   dq_ref, dq_acc, *, scale, causal, causal_off, window=None):
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   qs_ref, ks_ref, mask_ref, dq_ref, *more, scale, causal,
+                   causal_off, window=None):
+    if qs_ref is None:
+        dq_acc, = more
+    else:
+        dqs_ref, dq_acc, dqs_acc = more
     bq, d = q_ref.shape[1], q_ref.shape[2]
     bk = k_ref.shape[1]
     iq = pl.program_id(1)
@@ -423,6 +493,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
     @pl.when(jk == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        if qs_ref is not None:
+            dqs_acc[...] = jnp.zeros_like(dqs_acc)
 
     def _step():
         qb = q_ref[0]
@@ -431,8 +503,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
         dob = do_ref[0]
         lseb = lse_ref[0, 0]
         deltab = delta_ref[0, 0]
-        s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _scores(qb, kb, qs_ref, ks_ref) * scale
         if mask_ref is not None:
             s = jnp.where(mask_ref[0, 0][None, :].astype(bool), s, _NEG)
         if causal:
@@ -446,6 +517,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
         dq_acc[...] += jax.lax.dot_general(
             ds, kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if qs_ref is not None:
+            dqs_acc[...] += jax.lax.dot_general(
+                ds, ks_ref[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     if causal:
         pl.when(_causal_live(iq, jk, bq, bk, causal_off, window))(_step)
@@ -455,19 +530,19 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
     @pl.when(jk == nk - 1)
     def _finish():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
-
-
-def _bwd_dq_kernel_nomask(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dq_ref, dq_acc, **kw):
-    _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, None,
-                   dq_ref, dq_acc, **kw)
+        if qs_ref is not None:
+            dqs_ref[0] = dqs_acc[...].astype(dqs_ref.dtype)
 
 
 def _bwd(q, k, v, key_mask, causal, scale, o, lse, do, dlse=None,
-         window=None):
+         window=None, shared=None):
+    """``(dq, dk, dv)``, and with ``shared`` a fourth: ``(dq_s, dk_s)``."""
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
     G = _kv_group(q, k)                 # query heads per K/V head
+    # query heads that one row of the dkv grid streams: the K/V head's
+    # group, or with the shared pair every head of the batch row
+    S = G if shared is None else H
     bq, bk = _bq(Lq), _bk(Lk)
     BH, BHkv = B * H, B * H // G
     nq, nk = Lq // bq, Lk // bk
@@ -487,53 +562,69 @@ def _bwd(q, k, v, key_mask, causal, scale, o, lse, do, dlse=None,
     kwargs = {} if interpret else {"compiler_params": _COMPILER_PARAMS}
     off = Lk - Lq
     args = [q3, k3, v3, do3, lse3, delta3]
+    if shared is not None:
+        Ds = shared[0].shape[-1]
+        args += [shared[0].reshape(BH, Lq, Ds), shared[1].reshape(B, Lk, Ds)]
     if key_mask is not None:
         args.append(key_mask.astype(jnp.int32).reshape(-1, 1, Lk))
+    present = (shared is not None, shared is not None, key_mask is not None)
 
     # ---- dk/dv: fixed k-block (parallel), stream q-blocks (arbitrary);
     # grid over K/V heads, the stream covering the group's query heads
-    if G == 1:
+    if S == 1:
         def qh(b, t):                   # the query head of step t
             return b
     else:
         def qh(b, t):
-            return b * G + t // nq
+            return b * S + t // nq
     if causal:      # dead tiles fetch nothing (see _live_k)
         def ii(j, t):
-            return _live_q(j, bq, bk, nq, off, window)(t if G == 1 else t % nq)
-    elif G == 1:
+            return _live_q(j, bq, bk, nq, off, window)(t if S == 1 else t % nq)
+    elif S == 1:
         def ii(j, t):
             return t
     else:
         def ii(j, t):
             return t % nq
-    q_spec = pl.BlockSpec((1, bq, D), lambda b, j, t: (qh(b, t), ii(j, t), 0),
-                          memory_space=_VMEM)
+
+    def q_spec(width):
+        return pl.BlockSpec((1, bq, width), lambda b, j, t: (qh(b, t), ii(j, t), 0),
+                            memory_space=_VMEM)
     row_spec = pl.BlockSpec((1, 1, bq), lambda b, j, t: (qh(b, t), 0, ii(j, t)),
                             memory_space=_VMEM)
-    kv_spec = pl.BlockSpec((1, bk, D), lambda b, j, t: (b, j, 0),
-                           memory_space=_VMEM)
-    dkv_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+    # a K/V head's block is the grid row's own; with the shared pair it is
+    # the streamed head's, and the grid row's own block is the shared key's
+    kv_spec = pl.BlockSpec(
+        (1, bk, D), (lambda b, j, t: (b, j, 0)) if shared is None else
+        (lambda b, j, t: (qh(b, t), j, 0)), memory_space=_VMEM)
+    dkv_specs = [q_spec(D), kv_spec, kv_spec, q_spec(D), row_spec, row_spec]
+    out_specs = [kv_spec, kv_spec]
+    out_shape = [jax.ShapeDtypeStruct((BHkv, Lk, D), k.dtype),
+                 jax.ShapeDtypeStruct((BHkv, Lk, D), v.dtype)]
+    scratch = [pltpu.VMEM((bk, D), jnp.float32), pltpu.VMEM((bk, D), jnp.float32)]
+    if shared is not None:
+        ks_spec = pl.BlockSpec((1, bk, Ds), lambda b, j, t: (b, j, 0),
+                               memory_space=_VMEM)
+        dkv_specs += [q_spec(Ds), ks_spec]
+        out_specs.append(ks_spec)
+        out_shape.append(jax.ShapeDtypeStruct((B, Lk, Ds), shared[1].dtype))
+        scratch.append(pltpu.VMEM((bk, Ds), jnp.float32))
     if key_mask is not None:
         dkv_specs.append(pl.BlockSpec((1, 1, bk),
-                                      lambda b, j, t: (b // (H // G), 0, j),
+                                      lambda b, j, t: (b // (H // S), 0, j),
                                       memory_space=_VMEM))
     dkv_kern = functools.partial(
-        _bwd_dkv_kernel if key_mask is not None else _bwd_dkv_kernel_nomask,
+        _optional_inputs(_bwd_dkv_kernel, 6, present),
         scale=scale, causal=causal, causal_off=off, window=window,
-        **({} if G == 1 else {"group": G}))
-    dk, dv = pl.pallas_call(
+        **({} if S == 1 else {"group": S}))
+    dk, dv, *dks = pl.pallas_call(
         dkv_kern,
-        name=_kernel_name("flash_bwd_dkv", window),
-        grid=(BHkv, nk, G * nq),
+        name=_kernel_name("flash_bwd_dkv", window, shared),
+        grid=(BH // S, nk, S * nq),
         in_specs=dkv_specs,
-        out_specs=[kv_spec, kv_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((BHkv, Lk, D), k.dtype),
-            jax.ShapeDtypeStruct((BHkv, Lk, D), v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
         **kwargs,
     )(*args)
@@ -545,43 +636,57 @@ def _bwd(q, k, v, key_mask, causal, scale, o, lse, do, dlse=None,
     else:
         def jj(i, j):
             return j
-    q_spec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
-                          memory_space=_VMEM)
+
+    def q_spec(width):
+        return pl.BlockSpec((1, bq, width), lambda b, i, j: (b, i, 0),
+                            memory_space=_VMEM)
     row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i),
                             memory_space=_VMEM)
     kv_spec = pl.BlockSpec(
         (1, bk, D), (lambda b, i, j: (b, jj(i, j), 0)) if G == 1 else
         (lambda b, i, j: (b // G, jj(i, j), 0)), memory_space=_VMEM)
-    dq_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
+    dq_specs = [q_spec(D), kv_spec, kv_spec, q_spec(D), row_spec, row_spec]
+    out_specs, out_shape = [q_spec(D)], [jax.ShapeDtypeStruct((BH, Lq, D), q.dtype)]
+    scratch = [pltpu.VMEM((bq, D), jnp.float32)]
+    if shared is not None:
+        dq_specs += [q_spec(Ds),
+                     pl.BlockSpec((1, bk, Ds), lambda b, i, j: (b // H, jj(i, j), 0),
+                                  memory_space=_VMEM)]
+        out_specs.append(q_spec(Ds))
+        out_shape.append(jax.ShapeDtypeStruct((BH, Lq, Ds), shared[0].dtype))
+        scratch.append(pltpu.VMEM((bq, Ds), jnp.float32))
     if key_mask is not None:
         dq_specs.append(pl.BlockSpec((1, 1, bk),
                                      lambda b, i, j: (b // H, 0, jj(i, j)),
                                      memory_space=_VMEM))
     dq_kern = functools.partial(
-        _bwd_dq_kernel if key_mask is not None else _bwd_dq_kernel_nomask,
+        _optional_inputs(_bwd_dq_kernel, 6, present),
         scale=scale, causal=causal, causal_off=off, window=window)
-    dq = pl.pallas_call(
+    dq, *dqs = pl.pallas_call(
         dq_kern,
-        name=_kernel_name("flash_bwd_dq", window),
+        name=_kernel_name("flash_bwd_dq", window, shared),
         grid=(BH, nq, nk),
         in_specs=dq_specs,
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
         **kwargs,
     )(*args)
-    return (dq.reshape(B, H, Lq, D), dk.reshape(B, H // G, Lk, D),
-            dv.reshape(B, H // G, Lk, D))
+    grads = (dq.reshape(B, H, Lq, D), dk.reshape(B, H // G, Lk, D),
+             dv.reshape(B, H // G, Lk, D))
+    if shared is None:
+        return grads
+    return grads + ((dqs[0].reshape(B, H, Lq, Ds), dks[0].reshape(B, 1, Lk, Ds)),)
 
 
 # ---------------------------------------------------------------------------
 # public entry with custom VJP
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash(q, k, v, key_mask, causal, scale, window=None):
-    o, _ = _fwd(q, k, v, key_mask, causal, scale, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _flash(q, k, v, key_mask, shared, causal, scale, window=None):
+    o, _ = _fwd(q, k, v, key_mask, causal, scale, window, shared)
     return o
 
 
@@ -589,16 +694,16 @@ def _named(o, lse):
     return checkpoint_name(o, REMAT_KEEP[0]), checkpoint_name(lse, REMAT_KEEP[1])
 
 
-def _flash_fwd(q, k, v, key_mask, causal, scale, window=None):
-    o, lse = _named(*_fwd(q, k, v, key_mask, causal, scale, window))
-    return o, (q, k, v, key_mask, o, lse)
+def _flash_fwd(q, k, v, key_mask, shared, causal, scale, window=None):
+    o, lse = _named(*_fwd(q, k, v, key_mask, causal, scale, window, shared))
+    return o, (q, k, v, key_mask, shared, o, lse)
 
 
 def _flash_bwd(causal, scale, window, res, do):
-    q, k, v, key_mask, o, lse = res
-    dq, dk, dv = _bwd(q, k, v, key_mask, causal, scale, o, lse, do,
-                      window=window)
-    return dq, dk, dv, None
+    q, k, v, key_mask, shared, o, lse = res
+    dq, dk, dv, *dshared = _bwd(q, k, v, key_mask, causal, scale, o, lse, do,
+                                window=window, shared=shared)
+    return dq, dk, dv, None, (dshared[0] if dshared else None)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -634,7 +739,7 @@ flash_block.defvjp(_flash_block_fwd, _flash_block_bwd)
 
 def flash_attention(q, k, v, mask=None, causal: bool = False,
                     scale: Optional[float] = None,
-                    window: Optional[int] = None):
+                    window: Optional[int] = None, shared=None):
     """Blockwise attention, O(L·D) memory. See module docstring for the
     supported mask forms; unsupported ones should be routed to the XLA path
     by the caller (dot_product_attention does this via flash_supported).
@@ -642,8 +747,20 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     ``window`` (requires ``causal=True``): causal sliding-window attention —
     position i attends to the ``window`` most recent keys only. Tiles fully
     outside the band are skipped, so compute is O(L·window) not O(L²): the
-    Mistral-style long-context recipe, native to the tile grid."""
-    scale = (q.shape[-1] ** -0.5) if scale is None else float(scale)
+    Mistral-style long-context recipe, native to the tile grid.
+
+    ``shared=(q_s, k_s)``: a second score term, ``q_s (B, H, Lq, Ds)``
+    against the one key head ``k_s (B, 1, Lk, Ds)`` that every query head
+    reads (module docstring); the default scale is then ``(D + Ds) ** -0.5``."""
+    if shared is not None:
+        shared = tuple(shared)
+        if not _shared_ok(q, k, *shared):
+            raise ValueError(
+                "shared=(q_s, k_s) needs q_s (B, H, Lq, Ds), k_s (B, 1, Lk, Ds) "
+                "and as many key heads as query heads; got q "
+                f"{q.shape}, k {k.shape}, q_s {shared[0].shape}, k_s {shared[1].shape}")
+    width = q.shape[-1] + (0 if shared is None else shared[0].shape[-1])
+    scale = (width ** -0.5) if scale is None else float(scale)
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
     if window is not None:
@@ -662,4 +779,4 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     if mask is not None and key_mask is None:
         raise ValueError("flash_attention supports key-padding masks "
                          "(B, Lk) / (B,1,1,Lk); use the XLA path otherwise")
-    return _flash(q, k, v, key_mask, causal, scale, window)
+    return _flash(q, k, v, key_mask, shared, causal, scale, window)

@@ -117,13 +117,13 @@ def test_trains_through_sharded_trainer_in_one_compiled_step(system_and_referenc
 
 # --- the share of a deployment ------------------------------------------------
 
-def _moe_layer_params(rng, E):
+def _moe_layer_params(rng, E, num_shared=1):
     C, F = CFG["hidden_size"], CFG["moe_intermediate_size"]
     n = lambda *s: jnp.asarray(rng.normal(0, 0.1, s), jnp.float32)  # noqa: E731
     return {"router_weight": n(E, C), "expert_bias": jnp.zeros((E,)),
             "experts_w13": n(E, 2 * F, C), "experts_w2": n(E, C, F),
-            "shared_gate_weight": n(F, C), "shared_up_weight": n(F, C),
-            "shared_down_weight": n(C, F)}
+            "shared_gate_weight": n(num_shared * F, C), "shared_up_weight": n(num_shared * F, C),
+            "shared_down_weight": n(C, num_shared * F)}
 
 
 @pytest.fixture
@@ -134,24 +134,32 @@ def pallas_gmm(monkeypatch):
                         functools.partial(moe_gmm.grouped_matmul, impl="pallas"))
 
 
-def test_shares_add_up_to_the_uncut_layer(pallas_gmm):
-    """Over both shares of 4 of 8 experts, the routed parts added and the
-    shared expert counted once equal the reference's whole layer."""
+# experts, experts a token, shares, shared experts, scale: Trinity-Mini's
+# shape (two shares of 4 of 8, top 2, one shared expert) and Kanana-2's
+# (eight shares of 2 of 16, top 6, two shared experts as one FFN, 2.448)
+@pytest.mark.parametrize("E,k,shares,num_shared,scale", [
+    pytest.param(8, 2, 2, 1, 2.826, id="top2_of_8_two_shares"),
+    pytest.param(16, 6, 8, 2, 2.448, id="top6_of_16_eight_shares_two_shared"),
+])
+def test_shares_add_up_to_the_uncut_layer(pallas_gmm, E, k, shares, num_shared, scale):
+    """Over all the shares of the experts, the routed parts added and the
+    shared experts counted once equal the reference's whole layer."""
     rng = onp.random.default_rng(1)
-    E, k, T = CFG["num_experts"], CFG["num_experts_per_tok"], 40
-    p = _moe_layer_params(rng, E)
+    T, n = 40, E // shares
+    cfg = dict(CFG, num_experts=E, num_experts_per_tok=k, route_scale=scale)
+    p = _moe_layer_params(rng, E, num_shared)
     x = jnp.asarray(rng.normal(0, 1, (T, CFG["hidden_size"])), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        whole, _ = reference.moe(p, "", CFG, x, (0, E))
+        whole, _ = reference.moe(p, "", cfg, x, (0, E))
         shared = reference._gated(x, p["shared_gate_weight"], p["shared_up_weight"],
                                   p["shared_down_weight"])
     idx, w = moe_dropless.sigmoid_topk(x, p["router_weight"], p["expert_bias"], k,
-                                       True, CFG["route_scale"])
-    parts = [moe_dropless.routed_experts(x, idx, w, p["experts_w13"][f:f + 4],
-                                         p["experts_w2"][f:f + 4], (f, 4), tile_rows=8)
-             for f in (0, 4)]
-    onp.testing.assert_allclose(shared + parts[0] + parts[1], whole, rtol=1e-4, atol=1e-5)
-    assert float(jnp.abs(parts[0]).max()) > 0 and float(jnp.abs(parts[1]).max()) > 0
+                                       True, scale)
+    parts = [moe_dropless.routed_experts(x, idx, w, p["experts_w13"][f:f + n],
+                                         p["experts_w2"][f:f + n], (f, n), tile_rows=8)
+             for f in range(0, E, n)]
+    onp.testing.assert_allclose(shared + sum(parts), whole, rtol=1e-4, atol=1e-5)
+    assert all(float(jnp.abs(part).max()) > 0 for part in parts)
 
 
 @pytest.fixture

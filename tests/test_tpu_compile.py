@@ -116,39 +116,62 @@ def test_flash_fwd_bwd_compiles_for_v5e(topo, for_the_chip, shape, dtype,
                          text, re.M), kernel
 
 
-# (B, H, Hkv, L, D), window: Trinity-Mini's heads, 32 query heads over 4 K/V
-# heads at D=128, key-masked and causal, a sliding layer and a full one
-@pytest.mark.parametrize("shape,window", [
-    pytest.param((1, 32, 4, 4096, 128), 2048, id="gqa8_window2048_L4096"),
-    pytest.param((1, 32, 4, 2048, 128), None, id="gqa8_causal_L2048"),
+# (B, H, Hkv, L, D), window, width of the shared pair: Trinity-Mini's heads,
+# 32 query heads over 4 K/V heads at D=128, key-masked and causal, a sliding
+# layer and a full one; and latent attention's at Kanana-2's widths and the
+# cell's length: 32 heads of 128 with a second, 64-wide score term whose one
+# key head all of them read
+@pytest.mark.parametrize("shape,window,shared", [
+    pytest.param((1, 32, 4, 4096, 128), 2048, None, id="gqa8_window2048_L4096"),
+    pytest.param((1, 32, 4, 2048, 128), None, None, id="gqa8_causal_L2048"),
+    pytest.param((1, 32, 32, 8192, 128), None, 64, id="mla_causal_L8192"),
+    pytest.param((2, 32, 32, 1024, 128), None, 64, id="mla_causal_B2_L1024"),
 ])
-def test_flash_grouped_heads_compile_for_v5e(topo, for_the_chip, shape, window):
+def test_flash_grouped_heads_compile_for_v5e(topo, for_the_chip, shape, window, shared):
     one_chip = SingleDeviceSharding(topo.devices[0])
     B, H, Hkv, L, D = shape
-    q = jax.ShapeDtypeStruct((B, H, L, D), jnp.bfloat16, sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((B, Hkv, L, D), jnp.bfloat16, sharding=one_chip)
+    sds = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)  # noqa: E731
+    q, kv = sds(B, H, L, D), sds(B, Hkv, L, D)
     mask = jax.ShapeDtypeStruct((B, L), jnp.bool_, sharding=one_chip)
+    pair = () if shared is None else (sds(B, H, L, shared), sds(B, 1, L, shared))
 
-    def attn(q, k, v, mask):
-        return fa.flash_attention(q, k, v, mask=mask, causal=True, window=window)
+    def attn(q, k, v, *pair, mask):
+        return fa.flash_attention(q, k, v, mask=mask, causal=True, window=window,
+                                  shared=pair or None)
 
-    text = _fwd_bwd(attn).lower(q, kv, kv, mask, q).compile().as_text()
-    # a windowed call names its kernels apart; dk and dv come out at the K/V
-    # heads' count (summed over each group inside the kernel)
+    text = jax.jit(lambda mask, do, *ins: (lambda o, vjp: (o,) + vjp(do))(
+        *jax.vjp(functools.partial(attn, mask=mask), *ins))).lower(
+            mask, q, q, kv, kv, *pair).compile().as_text()
+    # a windowed call names its kernels apart, and so does one with the
+    # shared pair; dk and dv come out at the K/V heads' count (summed over
+    # each group inside the kernel)
     for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
-        name = fa._kernel_name(kernel, window)
-        assert re.search(rf'^\s*%\S*{name}\S* = .*custom_call_target="tpu_custom_call"',
+        name = fa._kernel_name(kernel, window, shared)
+        assert re.search(rf'^\s*%\S*{name}[_.\d]* = .*custom_call_target="tpu_custom_call"',
                          text, re.M), name
     assert re.search(rf'flash_bwd_dkv\S* = \(bf16\[{B * Hkv},{L},{D}\]', text), "dk, dv per K/V head"
+    if shared:
+        # dk_s leaves the kernel summed over the heads: one key head a row,
+        # and neither a (D + Ds)-wide key nor H copies of the shared one
+        # anywhere in the program
+        assert re.search(rf'flash_bwd_dkv_mla\S* = \(bf16\[{B * H},{L},{D}\]\S*, '
+                         rf'bf16\[{B * H},{L},{D}\]\S*, bf16\[{B},{L},{shared}\]', text)
+        assert not re.search(rf'\[(?:{B},{H}|{B * H}),{L},(?:{D + shared}|{shared})\]\S* '
+                             r'(?:broadcast|concatenate)\(', text)
 
 
-def test_grouped_matmul_compiles_for_v5e(topo, for_the_chip):
-    """The routed experts' kernels at Trinity-Mini's widths: 16 experts of
-    2,048 x 2,048 (gate and up stacked) over a buffer of 24 tiles, forward,
-    rows' gradient and weights' gradient, each under its name."""
+# (K, N) of an expert's weight: Trinity-Mini's gate and up stacked (2 x 1,024)
+# and Kanana-2's, whose widths 1,536 = 2 x 768 and 768 no 1,024-column block
+# divides: the kernel takes the whole width as one block
+@pytest.mark.parametrize("K,N", [(2048, 2048), (2048, 1536), (768, 2048)],
+                         ids=["w13_2048", "w13_1536", "w2_from_768"])
+def test_grouped_matmul_compiles_for_v5e(topo, for_the_chip, K, N):
+    """The routed experts' kernels: 16 experts of ``N x K`` over a buffer of
+    24 tiles, forward, rows' gradient and weights' gradient, each under its
+    name."""
     one_chip = SingleDeviceSharding(topo.devices[0])
     tm = moe_gmm.TILE_ROWS
-    R, K, N, G = 24 * tm, 2048, 2048, 16
+    R, G = 24 * tm, 16
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
 
     def f(lhs, rhs, tile_group, n_tiles, dout):
@@ -165,15 +188,19 @@ def test_grouped_matmul_compiles_for_v5e(topo, for_the_chip):
                          text, re.M), kernel
 
 
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_routed_half_compiles_for_v5e_on_the_row_kernels(topo, for_the_chip, dtype):
-    """The routed half as a TPU runs it, forward and backward at
-    Trinity-Mini's widths (1,024 tokens, top 8, 16 experts held: a buffer of
-    48 tiles): the six row kernels and the grouped matmuls each under its
+# dtype, experts a token, expert width: Trinity-Mini's (top 8, 1,024) and
+# Kanana-2's (top 6, 768 = 6 x 128 lanes, where the gate kernel slices)
+@pytest.mark.parametrize("dtype,k,F", [("bfloat16", 8, 1024), ("float32", 8, 1024),
+                                       ("bfloat16", 6, 768)],
+                         ids=["bfloat16", "float32", "bfloat16_top6_width768"])
+def test_routed_half_compiles_for_v5e_on_the_row_kernels(topo, for_the_chip, dtype, k, F):
+    """The routed half as a TPU runs it, forward and backward at the
+    published widths (1,024 tokens, 16 experts held: a buffer of up to 48
+    tiles): the six row kernels and the grouped matmuls each under its
     name, Mosaic taking the slabs' row DMAs, the strided loads and the index
     blocks in SMEM; and no XLA gather of 2,048-wide rows left beside them."""
     one_chip = SingleDeviceSharding(topo.devices[0])
-    T, k, C, F, G = 1024, 8, 2048, 1024, 16
+    T, C, G = 1024, 2048, 16
     dt = jnp.dtype(dtype)
     sds = lambda shape, d: jax.ShapeDtypeStruct(shape, d, sharding=one_chip)  # noqa: E731
 

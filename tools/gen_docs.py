@@ -605,6 +605,26 @@ def gen_profiler() -> str:
            "benchmark reads the same spans from the device trace "
            "(`host_step_ms.train`, `step_*_ms.train`); "
            "`telemetry.snapshot()` embeds both reports.",
+           "", "## Kernels and scopes in a device trace", "",
+           "What the program names so that a trace of the chip reads by "
+           "module (the table of all of them, with the benchmark metric "
+           "that reads each, is `docs/observability.md` section 9). Pallas "
+           "kernels carry their `pl.pallas_call(name=...)` in the HLO "
+           "instruction's name: `flash_fwd`, `flash_bwd_dkv`, "
+           "`flash_bwd_dq`; with `_win` where the call has a `window=`; "
+           "with `_mla` where it has `shared=(q_s, k_s)`, the second score "
+           "term whose one key head all query heads read (latent "
+           "attention: `flash_fwd_mla`, `flash_bwd_dkv_mla`, "
+           "`flash_bwd_dq_mla`, read by `mla_attn_ms.train` and "
+           "`mla_attn_roofline.train`); `moe_gmm`, `moe_tgmm`; "
+           "`moe_rows_*`. `jax.named_scope`s sit in every operation's "
+           "`op_name`: `afmoe_attention`, `moe_router`, `moe_dispatch`, "
+           "`moe_experts`, `moe_combine`, `lm_head`, and in "
+           "`models/deepseek_v3.py`'s attention block `mla_q` (the query "
+           "projection and its split), `mla_latent` (the down-projection, "
+           "the latent's norm), `mla_kv_up` (the up-projection to keys and "
+           "values) and `mla_attention` (rotary on the 64-wide parts, the "
+           "heads' layout, the kernel).",
            "", "## API", "", "| Name | Summary |", "|---|---|"]
     for name in profiler.__all__:
         obj = getattr(profiler, name, None)
