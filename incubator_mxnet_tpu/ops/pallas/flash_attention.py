@@ -18,9 +18,26 @@ TPU mapping (the parts that set the MFU):
   in VMEM scratch across steps. Pallas double-buffers the HBM→VMEM copies
   automatically, so there is no whole-sequence VMEM residency and no cap on
   L (the old design held all of K/V per (b,h) in VMEM and capped L at 4k).
-- ``dimension_semantics``: (batch·head, q-block) grid dims are "parallel";
-  the k-block dim is "arbitrary" (carries the softmax recurrence).
-- Fully-masked causal tiles are skipped with ``pl.when`` (≈2× on causal).
+- A non-causal call runs the rectangular grid ``(batch·head, fixed block,
+  streamed block)``: the first two "parallel", the streamed one "arbitrary"
+  (it carries the softmax recurrence).
+- A causal call (with or without ``window``) **walks a list of its live
+  tiles**. What the call can see when it is traced (``Lq``, ``Lk``, the
+  tile sizes, the window, and for dkv the heads a grid row streams) fixes
+  which tiles the band leaves live and in what order the rectangle would
+  visit them; ``_tile_schedule`` builds that list once a shape, in numpy,
+  and the kernel takes it as scalar prefetch (as ``moe_gmm`` and
+  ``moe_rows`` take theirs). The grid is ``(batch·head, steps)``: no step
+  exists for a dead tile (47% of the rectangle for a full causal call at
+  L = 8,192 and 512-row tiles, 73% with a window of 2,048), every index map
+  reads its block from the tables, and a step's ``kind`` says whether it is
+  the first or last of its fixed block (scratch zeroed, result written) and
+  whether the band cuts the tile or leaves it **whole**. Only a cut tile
+  builds the in-tile mask (two iotas, compares, a select; in the backward
+  kernels a second select on hidden pairs); a whole tile runs the same
+  body without it. Same tiles, same order, same arithmetic on every visible
+  pair as the rectangular walk. Gauges ``mxtpu_flash_tiles_live_share`` and
+  ``mxtpu_flash_tiles_cut_share`` ``{kernel=}`` say how far it engages.
 
 Longer-than-memory sequences go through ring attention over the ``sp`` mesh
 axis (``parallel/ring.py``), which calls back into this kernel's ``_fwd``
@@ -48,12 +65,14 @@ The generic (B, H, Lq, Lk) mask case falls back to the XLA path in
 """
 from __future__ import annotations
 
+import collections
 import functools
 import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -133,13 +152,16 @@ def _auto_block(length: int) -> int:
     else 512 (which won't divide — the caller then routes to the XLA path
     via ``flash_supported``).
 
-    Measured 2026-07-30 on a v5e under an earlier installation (BASELINE.md;
-    not re-measured since): BERT-base, L=512, D=64, (BQ, BK)=(512, 512) ran
-    the step at 40.9ms vs 45.5ms for (256, 512) and a pathological 1066ms for
-    (128, 512) — bigger tiles amortize the grid/recurrence overhead and keep
-    the MXU busier, and VMEM comfortably holds a 512-row block up to D=256.
-    Tiles below 256 rows are never chosen automatically (the 128-row config
-    is the measured-pathological regime; env overrides remain available).
+    Why no smaller tile is chosen: a grid step has a fixed cost beside its
+    tile's work (v5e, PR 32's chip runs, PERF.md section 6: an empty step of
+    the causal kernels took 0.06-0.25 us and a 512 x 512 tile 1.4-2.0 us), so
+    bigger tiles amortize it, and VMEM comfortably holds a 512-row block up
+    to D=256. One 128-row tile a (row, head) is the measured bad case
+    (`bert_base_pretrain.phase1_l128`: the kernels take longer than at
+    L=512 for a quarter of the pairs). The figures this docstring carried
+    until PR 32 (a BERT-base step at three tilings) dated from 2026-07-30
+    and an earlier installation and were never measured again; tile shape
+    is ROADMAP S1 (e). Env overrides remain available.
     """
     for cand in (512, 256):
         if cand <= length and length % cand == 0:
@@ -169,10 +191,14 @@ def _bk(lk: int) -> int:
     return _auto_block(lk)
 
 
-#: grid semantics of all three kernels for Mosaic: (batch·head, fixed
-#: block) are parallel, the streamed block carries the recurrence
+#: grid semantics for Mosaic. The rectangular grid of a non-causal call:
+#: (batch·head, fixed block) are parallel, the streamed block carries the
+#: recurrence. A causal call walks its schedule in one dimension, fixed
+#: blocks one after another, so that dimension is "arbitrary".
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
+_SCHEDULED_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"))
 
 
 def _as_key_mask(mask, B, H, Lq, Lk):
@@ -188,21 +214,6 @@ def _as_key_mask(mask, B, H, Lq, Lk):
     return None
 
 
-def _causal_live(iq, jk, bq, bk, causal_off, window=None):
-    """Does q-block iq intersect any unmasked position of k-block jk?
-    (bottom-right aligned causal: col <= row + causal_off; with a sliding
-    window additionally col > row + causal_off - window). Dead tiles are
-    skipped entirely — a window turns the O(L²) tile grid into O(L·W)."""
-    first_row = iq * bq
-    first_col = jk * bk
-    live = first_col <= first_row + (bq - 1) + causal_off
-    if window is not None:
-        last_col = first_col + bk - 1
-        live = jnp.logical_and(
-            live, last_col > first_row + causal_off - window)
-    return live
-
-
 def _band(rows, cols, causal_off, window):
     """The in-tile visibility mask for causal (+ optional window)."""
     live = cols <= rows + causal_off
@@ -211,23 +222,72 @@ def _band(rows, cols, causal_off, window):
     return live
 
 
-def _live_k(i, bq, bk, nk, causal_off, window):
-    """``j -> j`` held inside the k-blocks that q-block ``i`` can see: a dead
-    tile then names the block of the nearest live one, and Pallas, which
-    copies a block only when its index changes, fetches nothing for it. The
-    tile skipping saves the arithmetic; this saves the HBM traffic."""
-    hi = jnp.clip((i * bq + bq - 1 + causal_off) // bk, 0, nk - 1)
-    lo = 0 if window is None else jnp.clip(
-        (i * bq + causal_off - window + 1) // bk, 0, nk - 1)
-    return lambda j: jnp.clip(j, lo, hi)
+#: what one step of a causal call's schedule is, as bits of its ``kind``:
+#: the first / last step of its fixed block (scratch zeroed / written out),
+#: and a tile the band leaves whole (every pair visible) or cuts (some
+#: hidden). A step with neither is the one a fixed block gets that sees
+#: nothing, so that its zeros are written. Where several heads stream past a
+#: fixed block (dkv), first / last are a head's and open / close the block's.
+_FIRST, _LAST, _WHOLE, _CUT, _OPEN, _CLOSE = 1, 2, 4, 8, 16, 32
+
+_Schedule = collections.namedtuple(
+    "_Schedule", "tables live_share cut_share")
 
 
-def _live_q(j, bq, bk, nq, causal_off, window):
-    """The same for the q-blocks that can see k-block ``j`` (dkv kernel)."""
-    lo = jnp.clip(-((bq - 1 + causal_off - j * bk) // bq), 0, nq - 1)
-    hi = nq - 1 if window is None else jnp.clip(
-        (j * bk + bk - 2 - causal_off + window) // bq, 0, nq - 1)
-    return lambda i: jnp.clip(i, lo, hi)
+@functools.lru_cache(maxsize=None)
+def _tile_schedule(Lq, Lk, bq, bk, window=None, heads=None) -> _Schedule:
+    """The tiles a causal call visits, in the order it visits them (bottom-
+    right aligned: col <= row + Lk - Lq; with a sliding window additionally
+    col > row + Lk - Lq - window). Dead tiles are no step at all: a window
+    turns the O(L²) tile grid into O(L·W) steps.
+
+    ``tables`` are int32 vectors with an entry a step. ``heads=None``
+    (forward, dq): ``(q-block, k-block, kind)``, k-blocks streamed past each
+    q-block. ``heads=S`` (dkv): ``(k-block, q-block, kind, head)``, for each
+    k-block the q-blocks of head 0, then of head 1, ... of the ``S`` query
+    heads a grid row streams. ``live_share`` is live tiles over the
+    rectangle's, ``cut_share`` cut tiles over live ones. Built once a shape:
+    every layer of a model hands its kernels the same constants."""
+    nq, nk, off = Lq // bq, Lk // bk, Lk - Lq
+    row0, col0 = np.arange(nq)[:, None] * bq, np.arange(nk)[None, :] * bk
+    # col - row over a tile takes every integer of [lo, hi]
+    lo, hi = col0 - (row0 + bq - 1), col0 + (bk - 1) - row0
+    live, whole = lo <= off, hi <= off
+    if window is not None:
+        live, whole = live & (hi > off - window), whole & (lo > off - window)
+    kinds = np.where(whole, _WHOLE, np.where(live, _CUT, 0))
+    steps = []                          # [fixed, streamed, kind, head]
+    for fixed, kind in enumerate(kinds if heads is None else kinds.T):
+        seen = np.flatnonzero(kind)
+        if not seen.size:               # names a block that is fetched already
+            seen = [steps[-1][1] if steps else 0]
+        opened = len(steps)
+        for head in range(heads or 1):
+            steps += [[fixed, s, kind[s], head] for s in seen]
+            steps[-len(seen)][2] |= _FIRST
+            steps[-1][2] |= _LAST
+        steps[opened][2] |= _OPEN
+        steps[-1][2] |= _CLOSE
+    tables = np.asarray(steps, np.int32).T[:3 if heads is None else 4]
+    return _Schedule(tuple(np.ascontiguousarray(t) for t in tables), live.mean(),
+                     (live & ~whole).sum() / live.sum())
+
+
+def _schedule(name, causal, *key):
+    """The schedule of a causal call of the kernel ``name`` (None for a
+    non-causal one, whose every tile is live and whole: the rectangular grid
+    is its schedule), with the two gauges that say how far it engages."""
+    if not causal:
+        return None
+    from ...telemetry import metrics
+    sched = _tile_schedule(*key)
+    for what, share, text in (
+            ("live", sched.live_share, "Tiles a causal flash kernel visits over "
+             "the rectangular grid's, last call traced"),
+            ("cut", sched.cut_share, "Visited tiles of a causal flash kernel "
+             "that keep the band mask, last call traced")):
+        metrics.gauge(f"mxtpu_flash_tiles_{what}_share", text, kernel=name).set(share)
+    return sched.tables
 
 
 def _kernel_name(base: str, window, shared=None) -> str:
@@ -238,60 +298,101 @@ def _kernel_name(base: str, window, shared=None) -> str:
             + ("" if shared is None else "_mla"))
 
 
-def _optional_inputs(kernel, fixed: int, present: tuple):
-    """Pallas hands a kernel its refs by position. ``kernel`` takes
-    ``fixed`` inputs, then one ref for each entry of ``present``, then
-    outputs and scratch: the absent ones are passed as ``None``."""
+def _optional_inputs(kernel, tables: int, fixed: int, present: tuple):
+    """Pallas hands a kernel its refs by position: the ``tables`` of a
+    schedule first (scalar prefetch), which ``kernel`` takes as one tuple
+    (``None`` where there are none), then ``fixed`` inputs, then one ref for
+    each entry of ``present``, then outputs and scratch: the absent ones
+    are passed as ``None``."""
     def call(*refs, **kw):
-        refs = list(refs)
+        sched, refs = refs[:tables] or None, list(refs[tables:])
         given = iter(refs[fixed:fixed + sum(present)])
         optional = [next(given) if p else None for p in present]
-        return kernel(*refs[:fixed], *optional, *refs[fixed + sum(present):], **kw)
+        return kernel(sched, *refs[:fixed], *optional,
+                      *refs[fixed + sum(present):], **kw)
     return call
 
 
-def _scores(qb, kb, qs_ref, ks_ref):
-    """``q k^T`` of one tile in fp32, plus the shared pair's product where
-    the call has one."""
+def _walk(sched, group=1):
+    """Where a grid step is: ``(fixed block, streamed block, (first, last,
+    open, close), run)``. With a schedule it is what the tables say of step
+    ``program_id(1)``, and ``run(step)`` runs ``step(cut)`` under the tile's
+    kind: without the band on a whole tile, with it on a cut one, not at all
+    where the fixed block sees nothing. Without one the grid is the
+    rectangle ``(row, fixed, streamed)``, the stream running ``group`` times
+    over the blocks, and every tile is whole."""
+    if sched is None:
+        fixed, step, n = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
+        if group == 1:
+            streamed, ends = step, (step == 0, step == n - 1) * 2
+        else:
+            streamed = step % (n // group)
+            ends = (streamed == 0, streamed == n // group - 1, step == 0, step == n - 1)
+        return fixed, streamed, ends, lambda body: body(False)
+    t = pl.program_id(1)
+    kind = sched[2][t]
+
+    def run(body):
+        pl.when(kind & _WHOLE != 0)(functools.partial(body, False))
+        pl.when(kind & _CUT != 0)(functools.partial(body, True))
+    return (sched[0][t], sched[1][t],
+            tuple(kind & bit != 0 for bit in (_FIRST, _LAST, _OPEN, _CLOSE)), run)
+
+
+def _scores(qb, kb, qs_ref, ks_ref, mask_ref, scale, band):
+    """The scaled scores of one tile in fp32: ``q k^T``, plus the shared
+    pair's product where the call has one, with ``_NEG`` where the key mask
+    or ``band = (iq, jk, causal_off, window)``, given for a tile the band
+    cuts, hides the pair."""
     s = jax.lax.dot_general(qb, kb, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if qs_ref is not None:
         s = s + jax.lax.dot_general(qs_ref[0], ks_ref[0], (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
+    s = s * scale                       # MXU dot in input dtype, scale in fp32
+    if mask_ref is not None:
+        # broadcast, then convert: converting the (bk,) row to bool first
+        # lowers to a relayout of every tile that tripled both backward
+        # kernels on bf16 operands (v5e, PR 26: dkv 2.47 -> 0.78 ms, dq
+        # 1.58 -> 0.51 ms a call at (384, 512, 64); gradients bit-identical)
+        s = jnp.where(mask_ref[0, 0][None, :].astype(bool), s, _NEG)
+    if band is not None:
+        iq, jk, causal_off, window = band
+        bq, bk = s.shape
+        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
+        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + jk * bk
+        s = jnp.where(_band(rows, cols, causal_off, window), s, _NEG)
     return s
 
 
+def _probs(s, lseb, hidden: bool):
+    """``exp(s - lse)`` of a backward tile. Where a pair can be hidden,
+    ``exp`` can overflow for fully-masked rows (lse floors at m + log eps);
+    hidden pairs carry no gradient: zero them."""
+    if not hidden:
+        return jnp.exp(s - lseb[:, None])
+    return jnp.where(s > _NEG * 0.5, jnp.exp(s - lseb[:, None]), 0.0)
+
+
 # ---------------------------------------------------------------------------
-# forward: grid (B·H, nq, nk) — K/V streamed block-by-block, state in scratch
+# forward: K/V streamed block by block past each q-block, state in scratch.
+# Grid (B·H, nq, nk), or for a causal call (B·H, steps of the schedule)
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, mask_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, causal, causal_off,
+def _fwd_kernel(sched, q_ref, k_ref, v_ref, qs_ref, ks_ref, mask_ref, o_ref,
+                lse_ref, acc_ref, m_ref, l_ref, *, scale, causal_off,
                 window=None):
-    bq, d = q_ref.shape[1], q_ref.shape[2]
-    bk = k_ref.shape[1]
-    iq = pl.program_id(1)
-    jk = pl.program_id(2)
-    nk = pl.num_programs(2)
+    iq, jk, (first, last, _, _), run = _walk(sched)
 
-    @pl.when(jk == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def _step():
-        q = q_ref[0]                       # input dtype (bf16 in training)
-        kb = k_ref[0]
-        # MXU dot in input dtype, fp32 accumulate; scale applied in fp32
-        s = _scores(q, kb, qs_ref, ks_ref) * scale
-        if mask_ref is not None:
-            mb = mask_ref[0, 0]
-            s = jnp.where(mb[None, :].astype(bool), s, _NEG)
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
-            cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + jk * bk
-            s = jnp.where(_band(rows, cols, causal_off, window), s, _NEG)
+    def _step(cut):
+        s = _scores(q_ref[0], k_ref[0], qs_ref, ks_ref, mask_ref, scale,
+                    (iq, jk, causal_off, window) if cut else None)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -302,12 +403,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, qs_ref, ks_ref, mask_ref, o_ref, lse_ref,
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    if causal:  # skip tiles fully outside the (banded) diagonal
-        pl.when(_causal_live(iq, jk, bq, bk, causal_off, window))(_step)
-    else:
-        _step()
+    run(_step)
 
-    @pl.when(jk == nk - 1)
+    @pl.when(last)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)  # fully-masked rows → output 0
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
@@ -320,132 +418,140 @@ def _scratch(bq, d):
             pltpu.VMEM((bq, 1), jnp.float32)]
 
 
+def _pallas(kernel, name, sched, rectangle, interpret, in_specs, out_specs,
+            out_shape, scratch, args):
+    """One ``pallas_call``: over the grid ``rectangle``, or where the call
+    has a schedule over ``(rows, its steps)`` with the tables prefetched, so
+    that every index map reads its block from them."""
+    kwargs = {} if interpret else {
+        "compiler_params": _COMPILER_PARAMS if sched is None else _SCHEDULED_PARAMS}
+    if sched is None:
+        return pl.pallas_call(
+            kernel, name=name, grid=rectangle, in_specs=in_specs,
+            out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch,
+            interpret=interpret, **kwargs)(*args)
+    return pl.pallas_call(
+        kernel, name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(sched), grid=(rectangle[0], len(sched[0])),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape, interpret=interpret, **kwargs)(*sched, *args)
+
+
+def _spec(place, shape, block):
+    """A block of ``shape`` at ``block(b, h, i, j)``: grid row, query head,
+    q-block and k-block, which ``place`` reads off the grid's indices (and a
+    schedule's tables)."""
+    return pl.BlockSpec(shape, lambda *g: block(*place(*g)), memory_space=_VMEM)
+
+
+def _q_block(b, h, i, j):
+    return h, i, 0
+
+
+def _q_row(b, h, i, j):
+    return h, 0, i
+
+
+def _kv_block(G):
+    """The K/V block a query head reads: its own, or its group's."""
+    if G == 1:
+        return lambda b, h, i, j: (h, j, 0)
+    return lambda b, h, i, j: (h // G, j, 0)
+
+
+def _place_qk(sched):
+    """``place`` of a grid whose rows are query heads and whose fixed block
+    is the q-block (forward, dq)."""
+    if sched is None:
+        return lambda b, i, j: (b, b, i, j)
+    return lambda b, t, qi, kj, kind: (b, b, qi[t], kj[t])
+
+
 def _fwd(q, k, v, key_mask, causal, scale, window=None, shared=None):
     B, H, Lq, D = q.shape
     Lk = k.shape[2]
     G = _kv_group(q, k)                 # query heads per K/V head
     bq, bk = _bq(Lq), _bk(Lk)
     BH = B * H
-    nk = Lk // bk
+    name = _kernel_name("flash_fwd", window, shared)
+    sched = _schedule(name, causal, Lq, Lk, bq, bk, window)
+    spec = functools.partial(_spec, _place_qk(sched))
     q3 = q.reshape(BH, Lq, D)
     k3 = k.reshape(BH // G, Lk, D)
     v3 = v.reshape(BH // G, Lk, D)
-    grid = (BH, Lq // bq, nk)
-    if causal:      # dead tiles fetch nothing (see _live_k)
-        def jj(i, j):
-            return _live_k(i, bq, bk, nk, Lk - Lq, window)(j)
-    else:
-        def jj(i, j):
-            return j
-    kv_spec = pl.BlockSpec(
-        (1, bk, D), (lambda b, i, j: (b, jj(i, j), 0)) if G == 1 else
-        (lambda b, i, j: (b // G, jj(i, j), 0)), memory_space=_VMEM)
-    in_specs = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0), memory_space=_VMEM),
-        kv_spec, kv_spec,
-    ]
+    kv_spec = spec((1, bk, D), _kv_block(G))
+    in_specs = [spec((1, bq, D), _q_block), kv_spec, kv_spec]
     args = [q3, k3, v3]
     if shared is not None:
-        # the one key head all H query heads read: block b // H, as a
-        # grouped K/V head is read through b // G
+        # the one key head all H query heads read: block h // H, as a
+        # grouped K/V head is read through h // G
         Ds = shared[0].shape[-1]
-        in_specs += [
-            pl.BlockSpec((1, bq, Ds), lambda b, i, j: (b, i, 0), memory_space=_VMEM),
-            pl.BlockSpec((1, bk, Ds), lambda b, i, j: (b // H, jj(i, j), 0),
-                         memory_space=_VMEM)]
+        in_specs += [spec((1, bq, Ds), _q_block),
+                     spec((1, bk, Ds), lambda b, h, i, j: (h // H, j, 0))]
         args += [shared[0].reshape(BH, Lq, Ds), shared[1].reshape(B, Lk, Ds)]
     if key_mask is not None:
         # (B, 1, Lk): TPU block shapes need the trailing two dims to be
         # tile-divisible or whole, so the mask rides with a singleton row.
-        in_specs.append(pl.BlockSpec(
-            (1, 1, bk), lambda b, i, j: (b // H, 0, jj(i, j)),
-            memory_space=_VMEM))
+        in_specs.append(spec((1, 1, bk), lambda b, h, i, j: (h // H, 0, j)))
         args.append(key_mask.astype(jnp.int32).reshape(key_mask.shape[0], 1, Lk))
     kern = functools.partial(
-        _optional_inputs(_fwd_kernel, 3, (shared is not None, shared is not None,
-                                          key_mask is not None)),
-        scale=scale, causal=causal, causal_off=Lk - Lq, window=window)
-    interpret = _interpret_for(q3)
-    kwargs = {} if interpret else {"compiler_params": _COMPILER_PARAMS}
-    o, lse = pl.pallas_call(
-        kern,
-        name=_kernel_name("flash_fwd", window, shared),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0),
-                         memory_space=_VMEM),
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i),
-                         memory_space=_VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, 1, Lq), jnp.float32),
-        ],
-        scratch_shapes=_scratch(bq, D),
-        interpret=interpret,
-        **kwargs,
-    )(*args)
+        _optional_inputs(_fwd_kernel, len(sched or ()), 3,
+                         (shared is not None, shared is not None,
+                          key_mask is not None)),
+        scale=scale, causal_off=Lk - Lq, window=window)
+    o, lse = _pallas(
+        kern, name, sched, (BH, Lq // bq, Lk // bk), _interpret_for(q3), in_specs,
+        [spec((1, bq, D), _q_block), spec((1, 1, bq), _q_row)],
+        [jax.ShapeDtypeStruct((BH, Lq, D), q.dtype),
+         jax.ShapeDtypeStruct((BH, 1, Lq), jnp.float32)],
+        _scratch(bq, D), args)
     return o.reshape(B, H, Lq, D), lse.reshape(B, H, Lq)
 
 
 # ---------------------------------------------------------------------------
-# backward: dkv kernel (grid B·H, nk, nq) + dq kernel (grid B·H, nq, nk);
-# delta = rowsum(do * o) precomputed with plain jnp.
+# backward: the dkv kernel streams q-blocks past each k-block (grid B·H / S,
+# nk, S·nq), the dq kernel k-blocks past each q-block (grid B·H, nq, nk);
+# a causal call walks its schedules. delta = rowsum(do * o) precomputed with
+# plain jnp.
 # ---------------------------------------------------------------------------
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _bwd_dkv_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     qs_ref, ks_ref, mask_ref, dk_ref, dv_ref, *more, scale,
-                    causal, causal_off, window=None, group=1):
+                    causal_off, window=None, group=1):
     if qs_ref is None:
         dk_acc, dv_acc = more
     else:
         dks_ref, dk_acc, dv_acc, dks_acc = more
-    bk, d = k_ref.shape[1], k_ref.shape[2]
-    bq = q_ref.shape[1]
-    jk = pl.program_id(1)
     # the streamed dimension runs over the q-blocks of every query head of
-    # this K/V head's group, one head after another: dk and dv add them up
-    step = pl.program_id(2)
-    last = pl.num_programs(2) - 1
-    iq = step if group == 1 else step % (pl.num_programs(2) // group)
-    # with the shared pair the group is the shared key's (all the heads of a
+    # this K/V head's group, one head after another: dk and dv add them up.
+    # With the shared pair the group is the shared key's (all the heads of a
     # row): k and v are a head's own, so dk and dv start and end with each
     # head's q-blocks, and only dk_s adds up the whole stream
-    per_head = qs_ref is not None
+    jk, iq, (first, last, opened, closed), run = _walk(sched, group)
+    if qs_ref is None:
+        first, last = opened, closed
 
-    @pl.when(iq == 0 if per_head else step == 0)
+    @pl.when(first)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     if qs_ref is not None:
-        @pl.when(step == 0)
+        @pl.when(opened)
         def _init_shared():
             dks_acc[...] = jnp.zeros_like(dks_acc)
 
-    def _step():
+    def _step(cut):
         kb = k_ref[0]
         vb = v_ref[0]
         qb = q_ref[0]
         dob = do_ref[0]
         lseb = lse_ref[0, 0]
         deltab = delta_ref[0, 0]
-        s = _scores(qb, kb, qs_ref, ks_ref) * scale
-        if mask_ref is not None:
-            # broadcast, then convert, as the forward does: converting the
-            # (bk,) row to bool first lowers to a relayout of every tile
-            # that tripled both backward kernels on bf16 operands (v5e, PR
-            # 26: dkv 2.47 -> 0.78 ms, dq 1.58 -> 0.51 ms a call at
-            # (384, 512, 64); gradients bit-identical)
-            s = jnp.where(mask_ref[0, 0][None, :].astype(bool), s, _NEG)
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
-            cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + jk * bk
-            s = jnp.where(_band(rows, cols, causal_off, window), s, _NEG)
-        # masked entries: exp(s - lse) can overflow for fully-masked rows
-        # (lse floors at m + log eps); they carry no gradient — zero them.
-        p = jnp.where(s > _NEG * 0.5, jnp.exp(s - lseb[:, None]), 0.0)
+        s = _scores(qb, kb, qs_ref, ks_ref, mask_ref, scale,
+                    (iq, jk, causal_off, window) if cut else None)
+        p = _probs(s, lseb, cut or mask_ref is not None)
         pb = p.astype(dob.dtype)
         dv_acc[...] += jax.lax.dot_general(
             pb, dob, (((0,), (0,)), ((), ())),
@@ -461,56 +567,44 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 ds, qs_ref[0], (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(_causal_live(iq, jk, bq, bk, causal_off, window))(_step)
-    else:
-        _step()
+    run(_step)
 
-    @pl.when(iq == pl.num_programs(2) // group - 1 if per_head else step == last)
+    @pl.when(last)
     def _finish():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
     if qs_ref is not None:
-        @pl.when(step == last)
+        @pl.when(closed)
         def _finish_shared():
             dks_ref[0] = dks_acc[...].astype(dks_ref.dtype)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   qs_ref, ks_ref, mask_ref, dq_ref, *more, scale, causal,
+def _bwd_dq_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   qs_ref, ks_ref, mask_ref, dq_ref, *more, scale,
                    causal_off, window=None):
     if qs_ref is None:
         dq_acc, = more
     else:
         dqs_ref, dq_acc, dqs_acc = more
-    bq, d = q_ref.shape[1], q_ref.shape[2]
-    bk = k_ref.shape[1]
-    iq = pl.program_id(1)
-    jk = pl.program_id(2)
-    nk = pl.num_programs(2)
+    iq, jk, (first, last, _, _), run = _walk(sched)
 
-    @pl.when(jk == 0)
+    @pl.when(first)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
         if qs_ref is not None:
             dqs_acc[...] = jnp.zeros_like(dqs_acc)
 
-    def _step():
+    def _step(cut):
         qb = q_ref[0]
         kb = k_ref[0]
         vb = v_ref[0]
         dob = do_ref[0]
         lseb = lse_ref[0, 0]
         deltab = delta_ref[0, 0]
-        s = _scores(qb, kb, qs_ref, ks_ref) * scale
-        if mask_ref is not None:
-            s = jnp.where(mask_ref[0, 0][None, :].astype(bool), s, _NEG)
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0) + iq * bq
-            cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1) + jk * bk
-            s = jnp.where(_band(rows, cols, causal_off, window), s, _NEG)
-        p = jnp.where(s > _NEG * 0.5, jnp.exp(s - lseb[:, None]), 0.0)
+        s = _scores(qb, kb, qs_ref, ks_ref, mask_ref, scale,
+                    (iq, jk, causal_off, window) if cut else None)
+        p = _probs(s, lseb, cut or mask_ref is not None)
         dp = jax.lax.dot_general(dob, vb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = (p * (dp - deltab[:, None]) * scale).astype(kb.dtype)
@@ -522,12 +616,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 ds, ks_ref[0], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when(_causal_live(iq, jk, bq, bk, causal_off, window))(_step)
-    else:
-        _step()
+    run(_step)
 
-    @pl.when(jk == nk - 1)
+    @pl.when(last)
     def _finish():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
         if qs_ref is not None:
@@ -554,125 +645,80 @@ def _bwd(q, k, v, key_mask, causal, scale, o, lse, do, dlse=None,
         # ring attention.
         delta = delta - dlse.astype(jnp.float32)
     q3 = q.reshape(BH, Lq, D)
-    k3, v3 = k.reshape(BHkv, Lk, D), v.reshape(BHkv, Lk, D)
-    do3 = do.reshape(BH, Lq, D)
-    lse3 = lse.reshape(BH, 1, Lq)
-    delta3 = delta.reshape(BH, 1, Lq)
     interpret = _interpret_for(q3)
-    kwargs = {} if interpret else {"compiler_params": _COMPILER_PARAMS}
-    off = Lk - Lq
-    args = [q3, k3, v3, do3, lse3, delta3]
+    args = [q3, k.reshape(BHkv, Lk, D), v.reshape(BHkv, Lk, D),
+            do.reshape(BH, Lq, D), lse.reshape(BH, 1, Lq), delta.reshape(BH, 1, Lq)]
+    Ds = None
     if shared is not None:
         Ds = shared[0].shape[-1]
         args += [shared[0].reshape(BH, Lq, Ds), shared[1].reshape(B, Lk, Ds)]
     if key_mask is not None:
         args.append(key_mask.astype(jnp.int32).reshape(-1, 1, Lk))
     present = (shared is not None, shared is not None, key_mask is not None)
+    params = dict(scale=scale, causal_off=Lk - Lq, window=window)
 
-    # ---- dk/dv: fixed k-block (parallel), stream q-blocks (arbitrary);
-    # grid over K/V heads, the stream covering the group's query heads
-    if S == 1:
-        def qh(b, t):                   # the query head of step t
-            return b
+    # ---- dk/dv: fixed k-block, stream q-blocks; grid rows are K/V heads
+    # (batch rows with the shared pair), the stream covering their S query
+    # heads one after another
+    name = _kernel_name("flash_bwd_dkv", window, shared)
+    sched = _schedule(name, causal, Lq, Lk, bq, bk, window, S)
+    if sched is None:
+        def place(b, j, t):
+            return (b, b, t, j) if S == 1 else (b, b * S + t // nq, t % nq, j)
     else:
-        def qh(b, t):
-            return b * S + t // nq
-    if causal:      # dead tiles fetch nothing (see _live_k)
-        def ii(j, t):
-            return _live_q(j, bq, bk, nq, off, window)(t if S == 1 else t % nq)
-    elif S == 1:
-        def ii(j, t):
-            return t
-    else:
-        def ii(j, t):
-            return t % nq
-
-    def q_spec(width):
-        return pl.BlockSpec((1, bq, width), lambda b, j, t: (qh(b, t), ii(j, t), 0),
-                            memory_space=_VMEM)
-    row_spec = pl.BlockSpec((1, 1, bq), lambda b, j, t: (qh(b, t), 0, ii(j, t)),
-                            memory_space=_VMEM)
+        def place(b, t, kj, qi, kind, head):
+            return b, (b if S == 1 else b * S + head[t]), qi[t], kj[t]
+    spec = functools.partial(_spec, place)
     # a K/V head's block is the grid row's own; with the shared pair it is
     # the streamed head's, and the grid row's own block is the shared key's
-    kv_spec = pl.BlockSpec(
-        (1, bk, D), (lambda b, j, t: (b, j, 0)) if shared is None else
-        (lambda b, j, t: (qh(b, t), j, 0)), memory_space=_VMEM)
-    dkv_specs = [q_spec(D), kv_spec, kv_spec, q_spec(D), row_spec, row_spec]
+    kv_spec = spec((1, bk, D), (lambda b, h, i, j: (b, j, 0)) if shared is None
+                   else (lambda b, h, i, j: (h, j, 0)))
+    in_specs = [spec((1, bq, D), _q_block), kv_spec, kv_spec,
+                spec((1, bq, D), _q_block), spec((1, 1, bq), _q_row),
+                spec((1, 1, bq), _q_row)]
     out_specs = [kv_spec, kv_spec]
     out_shape = [jax.ShapeDtypeStruct((BHkv, Lk, D), k.dtype),
                  jax.ShapeDtypeStruct((BHkv, Lk, D), v.dtype)]
     scratch = [pltpu.VMEM((bk, D), jnp.float32), pltpu.VMEM((bk, D), jnp.float32)]
     if shared is not None:
-        ks_spec = pl.BlockSpec((1, bk, Ds), lambda b, j, t: (b, j, 0),
-                               memory_space=_VMEM)
-        dkv_specs += [q_spec(Ds), ks_spec]
+        ks_spec = spec((1, bk, Ds), lambda b, h, i, j: (b, j, 0))
+        in_specs += [spec((1, bq, Ds), _q_block), ks_spec]
         out_specs.append(ks_spec)
         out_shape.append(jax.ShapeDtypeStruct((B, Lk, Ds), shared[1].dtype))
         scratch.append(pltpu.VMEM((bk, Ds), jnp.float32))
     if key_mask is not None:
-        dkv_specs.append(pl.BlockSpec((1, 1, bk),
-                                      lambda b, j, t: (b // (H // S), 0, j),
-                                      memory_space=_VMEM))
-    dkv_kern = functools.partial(
-        _optional_inputs(_bwd_dkv_kernel, 6, present),
-        scale=scale, causal=causal, causal_off=off, window=window,
-        **({} if S == 1 else {"group": S}))
-    dk, dv, *dks = pl.pallas_call(
-        dkv_kern,
-        name=_kernel_name("flash_bwd_dkv", window, shared),
-        grid=(BH // S, nk, S * nq),
-        in_specs=dkv_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-        **kwargs,
-    )(*args)
+        in_specs.append(spec((1, 1, bk), lambda b, h, i, j: (b // (H // S), 0, j)))
+    dk, dv, *dks = _pallas(
+        functools.partial(
+            _optional_inputs(_bwd_dkv_kernel, len(sched or ()), 6, present),
+            **params, **({} if S == 1 else {"group": S})),
+        name, sched, (BH // S, nk, S * nq), interpret, in_specs, out_specs,
+        out_shape, scratch, args)
 
-    # ---- dq: fixed q-block (parallel), stream k-blocks (arbitrary)
-    if causal:
-        def jj(i, j):
-            return _live_k(i, bq, bk, nk, off, window)(j)
-    else:
-        def jj(i, j):
-            return j
-
-    def q_spec(width):
-        return pl.BlockSpec((1, bq, width), lambda b, i, j: (b, i, 0),
-                            memory_space=_VMEM)
-    row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i),
-                            memory_space=_VMEM)
-    kv_spec = pl.BlockSpec(
-        (1, bk, D), (lambda b, i, j: (b, jj(i, j), 0)) if G == 1 else
-        (lambda b, i, j: (b // G, jj(i, j), 0)), memory_space=_VMEM)
-    dq_specs = [q_spec(D), kv_spec, kv_spec, q_spec(D), row_spec, row_spec]
-    out_specs, out_shape = [q_spec(D)], [jax.ShapeDtypeStruct((BH, Lq, D), q.dtype)]
+    # ---- dq: fixed q-block, stream k-blocks; grid rows are query heads
+    name = _kernel_name("flash_bwd_dq", window, shared)
+    sched = _schedule(name, causal, Lq, Lk, bq, bk, window)
+    spec = functools.partial(_spec, _place_qk(sched))
+    kv_spec = spec((1, bk, D), _kv_block(G))
+    in_specs = [spec((1, bq, D), _q_block), kv_spec, kv_spec,
+                spec((1, bq, D), _q_block), spec((1, 1, bq), _q_row),
+                spec((1, 1, bq), _q_row)]
+    out_specs = [spec((1, bq, D), _q_block)]
+    out_shape = [jax.ShapeDtypeStruct((BH, Lq, D), q.dtype)]
     scratch = [pltpu.VMEM((bq, D), jnp.float32)]
     if shared is not None:
-        dq_specs += [q_spec(Ds),
-                     pl.BlockSpec((1, bk, Ds), lambda b, i, j: (b // H, jj(i, j), 0),
-                                  memory_space=_VMEM)]
-        out_specs.append(q_spec(Ds))
+        in_specs += [spec((1, bq, Ds), _q_block),
+                     spec((1, bk, Ds), lambda b, h, i, j: (h // H, j, 0))]
+        out_specs.append(spec((1, bq, Ds), _q_block))
         out_shape.append(jax.ShapeDtypeStruct((BH, Lq, Ds), shared[0].dtype))
         scratch.append(pltpu.VMEM((bq, Ds), jnp.float32))
     if key_mask is not None:
-        dq_specs.append(pl.BlockSpec((1, 1, bk),
-                                     lambda b, i, j: (b // H, 0, jj(i, j)),
-                                     memory_space=_VMEM))
-    dq_kern = functools.partial(
-        _optional_inputs(_bwd_dq_kernel, 6, present),
-        scale=scale, causal=causal, causal_off=off, window=window)
-    dq, *dqs = pl.pallas_call(
-        dq_kern,
-        name=_kernel_name("flash_bwd_dq", window, shared),
-        grid=(BH, nq, nk),
-        in_specs=dq_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-        **kwargs,
-    )(*args)
+        in_specs.append(spec((1, 1, bk), lambda b, h, i, j: (h // H, 0, j)))
+    dq, *dqs = _pallas(
+        functools.partial(
+            _optional_inputs(_bwd_dq_kernel, len(sched or ()), 6, present), **params),
+        name, sched, (BH, nq, nk), interpret, in_specs, out_specs, out_shape,
+        scratch, args)
     grads = (dq.reshape(B, H, Lq, D), dk.reshape(B, H // G, Lk, D),
              dv.reshape(B, H // G, Lk, D))
     if shared is None:
@@ -746,8 +792,8 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
 
     ``window`` (requires ``causal=True``): causal sliding-window attention —
     position i attends to the ``window`` most recent keys only. Tiles fully
-    outside the band are skipped, so compute is O(L·window) not O(L²): the
-    Mistral-style long-context recipe, native to the tile grid.
+    outside the band are no grid step, so compute is O(L·window) not O(L²):
+    the Mistral-style long-context recipe, native to the tile schedule.
 
     ``shared=(q_s, k_s)``: a second score term, ``q_s (B, H, Lq, Ds)``
     against the one key head ``k_s (B, 1, Lk, Ds)`` that every query head

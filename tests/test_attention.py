@@ -304,3 +304,207 @@ def test_flash_under_a_mesh_runs_per_shard(axes, masked):
     assert "shard_map" in jaxpr and "shard_map" not in ref_jaxpr
     for g, w in zip(got, want):
         onp.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the causal kernels' tile schedule (flash_attention._tile_schedule)
+# ---------------------------------------------------------------------------
+
+def _tile_truth(Lq, Lk, bq, bk, window):
+    """``{(i, j): every pair visible?}`` for the tiles with a visible pair,
+    by brute force over the kernel's own in-tile mask."""
+    from incubator_mxnet_tpu.ops.pallas.flash_attention import _band
+    rows, cols = onp.arange(Lq)[:, None], onp.arange(Lk)[None, :]
+    seen = onp.asarray(_band(rows, cols, Lk - Lq, window))
+    tiles = seen.reshape(Lq // bq, bq, Lk // bk, bk).transpose(0, 2, 1, 3)
+    return {(i, j): bool(tiles[i, j].all()) for i in range(Lq // bq)
+            for j in range(Lk // bk) if tiles[i, j].any()}
+
+
+# (Lq, Lk, bq, bk): square, Lq < Lk (decode shapes; with a window, key
+# blocks older than every query's window), Lq > Lk (rows with no key), and
+# tiles that are not square
+_SCHEDULE_SHAPES = [(256, 256, 64, 64), (128, 256, 64, 64), (256, 128, 64, 64),
+                    (256, 256, 64, 32), (256, 256, 32, 64)]
+
+
+@pytest.mark.parametrize("heads", [None, 1, 3], ids=["stream_k", "dkv", "dkv_3_heads"])
+@pytest.mark.parametrize("window", [None, 16, 64, 100, 300],
+                         ids=["full", "w_lt_tile", "w_eq_tile", "w_not_multiple", "w_gt_L"])
+@pytest.mark.parametrize("shape", _SCHEDULE_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_tile_schedule_lists_the_live_tiles_once(shape, window, heads):
+    from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
+    Lq, Lk, bq, bk = shape
+    truth = _tile_truth(Lq, Lk, bq, bk, window)
+    sched = fa._tile_schedule(Lq, Lk, bq, bk, window, heads)
+    assert len(sched.tables) == (3 if heads is None else 4)
+    assert all(t.dtype == onp.int32 and t.shape == sched.tables[0].shape for t in sched.tables)
+    fixed, streamed, kind = (t.tolist() for t in sched.tables[:3])
+    head = sched.tables[3].tolist() if heads else [0] * len(kind)
+    live = [(f, s, h) for f, s, k, h in zip(fixed, streamed, kind, head)
+            if k & (fa._WHOLE | fa._CUT)]
+    # every tile with a visible pair once a head, none without one, in the
+    # rectangular grid's order: fixed block, head, streamed block
+    tile = (lambda f, s: (f, s)) if heads is None else (lambda f, s: (s, f))
+    assert live == sorted(set(live), key=lambda x: (x[0], x[2], x[1]))
+    assert {tile(f, s) for f, s, _ in live} == set(truth)
+    assert len(live) == len(truth) * (heads or 1)
+    # cut exactly where some pair is hidden; never both kinds
+    for f, s, k in zip(fixed, streamed, kind):
+        if k & (fa._WHOLE | fa._CUT):
+            assert bool(k & fa._WHOLE) == truth[tile(f, s)]
+            assert bool(k & fa._CUT) != truth[tile(f, s)]
+    # a fixed block (and head) with no live tile has the one step that
+    # writes its zeros; first / last one each a (fixed block, head), at its
+    # ends; open / close one each a fixed block
+    n_fixed = (Lq // bq) if heads is None else (Lk // bk)
+    segments = {}
+    for t, (f, h) in enumerate(zip(fixed, head)):
+        segments.setdefault((f, h), []).append(t)
+    assert sorted(segments) == [(f, h) for f in range(n_fixed) for h in range(heads or 1)]
+    for (f, h), steps in segments.items():
+        assert steps == list(range(steps[0], steps[-1] + 1))
+        kinds = [kind[t] for t in steps]
+        if not any(k & (fa._WHOLE | fa._CUT) for k in kinds):
+            assert len(steps) == 1
+        else:
+            assert all(k & (fa._WHOLE | fa._CUT) for k in kinds)
+        assert [bool(k & fa._FIRST) for k in kinds] == [True] + [False] * (len(steps) - 1)
+        assert [bool(k & fa._LAST) for k in kinds] == [False] * (len(steps) - 1) + [True]
+        assert [bool(k & fa._OPEN) for k in kinds] == [h == 0] + [False] * (len(steps) - 1)
+        assert [bool(k & fa._CLOSE) for k in kinds] == \
+            [False] * (len(steps) - 1) + [h == (heads or 1) - 1]
+    assert sched.live_share == len(truth) / ((Lq // bq) * (Lk // bk))
+    assert sched.cut_share == sum(not w for w in truth.values()) / len(truth)
+
+
+@pytest.mark.parametrize("window,steps,cut", [(None, 136, 16), (2048, 70, 28)],
+                         ids=["full", "window2048"])
+def test_tile_schedule_and_its_gauges_at_the_cells_shapes(window, steps, cut):
+    """L = 8,192 at 512-row tiles: 136 steps a head where the rectangle has
+    256 (70 with Trinity's window), and the gauges say so for each kernel a
+    causal call traces; a non-causal call sets nothing."""
+    from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
+    from incubator_mxnet_tpu.telemetry import metrics
+    sched = fa._tile_schedule(8192, 8192, 512, 512, window)
+    assert len(sched.tables[0]) == steps
+    assert len(fa._tile_schedule(8192, 8192, 512, 512, window, 8).tables[0]) == 8 * steps
+    names = [fa._kernel_name(k, window) for k in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq")]
+    gauges = [metrics.gauge(f"mxtpu_flash_tiles_{what}_share", kernel=name)
+              for name in names for what in ("live", "cut")]
+    for g in gauges:
+        g.set(-1.0)
+    q = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 1, 8192, 128), jnp.bfloat16)
+    if window is None:
+        jax.eval_shape(jax.grad(lambda q, k, v: flash_attention(q, k, v).sum().astype(
+            jnp.float32), (0, 1, 2)), q, kv, kv)
+        assert [g.value for g in gauges] == [-1.0] * 6
+    jax.eval_shape(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window).sum().astype(jnp.float32), (0, 1, 2)), q, kv, kv)
+    assert [g.value for g in gauges] == [steps / 256, cut / steps] * 3
+    assert round(steps / 256, 2) == (0.53 if window is None else 0.27)
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation under ``jaxpr``, by kernel name."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found[eqn.params["name"]] = eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.update(_pallas_calls(sub))
+    return found
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_only_a_causal_call_walks_a_schedule(causal, monkeypatch):
+    """A non-causal call has no dead tile and no band: its three kernels
+    keep the rectangular grid and take no scalar-prefetch operand. A causal
+    call's streamed dimension has one step a live tile."""
+    from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
+    monkeypatch.setenv("MXTPU_FLASH_BQ", "64")
+    monkeypatch.setenv("MXTPU_FLASH_BK", "64")
+    B, H, Hkv, L, D = 2, 4, 2, 256, 16
+    q = jnp.zeros((B, H, L, D), jnp.float32)
+    kv = jnp.zeros((B, Hkv, L, D), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal).sum(), (0, 1, 2)))(q, kv, kv)
+    calls = _pallas_calls(jaxpr.jaxpr)
+    assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    grids = {name: (eqn.params["grid_mapping"].grid,
+                    eqn.params["grid_mapping"].num_index_operands)
+             for name, eqn in calls.items()}
+    if not causal:
+        assert grids == {"flash_fwd": ((B * H, 4, 4), 0), "flash_bwd_dq": ((B * H, 4, 4), 0),
+                         "flash_bwd_dkv": ((B * Hkv, 4, 2 * 4), 0)}
+    else:
+        live = len(_tile_truth(L, L, 64, 64, None))        # 10 of 16
+        assert grids == {"flash_fwd": ((B * H, live), 3), "flash_bwd_dq": ((B * H, live), 3),
+                         "flash_bwd_dkv": ((B * Hkv, 2 * live), 4)}
+
+
+def _reference(q, k, v, mask=None, window=None, shared=None):
+    """Dense causal attention (bottom-right aligned) over grouped K/V heads,
+    with an optional window, key mask and shared second score term; a row
+    that sees no key gives zeros, as the kernel does."""
+    B, H, Lq, D = q.shape
+    Lk = k.shape[2]
+    k, v = (jnp.repeat(x, H // x.shape[1], axis=1) for x in (k, v))
+    if shared is not None:
+        q = jnp.concatenate([q, shared[0]], -1)
+        k = jnp.concatenate([k, jnp.broadcast_to(shared[1], (B, H, Lk, shared[1].shape[-1]))], -1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * (q.shape[-1] ** -0.5)
+    seen = jnp.tril(jnp.ones((Lq, Lk), bool), Lk - Lq)
+    if window is not None:
+        seen = seen & jnp.triu(jnp.ones((Lq, Lk), bool), Lk - Lq - window + 1)
+    seen = jnp.broadcast_to(seen, s.shape)
+    if mask is not None:
+        seen = seen & mask[:, None, None, :]
+    p = jax.nn.softmax(jnp.where(seen, s, -1e30), -1)
+    return jnp.einsum("bhqk,bhkd->bhqd", jnp.where(seen.any(-1, keepdims=True), p, 0.0), v)
+
+
+# (Hkv, Lq, Lk, window, key padding, width of the shared pair) at H = 4 and
+# 64-row tiles: four tiles a side, so every kind of tile is there
+@pytest.mark.parametrize("Hkv,Lq,Lk,window,padded,Ds", [
+    pytest.param(4, 256, 256, None, False, None, id="causal"),
+    pytest.param(4, 256, 256, 100, False, None, id="window"),
+    pytest.param(4, 256, 256, 64, True, None, id="window_padded"),
+    pytest.param(4, 256, 256, None, True, None, id="padded"),
+    pytest.param(2, 256, 256, None, False, None, id="grouped"),
+    pytest.param(1, 256, 256, 70, True, None, id="grouped_window_padded"),
+    pytest.param(4, 256, 256, None, False, 8, id="shared"),
+    pytest.param(4, 256, 256, 100, True, 8, id="shared_window_padded"),
+    pytest.param(2, 128, 256, None, False, None, id="grouped_lq_lt_lk"),
+    pytest.param(4, 128, 256, 40, False, None, id="window_lq_lt_lk"),
+    pytest.param(2, 256, 128, None, True, None, id="grouped_padded_lq_gt_lk"),
+    pytest.param(4, 256, 128, 70, False, 8, id="shared_window_lq_gt_lk"),
+])
+def test_scheduled_kernels_match_dense(Hkv, Lq, Lk, window, padded, Ds, monkeypatch):
+    monkeypatch.setenv("MXTPU_FLASH_BQ", "64")
+    monkeypatch.setenv("MXTPU_FLASH_BK", "64")
+    rng = onp.random.RandomState(11)
+    B, H, D = 2, 4, 16
+
+    def draw(*shape):
+        return jnp.asarray(rng.randn(*shape), jnp.float32)
+    q, k, v = draw(B, H, Lq, D), draw(B, Hkv, Lk, D), draw(B, Hkv, Lk, D)
+    shared = None if Ds is None else (draw(B, H, Lq, Ds), draw(B, 1, Lk, Ds))
+    mask = None
+    if padded:
+        mask = jnp.asarray(onp.arange(Lk)[None, :] < onp.array([Lk - 37, Lk])[:, None])
+
+    def flash(q, k, v, shared):
+        return flash_attention(q, k, v, mask=mask, causal=True, window=window, shared=shared)
+
+    def dense(q, k, v, shared):
+        return _reference(q, k, v, mask, window, shared)
+    out, vjp = jax.vjp(flash, q, k, v, shared)
+    ref, ref_vjp = jax.vjp(dense, q, k, v, shared)
+    onp.testing.assert_allclose(onp.asarray(out), onp.asarray(ref), atol=2e-4)
+    do = draw(B, H, Lq, D) * 0.1
+    for got, want in zip(jax.tree_util.tree_leaves(vjp(do)),
+                         jax.tree_util.tree_leaves(ref_vjp(do))):
+        assert got.shape == want.shape
+        onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want), atol=3e-4)
