@@ -28,6 +28,8 @@ from . import afmoe  # noqa: F401
 from .afmoe import AfmoeModel, get_afmoe, afmoe_lm_loss  # noqa: F401
 from . import deepseek_v3  # noqa: F401
 from .deepseek_v3 import DeepseekV3Model, get_deepseek_v3  # noqa: F401
+from . import lfm2_moe  # noqa: F401
+from .lfm2_moe import Lfm2MoeModel, get_lfm2_moe  # noqa: F401
 
 #: Serving axis specs per model family — the ``input_axes``/``pad_values``
 #: a ``serve.CompiledModel``/``ModelRegistry.load`` needs to bucket each

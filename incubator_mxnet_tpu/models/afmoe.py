@@ -162,15 +162,16 @@ class AfmoeAttention(HybridBlock):
 
 
 class AfmoeMoE(HybridBlock):
-    """Shared experts for every token plus the part of the routed result
-    that the experts held here give: ``held = (first, count)`` of
-    ``num_experts``. No assignment is dropped. Returns ``(out, rows)``,
-    ``rows (count,)`` the rows each expert held got in this call."""
+    """Shared experts for every token (``num_shared`` of them as one gated
+    FFN; none with 0) plus the part of the routed result that the experts
+    held here give: ``held = (first, count)`` of ``num_experts``. No
+    assignment is dropped. Returns ``(out, rows)``, ``rows (count,)`` the
+    rows each expert held got in this call."""
 
     def __init__(self, units: int, hidden: int, num_experts: int, top_k: int,
                  held, num_shared: int = 1, route_norm: bool = True,
                  route_scale: float = 1.0, tile_rows: int = TILE_ROWS,
-                 dtype="float32", **kwargs):
+                 dtype="float32", route_norm_eps: float = 1e-20, **kwargs):
         super().__init__(**kwargs)
         first, count = held
         if not 0 <= first <= first + count <= num_experts or count < 1:
@@ -178,6 +179,7 @@ class AfmoeMoE(HybridBlock):
                              f"{num_experts} experts")
         self._held, self._top_k, self._tile_rows = (first, count), top_k, tile_rows
         self._route_norm, self._route_scale = route_norm, route_scale
+        self._route_norm_eps = route_norm_eps
         #: set to a list by ``AfmoeModel.routing``: each eager call appends
         #: what its own routing function chose
         self.routes = None
@@ -195,7 +197,7 @@ class AfmoeMoE(HybridBlock):
             self.expert_rows = self.params.get(
                 "expert_rows", shape=(count,), init="zeros", grad_req="null")
             self.shared = GatedFFN(units, hidden * num_shared, dtype,
-                                   prefix="shared_")
+                                   prefix="shared_") if num_shared else None
             self.experts_w13 = self.params.get(
                 "experts_w13", shape=(count, 2 * hidden, units), dtype=dtype)
             self.experts_w2 = self.params.get(
@@ -208,7 +210,7 @@ class AfmoeMoE(HybridBlock):
         with jax.named_scope("moe_router"):
             idx, weight = moe_dropless.sigmoid_topk(
                 tokens, router_weight._data, expert_bias._data, self._top_k,
-                self._route_norm, self._route_scale)
+                self._route_norm, self._route_scale, self._route_norm_eps)
             plan = moe_dropless.plan_rows(idx, self._held, self._tile_rows)
         if self.routes is not None and not isinstance(idx, jax.core.Tracer):
             self.routes.append(dict(
@@ -216,7 +218,9 @@ class AfmoeMoE(HybridBlock):
         routed = moe_dropless.routed_experts(
             tokens, idx, weight.astype(jnp.float32), experts_w13._data,
             experts_w2._data, self._held, self._tile_rows, plan=plan)
-        return (self.shared(x) + NDArray(routed.reshape(B, L, C), ctx=x.context),
+        shared = None if self.shared is None else self.shared(x)
+        routed = NDArray(routed.reshape(B, L, C), ctx=x.context)
+        return (routed if shared is None else shared + routed,
                 NDArray(plan.counts.astype(jnp.float32), ctx=x.context))
 
 
@@ -246,17 +250,20 @@ class AfmoeDecoderLayer(HybridBlock):
 
 class DecoderLM(HybridBlock):
     """What the decoder-only language models here share: embedding, a list
-    of decoder layers, final norm and an untied LM head over the vocabulary
-    held, with the forward contract of the module docstring. A family gives
+    of decoder layers, final norm and an LM head over the vocabulary held
+    (its own matrix, or with ``tie_embeddings`` the embedding's read the
+    other way), with the forward contract of the module docstring. A family gives
     its layers (:meth:`decoder_layers`): each is called as ``layer(x,
     positions, key_mask)``, returns ``(x', rows)`` (``rows`` what a MoE FFN
     counted, else ``None``) and keeps its FFN as ``layer.ffn``. ``cfg``
     carries the source's own keys."""
 
     def __init__(self, cfg: dict, dtype="float32", remat: bool = False,
-                 embed_scale: float = 1.0, **kwargs):
+                 embed_scale: float = 1.0, tie_embeddings: bool = False,
+                 epsilon: float = None, **kwargs):
         super().__init__(**kwargs)
-        units, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
+        units = cfg["hidden_size"]
+        eps = cfg["rms_norm_eps"] if epsilon is None else epsilon   # the final norm's
         self._units, self._remat, self._embed_scale = units, remat, embed_scale
         with self.name_scope():
             self.embed = nn.Embedding(cfg["vocab_size"], units, dtype=dtype,
@@ -266,7 +273,12 @@ class DecoderLM(HybridBlock):
                 self.register_child(layer, f"layer{i}")
                 self.layers.append(layer)
             self.norm = RMSNorm(units, eps, prefix="norm_")
-            self.lm_head = _dense(cfg["vocab_size"], units, dtype, "lm_head_")
+            if tie_embeddings:
+                # one parameter under two names: registered here, it reaches
+                # hybrid_forward as the compiled step's own argument
+                self.lm_head, self.head_weight = None, self.embed.weight
+            else:
+                self.lm_head = _dense(cfg["vocab_size"], units, dtype, "lm_head_")
 
     def decoder_layers(self, cfg: dict, dtype):
         """The family's layers in order, layer ``i`` under the prefix
@@ -297,10 +309,18 @@ class DecoderLM(HybridBlock):
                 layer.ffn.expert_rows._deposit_aux(rows, ctx)
         return self.norm(x), NDArray(valid.astype(jnp.float32), ctx=ctx)
 
-    def hybrid_forward(self, F, ids, positions, valid_length):
-        x, valid = self.hidden(ids, positions, valid_length)
+    def head(self, x, weight=None):
+        """Logits ``(B, L, vocab)`` of the final normed hidden state ``x``;
+        ``weight``: the tied matrix where the caller holds it already."""
         with jax.named_scope("lm_head"):
-            return self.lm_head(x), valid
+            if self.lm_head is not None:
+                return self.lm_head(x)
+            weight = self.head_weight.data(x.context) if weight is None else weight
+            return NDArray(jnp.matmul(x._data, weight._data.T), ctx=x.context)
+
+    def hybrid_forward(self, F, ids, positions, valid_length, head_weight=None):
+        x, valid = self.hidden(ids, positions, valid_length)
+        return self.head(x, head_weight), valid
 
     def routing(self, ids, positions, valid_length, publish: bool = True) -> list:
         """One eager forward that records, for each MoE layer in order, what

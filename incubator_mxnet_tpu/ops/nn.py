@@ -935,3 +935,68 @@ def ctc_loss(data, label, data_lengths=None, label_lengths=None,
         return -jnp.logaddexp(p1, p2)
 
     return jax.vmap(per_example)(jnp.transpose(logp, (1, 0, 2)), ext, lab_len, t_len)
+
+
+# ---------------------------------------------------------------------------
+# Gated short convolution (LFM2-style conv mixer; no reference counterpart)
+# ---------------------------------------------------------------------------
+
+def short_conv_gate_plain(bcx, w):
+    """:func:`short_conv_gate` in plain ``jax.numpy``, differentiated by jax
+    itself: what XLA makes of the op, and the oracle the fused op and its
+    kernels are tested against. Products and the taps' sum in fp32."""
+    C, K = w.shape
+    b, c, x = jnp.split(bcx.astype(jnp.float32), 3, axis=-1)
+    L = bcx.shape[1]
+    s = jnp.pad(b * x, ((0, 0), (K - 1, 0), (0, 0)))
+    conv = sum(w[:, k].astype(jnp.float32) * s[:, k:k + L] for k in range(K))
+    return (c * conv).astype(bcx.dtype)
+
+
+def _short_conv_kernels(bcx, w):
+    """The Pallas kernel pair (its module) on a TPU where the shapes allow,
+    ``None`` for the plain form elsewhere: by platform and shape, as
+    ``dot_product_attention`` chooses its flash kernels."""
+    from .pallas import short_conv
+    on_chip = not short_conv._interpret_for(bcx) and short_conv.supported(bcx, w)
+    return short_conv if on_chip else None
+
+
+@jax.custom_vjp
+def _short_conv_gate(bcx, w):
+    kernels = _short_conv_kernels(bcx, w)
+    return kernels.forward(bcx, w) if kernels else short_conv_gate_plain(bcx, w)
+
+
+def _short_conv_gate_fwd(bcx, w):
+    return _short_conv_gate(bcx, w), (bcx, w)
+
+
+def _short_conv_gate_bwd(res, dy):
+    bcx, w = res
+    kernels = _short_conv_kernels(bcx, w)
+    if kernels:
+        return kernels.backward(bcx, w, dy)
+    return jax.vjp(short_conv_gate_plain, bcx, w)[1](dy)      # rebuilt from the inputs
+
+
+_short_conv_gate.defvjp(_short_conv_gate_fwd, _short_conv_gate_bwd)
+
+
+@register_op()
+def short_conv_gate(bcx, w, **_):
+    """Gated causal depthwise short convolution, fused: ``bcx (B, L, 3C)``
+    holds ``Bg | Cg | x`` side by side, ``w (C, K)`` one weight a channel and
+    a tap; returns ``Cg * conv(Bg * x) (B, L, C)`` with ``conv(s)[t] = sum_k
+    w[:, k] * s[t - (K - 1) + k]``, ``s`` zero before a row's first
+    position (a state-space-style token mixer without attention: LFM2's
+    ``conv`` layers, ``K = 3``).
+
+    One op with its own backward: ``d bcx`` and ``d w`` come from ``bcx``,
+    ``w`` and ``dy`` alone, so nothing but the inputs is held for the
+    backward pass. On a TPU both ways are one Pallas kernel each
+    (``ops/pallas/short_conv.py``: ``short_conv_fwd``, ``short_conv_bwd``),
+    one pass over the operands; elsewhere :func:`short_conv_gate_plain` and
+    jax's gradient of it, which is also what the kernels are tested against."""
+    with jax.named_scope("short_conv"):
+        return _short_conv_gate(bcx, w)

@@ -49,11 +49,13 @@ def router_scores(x, router_weight):
 
 
 def sigmoid_topk(x, router_weight, select_bias, top_k: int,
-                 route_norm: bool = True, route_scale: float = 1.0):
+                 route_norm: bool = True, route_scale: float = 1.0,
+                 norm_eps: float = 1e-20):
     """``(idx (T, k) int32, weight (T, k) float32)``: sigmoid scores in fp32
     over every expert, the ``top_k`` largest of ``score + select_bias`` (the
     bias steers selection only and carries no gradient), weights the chosen
-    scores themselves, renormalised to sum to one and scaled."""
+    scores themselves, renormalised to sum to one (over their sum +
+    ``norm_eps``, each family's own) and scaled."""
     scores = router_scores(x, router_weight)
     _, idx = jax.lax.top_k(
         scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), top_k)
@@ -62,7 +64,7 @@ def sigmoid_topk(x, router_weight, select_bias, top_k: int,
     chosen = idx[:, :, None] == jnp.arange(scores.shape[1])[None, None, :]
     weight = jnp.where(chosen, scores[:, None, :], 0).sum(-1)
     if route_norm:
-        weight = weight / (weight.sum(-1, keepdims=True) + 1e-20)
+        weight = weight / (weight.sum(-1, keepdims=True) + norm_eps)
     return idx.astype(jnp.int32), weight * route_scale
 
 

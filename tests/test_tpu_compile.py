@@ -25,7 +25,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from incubator_mxnet_tpu import parallel
 from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
-from incubator_mxnet_tpu.ops.pallas import moe_gmm, moe_rows
+from incubator_mxnet_tpu.ops import nn as ops_nn
+from incubator_mxnet_tpu.ops.pallas import moe_gmm, moe_rows, short_conv
 from incubator_mxnet_tpu.parallel import collectives, moe_dropless, ring
 
 
@@ -51,6 +52,7 @@ def for_the_chip(monkeypatch):
     monkeypatch.setattr(fa, "_interpret_for", lambda x: False)
     monkeypatch.setattr(moe_gmm, "_interpret_for", lambda x: False)
     monkeypatch.setattr(moe_rows, "_interpret_for", lambda x: False)
+    monkeypatch.setattr(short_conv, "_interpret_for", lambda x: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -186,6 +188,28 @@ def test_grouped_matmul_compiles_for_v5e(topo, for_the_chip, K, N):
     for kernel in ("moe_gmm", "moe_tgmm"):
         assert re.search(rf'^\s*%\S*{kernel}\S* = .*custom_call_target="tpu_custom_call"',
                          text, re.M), kernel
+
+
+# LFM2-8B-A1B's convolution mixer at the cell's size (4 rows of 8,192 tokens,
+# 2,048 channels, 3 taps), and a row that is no whole number of tiles
+@pytest.mark.parametrize("shape,dtype", [((4, 8192, 2048), "bfloat16"), ((1, 1000, 256), "float32")],
+                         ids=["lfm2_b4_8k_bf16", "ragged_rows_f32"])
+def test_short_conv_gate_compiles_for_v5e(topo, for_the_chip, shape, dtype):
+    """The fused op through its own backward rule: one ``short_conv_fwd``
+    and one ``short_conv_bwd`` kernel, and no convolution left to XLA."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    (B, L, C), dt = shape, jnp.dtype(dtype)
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def f(bcx, w, dy):
+        out, vjp = jax.vjp(ops_nn.short_conv_gate, bcx, w)
+        return (out,) + vjp(dy)
+
+    text = jax.jit(f).lower(sds((B, L, 3 * C)), sds((C, 3)), sds((B, L, C))).compile().as_text()
+    for kernel in ("short_conv_fwd", "short_conv_bwd"):
+        assert len(re.findall(rf'^\s*%\S*{kernel}\S* = .*custom_call_target="tpu_custom_call"',
+                              text, re.M)) == 1, kernel
+    assert " convolution(" not in text
 
 
 # dtype, experts a token, expert width: Trinity-Mini's (top 8, 1,024) and

@@ -617,14 +617,19 @@ def gen_profiler() -> str:
            "attention: `flash_fwd_mla`, `flash_bwd_dkv_mla`, "
            "`flash_bwd_dq_mla`, read by `mla_attn_ms.train` and "
            "`mla_attn_roofline.train`); `moe_gmm`, `moe_tgmm`; "
-           "`moe_rows_*`. `jax.named_scope`s sit in every operation's "
+           "`moe_rows_*`; `short_conv_fwd`, `short_conv_bwd` (the gated "
+           "short convolution `ops.nn.short_conv_gate`, one pass over its "
+           "operands each way, read by `short_conv_ms.train` and "
+           "`short_conv_roofline.train`). `jax.named_scope`s sit in every operation's "
            "`op_name`: `afmoe_attention`, `moe_router`, `moe_dispatch`, "
            "`moe_experts`, `moe_combine`, `lm_head`, and in "
            "`models/deepseek_v3.py`'s attention block `mla_q` (the query "
            "projection and its split), `mla_latent` (the down-projection, "
            "the latent's norm), `mla_kv_up` (the up-projection to keys and "
            "values) and `mla_attention` (rotary on the 64-wide parts, the "
-           "heads' layout, the kernel).",
+           "heads' layout, the kernel); in `models/lfm2_moe.py` "
+           "`lfm2_mixer_conv` and `lfm2_mixer_attn` round the two kinds of "
+           "token mixer, and `short_conv` round the fused op inside the first.",
            "", "## API", "", "| Name | Summary |", "|---|---|"]
     for name in profiler.__all__:
         obj = getattr(profiler, name, None)
