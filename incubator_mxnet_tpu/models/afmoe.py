@@ -51,39 +51,12 @@ from ..gluon.block import HybridBlock, _is_tracing
 from ..gluon import nn
 from ..ndarray import NDArray
 from ..ops.attention import checkpoint_layer, dot_product_attention
+from ..ops.nn import qk_norm_rope, rotary
 from ..ops.pallas.moe_gmm import TILE_ROWS
 from ..parallel import moe_dropless
 
 __all__ = ["AfmoeModel", "DecoderLM", "AfmoeDecoderLayer", "AfmoeAttention", "AfmoeMoE",
            "GatedFFN", "RMSNorm", "get_afmoe", "afmoe_lm_loss", "rotary"]
-
-
-def rotary(x, positions, theta: float, interleaved: bool = False):
-    """Rotary position embedding of ``x (B, L, H, D)`` at ``positions (B,
-    L)``, computed in fp32. A pair's two parts are the two halves of the
-    head dimension (the ``rotate_half`` convention) or, ``interleaved``,
-    the neighbours ``(2i, 2i + 1)``; a pair stays where it was."""
-    D = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
-    if interleaved:
-        # every lane meets its pair's other lane through a 0/1 matrix on the
-        # MXU (exact: one product by 1.0 a lane). On a TPU a strided slice of
-        # the lanes (x[..., 0::2]) is a gather and a shift by one lane a
-        # misaligned pass: 0.8 ms each over 32 heads of 64 at 8,192 tokens
-        # (v5e, PR 31) against the matmul's few tens of microseconds
-        angle = (positions.astype(jnp.float32)[:, :, None, None]
-                 * jnp.repeat(inv_freq, 2))
-        lane = jnp.arange(D)
-        swap = (lane[:, None] == (lane ^ 1)[None, :]).astype(x.dtype)
-        other = jnp.einsum("blhd,de->blhe", x, swap, precision=jax.lax.Precision.HIGHEST,
-                           preferred_element_type=jnp.float32)
-        return (x.astype(jnp.float32) * jnp.cos(angle)
-                + other * jnp.where(lane % 2 == 0, -1.0, 1.0) * jnp.sin(angle)).astype(x.dtype)
-    angle = positions.astype(jnp.float32)[:, :, None, None] * inv_freq
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
 
 
 class RMSNorm(HybridBlock):
@@ -121,6 +94,17 @@ class GatedFFN(HybridBlock):
         return self.down(NDArray(act.astype(g.dtype), ctx=x.context))
 
 
+def normed_heads(attn, x, positions):
+    """``q`` and ``k`` of an attention block as its kernel takes them, ``(B,
+    heads, L, D)``: the block's projections of ``x``, RMS-normed per head
+    with ``q_norm`` / ``k_norm``'s scale and rotated by ``positions`` (not
+    with ``None``): ``ops.nn.qk_norm_rope``, one fused pass on the chip."""
+    return (qk_norm_rope(proj(x)._data, norm.gamma.data()._data, positions, attn._theta,
+                         attn._epsilon, heads)
+            for proj, norm, heads in ((attn.q, attn.q_norm, attn._heads),
+                                      (attn.k, attn.k_norm, attn._kv_heads)))
+
+
 class AfmoeAttention(HybridBlock):
     """Gated causal attention over grouped K/V heads; ``window`` makes it a
     sliding layer (rotary positions, the ``window`` most recent keys)."""
@@ -143,15 +127,11 @@ class AfmoeAttention(HybridBlock):
     def hybrid_forward(self, F, x, positions, key_mask):
         B, L = x.shape[0], x.shape[1]
         H, Hkv, D = self._heads, self._kv_heads, self._dim
-        q = self.q_norm(self.q(x).reshape((B, L, H, D)))._data
-        k = self.k_norm(self.k(x).reshape((B, L, Hkv, D)))._data
-        v = self.v(x)._data.reshape(B, L, Hkv, D)
+        # rotary positions in sliding layers only
+        q, k = normed_heads(self, x, None if self._window is None else positions._data)
+        v = self.v(x)._data.reshape(B, L, Hkv, D).transpose(0, 2, 1, 3)
         gate = self.gate(x)._data
         with jax.named_scope("afmoe_attention"):
-            if self._window is not None:
-                q = rotary(q, positions._data, self._theta)
-                k = rotary(k, positions._data, self._theta)
-            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
             out = dot_product_attention(
                 q, k, v, mask=key_mask._data[:, None, None, :], causal=True,
                 window=self._window, scale=D ** -0.5)

@@ -54,7 +54,7 @@ from ..ndarray import NDArray
 from ..ops.attention import dot_product_attention
 from ..ops.nn import short_conv_gate
 from ..ops.pallas.moe_gmm import TILE_ROWS
-from .afmoe import AfmoeMoE, DecoderLM, GatedFFN, RMSNorm, _dense, rotary
+from .afmoe import AfmoeMoE, DecoderLM, GatedFFN, RMSNorm, _dense, normed_heads
 from .deepseek_v3 import DeepseekV3DecoderLayer
 
 __all__ = ["Lfm2MoeModel", "Lfm2ShortConv", "Lfm2Attention", "get_lfm2_moe"]
@@ -86,7 +86,7 @@ class Lfm2Attention(HybridBlock):
                  dtype="float32", **kwargs):
         super().__init__(**kwargs)
         self._heads, self._kv_heads, self._dim = num_heads, num_kv_heads, units // num_heads
-        self._theta = rope_theta
+        self._theta, self._epsilon = rope_theta, epsilon
         D = self._dim
         with self.name_scope():
             self.q = _dense(num_heads * D, units, dtype, "q_")
@@ -100,11 +100,8 @@ class Lfm2Attention(HybridBlock):
         B, L = x.shape[0], x.shape[1]
         H, Hkv, D = self._heads, self._kv_heads, self._dim
         with jax.named_scope("lfm2_mixer_attn"):
-            q = self.q_norm(self.q(x).reshape((B, L, H, D)))._data
-            k = self.k_norm(self.k(x).reshape((B, L, Hkv, D)))._data
-            v = self.v(x)._data.reshape(B, L, Hkv, D)
-            q, k = (rotary(t, positions._data, self._theta) for t in (q, k))
-            q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+            q, k = normed_heads(self, x, positions._data)
+            v = self.v(x)._data.reshape(B, L, Hkv, D).transpose(0, 2, 1, 3)
             out = dot_product_attention(
                 q, k, v, mask=key_mask._data[:, None, None, :], causal=True,
                 scale=D ** -0.5)

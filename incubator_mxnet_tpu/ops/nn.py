@@ -11,6 +11,7 @@ OIHW, time-major (T, N, C) for the fused RNN op.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -1000,3 +1001,115 @@ def short_conv_gate(bcx, w, **_):
     jax's gradient of it, which is also what the kernels are tested against."""
     with jax.named_scope("short_conv"):
         return _short_conv_gate(bcx, w)
+
+
+# ---------------------------------------------------------------------------
+# q and k between their projections and the attention kernels: per-head
+# RMSNorm, rotary, the head-major layout (no reference counterpart)
+# ---------------------------------------------------------------------------
+
+def rotary(x, positions, theta: float, interleaved: bool = False):
+    """Rotary position embedding of ``x (B, L, H, D)`` at ``positions (B,
+    L)``, computed in fp32. A pair's two parts are the two halves of the
+    head dimension (the ``rotate_half`` convention) or, ``interleaved``,
+    the neighbours ``(2i, 2i + 1)``; a pair stays where it was."""
+    D = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    if interleaved:
+        # every lane meets its pair's other lane through a 0/1 matrix on the
+        # MXU (exact: one product by 1.0 a lane). On a TPU a strided slice of
+        # the lanes (x[..., 0::2]) is a gather and a shift by one lane a
+        # misaligned pass: 0.8 ms each over 32 heads of 64 at 8,192 tokens
+        # (v5e, PR 31) against the matmul's few tens of microseconds
+        angle = (positions.astype(jnp.float32)[:, :, None, None]
+                 * jnp.repeat(inv_freq, 2))
+        lane = jnp.arange(D)
+        swap = (lane[:, None] == (lane ^ 1)[None, :]).astype(x.dtype)
+        other = jnp.einsum("blhd,de->blhe", x, swap, precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+        return (x.astype(jnp.float32) * jnp.cos(angle)
+                + other * jnp.where(lane % 2 == 0, -1.0, 1.0) * jnp.sin(angle)).astype(x.dtype)
+    angle = positions.astype(jnp.float32)[:, :, None, None] * inv_freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def qk_norm_rope_plain(x, gamma, positions, theta, eps, heads):
+    """:func:`qk_norm_rope` as three lines of ``jax.numpy``, differentiated by
+    jax itself: what XLA makes of the op, what a call the kernels do not take
+    traces, and the oracle the kernels are tested against."""
+    B, L, width = x.shape
+    y = rms_norm(x.reshape(B, L, heads, width // heads), gamma, eps=eps)
+    if positions is not None:
+        y = rotary(y, positions, theta)
+    return y.transpose(0, 2, 1, 3)
+
+
+def rotary_table(positions, theta: float, D: int):
+    """``cos`` and signed ``sin`` ``(B, L, D)`` in fp32, as the fused kernels
+    read them: :func:`rotary`'s angles on both halves of the head, ``sin``
+    negated on the first, so that ``x * cos + roll(x, D/2) * sin`` is the
+    rotation."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    angle = positions.astype(jnp.float32)[:, :, None] * jnp.tile(inv_freq, 2)
+    return jnp.cos(angle), jnp.sin(angle) * jnp.where(jnp.arange(D) < D // 2, -1.0, 1.0)
+
+
+def _qk_prologue_fused(x, heads) -> bool:
+    """Do the Pallas kernels take this call: on a TPU, where its dtype and
+    shapes allow? By platform and shape, as ``dot_product_attention``
+    chooses its flash kernels."""
+    from .pallas import qk_prologue
+    return not qk_prologue._interpret_for(x) and qk_prologue.supported(x, heads)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _qk_prologue(x, gamma, table, eps, heads):
+    from .pallas import qk_prologue
+    return qk_prologue.forward(x, gamma, table, eps, heads)
+
+
+def _qk_prologue_fwd(x, gamma, table, eps, heads):
+    return _qk_prologue(x, gamma, table, eps, heads), (x, gamma, table)
+
+
+def _qk_prologue_bwd(eps, heads, res, dy):
+    from .pallas import qk_prologue
+    x, gamma, table = res
+    # rebuilt from the inputs; the tables come from integer positions
+    return (*qk_prologue.backward(x, gamma, table, dy, eps, heads), None)
+
+
+_qk_prologue.defvjp(_qk_prologue_fwd, _qk_prologue_bwd)
+
+
+@register_op()
+def qk_norm_rope(x, gamma, positions=None, theta=10000.0, eps=1e-6, heads=1, **_):
+    """A q or k projection's result made ready for the attention kernels:
+    ``x (B, L, heads * D)`` is RMS-normed per head with the learnt scale
+    ``gamma (D,)``, rotated by ``positions (B, L)`` (:func:`rotary`'s halves
+    convention; no rotation with ``None``) and returned head-major, ``(B,
+    heads, L, D)``, as ``dot_product_attention`` takes it.
+
+    The call decides by what it can observe: bf16 with ``D`` whole lane
+    tiles and ``L`` whole row tiles on a TPU is one Pallas kernel each way
+    (``ops/pallas/qk_prologue.py``: ``qk_prologue_fwd``, ``qk_prologue_bwd``)
+    behind a backward rule of the op's own, one pass over the operands with
+    the statistic, the scale and the rotation in fp32 registers, and
+    nothing but the inputs held for the backward pass; anything else traces
+    :func:`qk_norm_rope_plain` and nothing more. The gauge
+    ``mxtpu_qk_prologue_fused{kernel=}`` says which way the last call of
+    that head count and size went."""
+    from ..telemetry import metrics
+    D = x.shape[-1] // heads
+    fused = _qk_prologue_fused(x, heads)
+    metrics.gauge("mxtpu_qk_prologue_fused", "1 where the fused q/k prologue kernels took "
+                  "the last call of this head count and size, 0 where the plain form did",
+                  kernel=f"qk_prologue_h{heads}_d{D}").set(int(fused))
+    if not fused:
+        return qk_norm_rope_plain(x, gamma, positions, theta, eps, heads)
+    table = None if positions is None else rotary_table(positions, theta, D)
+    with jax.named_scope("qk_prologue"):
+        return _qk_prologue(x, gamma, table, eps, heads)

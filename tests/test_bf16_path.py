@@ -169,3 +169,111 @@ def test_bf16_loss_and_gradients_agree_with_float32():
     for ga, gb in zip(grads["bfloat16"], grads["float32"]):
         if onp.linalg.norm(gb) > 1e-6 * onp.linalg.norm(b):
             assert onp.linalg.norm(ga - gb) <= 6e-2 * onp.linalg.norm(gb)
+
+
+# ---------------------------------------------------------------------------
+# the decoder (PR 35): q and k between their projections and the attention
+# call. Heads of 128 over 64 positions, so the fused q/k prologue's kernels
+# take the calls when the test says "as on the chip"
+# ---------------------------------------------------------------------------
+
+_DEC = dict(hidden_size=64, num_attention_heads=2, num_key_value_heads=1, head_dim=128,
+            intermediate_size=128, moe_intermediate_size=32, num_experts=4,
+            num_experts_per_tok=2, num_shared_experts=1, num_dense_layers=1,
+            layer_types=["sliding_attention", "full_attention"], sliding_window=16,
+            rope_theta=10000, rms_norm_eps=1e-5, route_norm=True, route_scale=2.0,
+            mup_enabled=True, vocab_size=96, moe_tile_rows=8)
+_DEC_B, _DEC_L = 2, 64
+
+
+def _decoder_step_eqns(monkeypatch, fused: bool):
+    """The afmoe decoder's bf16 ``ShardedTrainer`` step, traced with every
+    layer recomputed as the cell runs it; ``fused``: the q/k prologue's one
+    decision steered as on the chip (the kernels where their shapes allow)."""
+    from incubator_mxnet_tpu.ops import nn as ops_nn
+    from incubator_mxnet_tpu.ops.pallas import qk_prologue
+    if fused:
+        monkeypatch.setattr(ops_nn, "_qk_prologue_fused",
+                            lambda x, heads: qk_prologue.supported(x, heads))
+    mx.random.seed(5)
+    net = models.get_afmoe(_DEC, dtype="bfloat16", remat=True)
+    net.initialize(mx.init.Normal(0.05))
+    tr = parallel.ShardedTrainer(
+        net, models.afmoe_lm_loss, "adamw", {"learning_rate": 1e-4, "multi_precision": True},
+        mesh=parallel.make_mesh(devices=jax.devices()[:1]), n_labels=1)
+    rng = onp.random.RandomState(0)
+    seq = rng.randint(0, _DEC["vocab_size"], (_DEC_B, _DEC_L + 1)).astype("int32")
+    batch = (seq[:, :-1], onp.tile(onp.arange(_DEC_L, dtype="int32"), (_DEC_B, 1)),
+             onp.full((_DEC_B,), _DEC_L, "float32"), seq[:, 1:])
+    tr.prepare(*batch)
+    with active_mesh(tr.mesh):
+        closed = jax.make_jaxpr(tr._step_fn)(*tr.step_trace_args(*batch))
+    return list(walk_eqns(closed.jaxpr))
+
+
+def _float32_by_heads(eqns):
+    """fp32 values of q's or k's size by heads, ``(B, L, H, D)`` or ``(B, H,
+    L, D)``, outside the attention op itself (its scope holds the call, the
+    result's layout and the gate)."""
+    H, Hkv, D = _DEC["num_attention_heads"], _DEC["num_key_value_heads"], _DEC["head_dim"]
+    sizes = {(_DEC_B, a, b, D) for h in (H, Hkv) for a, b in ((_DEC_L, h), (h, _DEC_L))}
+    return [str(v.aval) for e in eqns if "afmoe_attention" not in str(e.source_info.name_stack)
+            for v in e.outvars
+            if getattr(v.aval, "dtype", None) == jnp.float32 and v.aval.shape in sizes]
+
+
+def test_decoder_q_and_k_reach_attention_without_a_float32_copy(monkeypatch):
+    """With the fused prologue no fp32 array of q's or k's size lies between
+    the ``q_`` / ``k_`` projections and the attention call, forward,
+    recomputed or backward: two kernels a tensor and a layer, bf16 in and
+    out. The plain form has them (the walk sees what it looks for)."""
+    plain = _decoder_step_eqns(monkeypatch, fused=False)
+    assert len(_float32_by_heads(plain)) >= 8
+    assert not any(e.primitive.name == "pallas_call" for e in plain)
+    fused = _decoder_step_eqns(monkeypatch, fused=True)
+    assert _float32_by_heads(fused) == []
+    kernels = collections.Counter(e.params["name"] for e in fused
+                                  if e.primitive.name == "pallas_call")
+    # q and k in two layers: a forward, a recomputed forward and a backward each
+    assert kernels == {"qk_prologue_fwd": 8, "qk_prologue_bwd": 4}
+    # the projections feed the kernels bf16 and the kernels hand bf16 on
+    for e in fused:
+        if e.primitive.name == "pallas_call":
+            big = [v.aval for v in (*e.invars, *e.outvars) if v.aval.size >= _DEC_B * _DEC_L * 128]
+            assert big and all(a.dtype == jnp.bfloat16 or a.shape[-1] == 128 and a.ndim == 3
+                               for a in big), big
+
+
+def test_decoder_step_with_the_fused_prologue_is_the_plain_steps(monkeypatch):
+    """One SGD step of the recomputed bf16 decoder both ways (a padded row,
+    so the key mask is there): the same loss, and every parameter's update
+    within 1% of its norm (a bf16 neighbour here and there in ``dx``; read
+    0.4% at most when the limit was set)."""
+    from incubator_mxnet_tpu.ops import nn as ops_nn
+    from incubator_mxnet_tpu.ops.pallas import qk_prologue
+    rng = onp.random.RandomState(0)
+    seq = rng.randint(0, _DEC["vocab_size"], (_DEC_B, _DEC_L + 1)).astype("int32")
+    batch = (seq[:, :-1], onp.tile(onp.arange(_DEC_L, dtype="int32"), (_DEC_B, 1)),
+             onp.array([_DEC_L, _DEC_L * 3 // 4], "float32"), seq[:, 1:])
+
+    def step():
+        mx.random.seed(5)
+        net = models.get_afmoe(_DEC, dtype="bfloat16", remat=True)
+        net.initialize(mx.init.Normal(0.05))
+        tr = parallel.ShardedTrainer(net, models.afmoe_lm_loss, "sgd", {"learning_rate": 1.0},
+                                     mesh=parallel.make_mesh(devices=jax.devices()[:1]), n_labels=1)
+        values = lambda: [onp.asarray(p.data().asnumpy(), "float32")  # noqa: E731
+                          for p in net.collect_params().values()]
+        before = values()
+        loss = float(tr.step(*batch).asnumpy())
+        tr.sync_to_block()
+        return loss, [b - a for b, a in zip(before, values())]
+    plain_loss, plain = step()
+    monkeypatch.setattr(ops_nn, "_qk_prologue_fused",
+                        lambda x, heads: qk_prologue.supported(x, heads))
+    fused_loss, fused = step()
+    assert abs(fused_loss - plain_loss) <= 1e-3 * abs(plain_loss)
+    moved = [(a, b) for a, b in zip(plain, fused) if onp.linalg.norm(a) > 0]
+    assert len(moved) > 30
+    for a, b in moved:
+        assert onp.linalg.norm(a - b) <= 1e-2 * onp.linalg.norm(a)

@@ -26,7 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from incubator_mxnet_tpu import parallel
 from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
 from incubator_mxnet_tpu.ops import nn as ops_nn
-from incubator_mxnet_tpu.ops.pallas import moe_gmm, moe_rows, short_conv
+from incubator_mxnet_tpu.ops.pallas import moe_gmm, moe_rows, qk_prologue, short_conv
 from incubator_mxnet_tpu.parallel import collectives, moe_dropless, ring
 
 
@@ -53,6 +53,7 @@ def for_the_chip(monkeypatch):
     monkeypatch.setattr(moe_gmm, "_interpret_for", lambda x: False)
     monkeypatch.setattr(moe_rows, "_interpret_for", lambda x: False)
     monkeypatch.setattr(short_conv, "_interpret_for", lambda x: False)
+    monkeypatch.setattr(qk_prologue, "_interpret_for", lambda x: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -210,6 +211,32 @@ def test_short_conv_gate_compiles_for_v5e(topo, for_the_chip, shape, dtype):
         assert len(re.findall(rf'^\s*%\S*{kernel}\S* = .*custom_call_target="tpu_custom_call"',
                               text, re.M)) == 1, kernel
     assert " convolution(" not in text
+
+
+# Trinity-Mini's q and k at the cell's size (one row of 8,192 tokens, 32 and 4
+# heads of 128), a sliding layer's (rotary) and the full layer's (none)
+@pytest.mark.parametrize("heads", [32, 4], ids=["q_h32", "k_h4"])
+@pytest.mark.parametrize("rope", [True, False], ids=["rotary", "no_rotary"])
+def test_qk_norm_rope_compiles_for_v5e(topo, for_the_chip, heads, rope):
+    """The fused op through its own backward rule: one ``qk_prologue_fwd``
+    and one ``qk_prologue_bwd`` kernel, and between the projection's bf16
+    result and the head-major bf16 result no array of their size in fp32."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    B, L, D = 1, 8192, 128
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def f(x, gamma, positions, dy):
+        out, vjp = jax.vjp(lambda x, gamma: ops_nn.qk_norm_rope(
+            x, gamma, positions if rope else None, 10000.0, 1e-5, heads), x, gamma)
+        return (out,) + vjp(dy)
+
+    text = jax.jit(f).lower(
+        sds((B, L, heads * D), jnp.bfloat16), sds((D,), jnp.float32), sds((B, L), jnp.int32),
+        sds((B, heads, L, D), jnp.bfloat16)).compile().as_text()
+    for kernel in ("qk_prologue_fwd", "qk_prologue_bwd"):
+        assert len(re.findall(rf'^\s*%\S*{kernel}\S* = .*custom_call_target="tpu_custom_call"',
+                              text, re.M)) == 1, kernel
+    assert f"f32[{B},{L},{heads},{D}]" not in text and f"f32[{B},{heads},{L},{D}]" not in text
 
 
 # dtype, experts a token, expert width: Trinity-Mini's (top 8, 1,024) and

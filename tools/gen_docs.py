@@ -620,7 +620,11 @@ def gen_profiler() -> str:
            "`moe_rows_*`; `short_conv_fwd`, `short_conv_bwd` (the gated "
            "short convolution `ops.nn.short_conv_gate`, one pass over its "
            "operands each way, read by `short_conv_ms.train` and "
-           "`short_conv_roofline.train`). `jax.named_scope`s sit in every operation's "
+           "`short_conv_roofline.train`); `qk_prologue_fwd`, `qk_prologue_bwd` "
+           "(`ops.nn.qk_norm_rope`: per-head RMSNorm, rotary and the "
+           "head-major write of a q or k projection in one pass each way; "
+           "the gauge `mxtpu_qk_prologue_fused{kernel=}` says whether a "
+           "call took them). `jax.named_scope`s sit in every operation's "
            "`op_name`: `afmoe_attention`, `moe_router`, `moe_dispatch`, "
            "`moe_experts`, `moe_combine`, `lm_head`, and in "
            "`models/deepseek_v3.py`'s attention block `mla_q` (the query "
@@ -629,7 +633,8 @@ def gen_profiler() -> str:
            "values) and `mla_attention` (rotary on the 64-wide parts, the "
            "heads' layout, the kernel); in `models/lfm2_moe.py` "
            "`lfm2_mixer_conv` and `lfm2_mixer_attn` round the two kinds of "
-           "token mixer, and `short_conv` round the fused op inside the first.",
+           "token mixer, and `short_conv` round the fused op inside the first; "
+           "`qk_prologue` round the fused q/k prologue where its kernels run.",
            "", "## API", "", "| Name | Summary |", "|---|---|"]
     for name in profiler.__all__:
         obj = getattr(profiler, name, None)
