@@ -334,18 +334,20 @@ def layer_norm(data, gamma, beta, axis=-1, eps=1e-5, output_mean_var=False):
     # (bf16 mean/var loses ~3 decimal digits; the reference computes fp32
     # throughout and XLA fuses the casts into the same kernel), then ONE
     # cast back: the layer keeps gamma/beta fp32 in a bf16 net, and a
-    # bf16 * f32 product would hand every later op a float32 activation
-    x32 = data.astype(jnp.float32)
-    m = jnp.mean(x32, axis=axis, keepdims=True)
-    v = jnp.var(x32, axis=axis, keepdims=True)
-    shape = [1] * data.ndim
-    shape[axis] = data.shape[axis]
-    out = ((x32 - m) * lax.rsqrt(v + eps)
-           * gamma.reshape(shape).astype(jnp.float32)
-           + beta.reshape(shape).astype(jnp.float32)).astype(data.dtype)
-    if output_mean_var:
-        return out, jnp.squeeze(m, axis), jnp.squeeze(v, axis)
-    return out
+    # bf16 * f32 product would hand every later op a float32 activation.
+    # The scope names the norm's device operations (their HLO op_name)
+    with jax.named_scope("layer_norm"):
+        x32 = data.astype(jnp.float32)
+        m = jnp.mean(x32, axis=axis, keepdims=True)
+        v = jnp.var(x32, axis=axis, keepdims=True)
+        shape = [1] * data.ndim
+        shape[axis] = data.shape[axis]
+        out = ((x32 - m) * lax.rsqrt(v + eps)
+               * gamma.reshape(shape).astype(jnp.float32)
+               + beta.reshape(shape).astype(jnp.float32)).astype(data.dtype)
+        if output_mean_var:
+            return out, jnp.squeeze(m, axis), jnp.squeeze(v, axis)
+        return out
 
 
 @register_op("GroupNorm")
@@ -387,11 +389,13 @@ def l2_normalization(data, eps=1e-10, mode="instance", **_):
 @register_op("RMSNorm", aliases=("rms_norm",))
 def rms_norm(data, gamma, axis=-1, eps=1e-6, **_):
     """TPU-era extension (not in reference): RMSNorm for LLaMA-family models.
-    Statistics and scale in fp32, one cast back (see layer_norm)."""
-    x32 = data.astype(jnp.float32)
-    v = jnp.mean(jnp.square(x32), axis=axis, keepdims=True)
-    return (x32 * lax.rsqrt(v + eps)
-            * gamma.astype(jnp.float32)).astype(data.dtype)
+    Statistics and scale in fp32, one cast back, under a scope of its own
+    (see layer_norm)."""
+    with jax.named_scope("rms_norm"):
+        x32 = data.astype(jnp.float32)
+        v = jnp.mean(jnp.square(x32), axis=axis, keepdims=True)
+        return (x32 * lax.rsqrt(v + eps)
+                * gamma.astype(jnp.float32)).astype(data.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import os
 import re
+import weakref
 from contextlib import ExitStack as _ExitStack
 from contextlib import nullcontext as _nullcontext
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -73,6 +74,53 @@ __all__ = ["ShardedTrainer"]
 def _natural_key(name: str):
     """Sort key comparing digit runs as numbers: ``dense9_`` < ``dense10_``."""
     return [int(p) if p.isdigit() else p for p in re.split(r"(\d+)", name)]
+
+
+class _StepProgram:
+    """How ``compile_log.program_text("trainer.step")`` reads the compiled
+    step back: the jitted step lowered again from its arguments' shapes
+    and shardings, under the step's mesh and tuned overlay, so that jax's
+    caches hand back the executable that ran. It holds the trainer weakly
+    (the jitted step holds the block) until :meth:`pin` captures what the
+    lowering needs; ``ShardedTrainer.step`` pins it when a profiler trace
+    records the step, so a trace can be read after the trainer is gone."""
+
+    __slots__ = ("_trainer", "_batch", "_pinned")
+
+    def __init__(self, trainer, batch_vals):
+        self._trainer = weakref.ref(trainer)
+        self._batch = tuple(_spec(v) for v in batch_vals)
+        self._pinned = None
+
+    def _capture(self):
+        tr = self._trainer()
+        if tr is None:
+            return None
+        state = jax.tree.map(_spec, (tr._param_vals, tr._opt_states, tr._base_key,
+                                     tr._lr_dev, tr._t_dev))
+        return tr._step_fn, state + self._batch, tr._mesh, tr._tuned
+
+    def pin(self) -> None:
+        if self._pinned is None:
+            self._pinned = self._capture()
+
+    def __call__(self) -> Optional[str]:
+        parts = self._pinned or self._capture()
+        if parts is None:
+            return None
+        step_fn, args, mesh, tuned = parts
+        from .. import autotune as _autotune
+        from .mesh import active_mesh
+        with active_mesh(mesh), (_autotune.applied(tuned) if tuned else _nullcontext()):
+            lowered = step_fn.lower(*args)
+        return lowered.compile().as_text()
+
+
+def _spec(a):
+    """``a``'s shape, dtype, sharding and weak type, without its data (a
+    typed key has no weak type)."""
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding,
+                                weak_type=getattr(a, "weak_type", False))
 
 
 class ShardedTrainer:
@@ -160,6 +208,7 @@ class ShardedTrainer:
         self._param_shardings = None  # per-param NamedSharding (post-init)
         self._state_shardings = None  # per-param tuple of NamedShardings
         self._step_fn = None
+        self._program = None          # _StepProgram of the step that runs
         self._info: Dict[str, Any] = {}
         self._t = 0
         self._t_dev = None           # device-resident step counter
@@ -467,27 +516,30 @@ class ShardedTrainer:
                     stats[site] = val
             constrain = jax.lax.with_sharding_constraint
             new_vals, new_states = [], []
-            for i, (w, g, s) in enumerate(zip(param_vals, grads, opt_states)):
-                if mp[i]:
-                    nm, ns = opt.step(s[0], g.astype(jnp.float32), tuple(s[1:]),
-                                      lr * lr_mults[i], wds[i], t)
-                    nv = nm.astype(w.dtype)
-                    nst = (nm,) + tuple(ns)
-                else:
-                    nw, ns = opt.step(w, g.astype(w.dtype), s,
-                                      lr * lr_mults[i], wds[i], t)
-                    nv = nw.astype(w.dtype)
-                    nst = tuple(ns)
-                # Pin layouts so step outputs keep the step-input shardings:
-                # under zero1 the update math runs dp-sharded (XLA
-                # reduce-scatters the grads into it) and ONLY the new weight
-                # is gathered back to the rule layout — and the next call
-                # sees identical input shardings (no silent recompile).
-                nv = constrain(nv, param_shardings[i])
-                nst = tuple(constrain(a, sh)
-                            for a, sh in zip(nst, state_shardings[i]))
-                new_vals.append(nv)
-                new_states.append(nst)
+            # one scope round the update: the device's operations carry it in
+            # their op_name, so a trace reads the update's time by name
+            with jax.named_scope("optimizer_update"):
+                for i, (w, g, s) in enumerate(zip(param_vals, grads, opt_states)):
+                    if mp[i]:
+                        nm, ns = opt.step(s[0], g.astype(jnp.float32), tuple(s[1:]),
+                                          lr * lr_mults[i], wds[i], t)
+                        nv = nm.astype(w.dtype)
+                        nst = (nm,) + tuple(ns)
+                    else:
+                        nw, ns = opt.step(w, g.astype(w.dtype), s,
+                                          lr * lr_mults[i], wds[i], t)
+                        nv = nw.astype(w.dtype)
+                        nst = tuple(ns)
+                    # Pin layouts so step outputs keep the step-input shardings:
+                    # under zero1 the update math runs dp-sharded (XLA
+                    # reduce-scatters the grads into it) and ONLY the new weight
+                    # is gathered back to the rule layout — and the next call
+                    # sees identical input shardings (no silent recompile).
+                    nv = constrain(nv, param_shardings[i])
+                    nst = tuple(constrain(a, sh)
+                                for a, sh in zip(nst, state_shardings[i]))
+                    new_vals.append(nv)
+                    new_states.append(nst)
             # the guard's finite check, captured in-graph: one fused
             # reduction instead of a separate jitted call — the rollback
             # DECISION stays on host (_apply_guard)
@@ -876,6 +928,16 @@ class ShardedTrainer:
                     _clog.note(self._retune_site, sig,
                                wall_ms=dispatch_ms, warmup=None)
                     self._retune_site = None
+                if (new_sig or retuned_now) and not fallback:
+                    # how to read the step that now runs back, kept, not
+                    # run: compile_log.program_text("trainer.step")
+                    self._program = _StepProgram(self, vals)
+                    _clog.keep_program("trainer.step", self._program)
+                if self._program is not None \
+                        and jax.profiler.TraceAnnotation.is_enabled():
+                    # a profiler trace records this step: the program it
+                    # saw outlives the trainer, to read the trace against
+                    self._program.pin()
                 # the dispatch ring: what this pod member actually ran,
                 # in order — the flight bundle's cross-host diff surface
                 _cledger.note_dispatch("trainer.step", sig)

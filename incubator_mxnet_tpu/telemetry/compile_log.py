@@ -26,13 +26,20 @@ site the seconds jax reports for tracing, lowering, the backend compile
 and the persistent cache's read (:func:`phase_seconds`). The site is the
 innermost :func:`at` block open on the thread, which the ``note`` call
 sites put round their jit call; anything else is ``"other"``.
+
+What a site's newest program is, as the device runs it, is asked of jax
+too, and only on request: :func:`keep_program` holds how to get it, and
+:func:`program_text` gets the optimized HLO text, in which every device
+operation has its instruction name and the ``op_name`` of the scopes it
+was traced under (a profiler trace names an operation by its instruction
+alone). Nothing is lowered or read until that call.
 """
 from __future__ import annotations
 
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 import jax
 
@@ -40,7 +47,8 @@ from ..lockcheck import make_lock
 
 __all__ = ["CompileRecord", "note", "mark_warmed", "is_warmed", "records",
            "summary", "post_warmup_compiles", "assert_zero_post_warmup",
-           "at", "phase_seconds", "clear", "MAX_RECORDS"]
+           "at", "phase_seconds", "keep_program", "program_text", "clear",
+           "MAX_RECORDS"]
 
 #: ledger ring size — a recompile storm must not grow host memory unbounded
 MAX_RECORDS = 4096
@@ -157,6 +165,31 @@ def phase_seconds(site: Optional[str] = None) -> Dict[str, float]:
     return out
 
 
+#: site -> a call that returns the text of the newest program compiled there
+_PROGRAMS: Dict[str, Callable[[], Optional[str]]] = {}
+
+
+def keep_program(site: str, text_of: Callable[[], Optional[str]]) -> None:
+    """Keep ``text_of`` as the way to the newest program compiled at
+    ``site``: a call, made only by :func:`program_text`, that returns the
+    compiled program's ``as_text()`` (or ``None`` once it is gone). It
+    should hold the program's owner weakly, so that keeping it keeps
+    nothing alive unless the owner asks (``ShardedTrainer`` pins its step
+    when a profiler trace records it)."""
+    with _LOCK:
+        _PROGRAMS[site] = text_of
+
+
+def program_text(site: str) -> Optional[str]:
+    """The optimized HLO text of the newest program compiled at ``site``
+    (instruction names and each one's ``metadata={op_name=...}``), or
+    ``None`` where nothing was kept. Costs a lowering and a compile-cache
+    read: call it after the work it describes, never on a hot path."""
+    with _LOCK:
+        text_of = _PROGRAMS.get(site)
+    return text_of() if text_of is not None else None
+
+
 def mark_warmed(site: str) -> None:
     """Declare ``site`` past its warmup phase: compiles noted there
     without an explicit ``warmup=`` flag count as post-warmup from now on
@@ -250,3 +283,4 @@ def clear() -> None:
         _BY_SITE.clear()
         _WARMED.clear()
         _PHASES.clear()
+        _PROGRAMS.clear()

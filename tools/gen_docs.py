@@ -634,7 +634,18 @@ def gen_profiler() -> str:
            "heads' layout, the kernel); in `models/lfm2_moe.py` "
            "`lfm2_mixer_conv` and `lfm2_mixer_attn` round the two kinds of "
            "token mixer, and `short_conv` round the fused op inside the first; "
-           "`qk_prologue` round the fused q/k prologue where its kernels run.",
+           "`qk_prologue` round the fused q/k prologue where its kernels run; "
+           "`layer_norm` and `rms_norm` (`ops/nn.py`) round the two norms; "
+           "`optimizer_update` (`ShardedTrainer`) round the per-parameter "
+           "update. jax adds `transpose(jvp(...))` for the backward pass and "
+           "`rematted_computation` for a recomputed layer's forward. A trace "
+           "names an operation by its HLO instruction only, so the benchmark "
+           "joins it to the compiled step's text, which "
+           "`telemetry.compile_log.program_text(\"trainer.step\")` hands out "
+           "on request (`chipbench/op_scopes.py`: `optimizer_update_ms.train`, "
+           "`norm_ms.train`, `recompute_ms.train`, `unscoped_share.train`, and "
+           "for the collectives the compiler puts in, `collective_ms.train` "
+           "and `collective_exposed_ms.train`).",
            "", "## API", "", "| Name | Summary |", "|---|---|"]
     for name in profiler.__all__:
         obj = getattr(profiler, name, None)
