@@ -5,9 +5,17 @@ Reference parity: GluonNLP's ``BERTEncoder``/``TransformerEncoderCell``
 interleaved-MHA ops (``src/operator/contrib/transformer.cc`` — SURVEY §2.4).
 
 TPU-native design: one fused QKV projection (a single MXU matmul over the
-batch·seq rows) followed by :func:`~incubator_mxnet_tpu.ops.attention.
-dot_product_attention` — which lowers to the Pallas flash kernel on TPU. The
-reference's (B·H, L, L) score tensor never exists in HBM.
+batch·seq rows) followed by the Pallas flash kernels on TPU. The reference's
+(B·H, L, L) score tensor never exists in HBM. ``MultiHeadAttention`` hands
+its projections as they are to
+:func:`~incubator_mxnet_tpu.ops.attention.projected_attention`, which
+picks the layout from what the call shows. On a TPU (head size 32, 64 or
+128, whole 128-lane blocks of heads, no ``tp`` or ``sp`` mesh) the kernels
+read q, k and v as lane blocks of the ``(B, L, 3C)`` projection and write
+the ``(B, L, C)`` the output projection reads, with no head-major copy
+either way. Elsewhere (the CPU, other shapes) the op splits the heads out,
+``(B, H, L, D)``, for :func:`~incubator_mxnet_tpu.ops.attention.
+dot_product_attention` and transposes the result back.
 """
 from __future__ import annotations
 
@@ -66,35 +74,20 @@ class MultiHeadAttention(HybridBlock):
                                  weight_initializer=weight_initializer)
             self.dropout = nn.Dropout(dropout) if dropout else None
 
-    def _heads(self, F, x, n):
-        # (B, L, n*C) -> n tensors of (B, H, L, d)
-        B, L = x.shape[0], x.shape[1]
-        H, d = self._num_heads, self._units // self._num_heads
-        parts = F.split(x, num_outputs=n, axis=2) if n > 1 else [x]
-        outs = []
-        for p in parts:
-            outs.append(F.transpose(F.reshape(p, (B, L, H, d)), axes=(0, 2, 1, 3)))
-        return outs
-
     def hybrid_forward(self, F, query, kv=None, mask=None):
-        B, Lq = query.shape[0], query.shape[1]
         if not self._cross:
             if kv is not None and kv is not query:
                 raise ValueError(
                     "this MultiHeadAttention was built for self-attention; "
                     "pass cross_attention=True to attend over a memory")
-            q, k, v = self._heads(F, self.qkv(query), 3)
+            operands = (self.qkv(query),)
         else:
-            if kv is None:
-                kv = query
-            q, = self._heads(F, self.q_proj(query), 1)
-            k, v = self._heads(F, self.kv_proj(kv), 2)
+            operands = (self.q_proj(query), self.kv_proj(query if kv is None else kv))
         if mask is not None:
-            out = F.dot_product_attention(q, k, v, mask, causal=self._causal)
-        else:
-            out = F.dot_product_attention(q, k, v, causal=self._causal)
-        # (B, H, Lq, d) -> (B, Lq, C)
-        out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)), (B, Lq, self._units))
+            operands += (mask,)
+        # (B, Lq, C), whichever layout the kernels read
+        out = F.projected_attention(*operands, heads=self._num_heads, cross=self._cross,
+                                    causal=self._causal)
         out = self.proj(out)
         if self.dropout is not None:
             out = self.dropout(out)

@@ -29,6 +29,7 @@ from .registry import register_op
 
 __all__ = [
     "dot_product_attention",
+    "projected_attention",
     "checkpoint_layer",
     "interleaved_matmul_selfatt_qk",
     "interleaved_matmul_selfatt_valatt",
@@ -97,48 +98,133 @@ def _maybe_ring(query, key, value, mask, causal, scale):
     return fn(query, key, value, key_mask)
 
 
-def _flash_on_mesh(query, key, value, mask, causal, scale, window, shared=None):
-    """The Pallas flash kernel, run per shard where a mesh is active.
+def _per_shard(attn, operands, mask, heads, lengths, head_counts=()):
+    """``attn(*operands, mask=mask)``, run per shard where a mesh is active.
 
     GSPMD cannot partition a Mosaic kernel ("wrap the call in a
     shard_map"), so inside a step compiled over a multi-device mesh the
-    kernel runs under a full-manual ``shard_map``: batch over ``dp`` and
-    heads over ``tp`` where they divide (attention is independent along
-    both), replicated over every other axis. Outside a trace, or on a
-    one-device mesh, it is the plain kernel call.
+    kernels run under a full-manual ``shard_map``: every operand's first
+    dimension, the batch, over ``dp`` where it divides, the second over
+    ``tp`` where every count of ``head_counts`` divides (head-major
+    operands; attention is independent along both), replicated over every
+    other axis; the mask as a key mask, ``(B, Lk)``. ``operands`` may hold
+    None and pairs. Outside a trace, or on a one-device mesh, it is the
+    plain call.
     """
-    from functools import partial
     from ..parallel.mesh import current_active_mesh
-    from .pallas.flash_attention import _as_key_mask, flash_attention
-    attn = partial(flash_attention, causal=causal, scale=scale,
-                   window=window)
+    from .pallas.flash_attention import _as_key_mask
     mesh = current_active_mesh()
     if mesh is None or mesh.devices.size == 1 \
-            or not isinstance(query, jax.core.Tracer):
-        return attn(query, key, value, mask=mask, shared=shared)
+            or not isinstance(operands[0], jax.core.Tracer):
+        return attn(*operands, mask=mask)
     from jax.sharding import PartitionSpec as P
     from ..parallel.collectives import shard_map
-    B, H, Lq, _ = query.shape
+    B = operands[0].shape[0]
     dp, tp = mesh.shape.get("dp", 1), mesh.shape.get("tp", 1)
     bspec = "dp" if dp > 1 and B % dp == 0 else None
-    # heads split over tp only where the K/V heads divide too (grouped K/V:
-    # a shard then holds whole groups); the shared pair's one key head does
-    # not divide, so such a call keeps its heads together
-    hspec = "tp" if tp > 1 and H % tp == 0 and key.shape[1] % tp == 0 \
-        and shared is None else None
-    spec = P(bspec, hspec, None, None)
+    hspec = "tp" if tp > 1 and head_counts and all(n % tp == 0 for n in head_counts) \
+        else None
     key_mask = None
     if mask is not None:
-        key_mask = _as_key_mask(mask, B, H, Lq, key.shape[2])
+        key_mask = _as_key_mask(mask, B, heads, *lengths)
         if key_mask is None:
-            return attn(query, key, value, mask=mask)   # raises: not a key mask
-    # the optional operands ride as one pytree: None has no leaves and
-    # needs no spec
+            return attn(*operands, mask=mask)       # raises: not a key mask
+
+    def spec(x):
+        return P(bspec, hspec, *(None,) * (x.ndim - 2))
+    # a None operand has no leaves and needs no spec
     return shard_map(
-        lambda q, k, v, m, s: attn(q, k, v, mask=m, shared=s), mesh=mesh,
-        in_specs=(spec, spec, spec, None if key_mask is None else P(bspec, None),
-                  None if shared is None else (spec, spec)),
-        out_specs=spec)(query, key, value, key_mask, shared)
+        lambda *args: attn(*args[:-1], mask=args[-1]), mesh=mesh,
+        in_specs=(*jax.tree.map(spec, operands),
+                  None if key_mask is None else P(bspec, None)),
+        out_specs=spec(operands[0]))(*operands, key_mask)
+
+
+def _flash_on_mesh(query, key, value, mask, causal, scale, window, shared=None):
+    """The head-major Pallas flash kernels, per shard where a mesh is
+    active (:func:`_per_shard`). Heads are split over ``tp`` only where the
+    K/V heads divide too (grouped K/V: a shard then holds whole groups);
+    the shared pair's one key head does not divide, so such a call keeps
+    its heads together."""
+    from .pallas.flash_attention import flash_attention
+
+    def attn(q, k, v, s, mask):
+        return flash_attention(q, k, v, mask=mask, causal=causal, scale=scale,
+                               window=window, shared=s)
+    H = query.shape[1]
+    return _per_shard(attn, (query, key, value, shared), mask, H,
+                      (query.shape[2], key.shape[2]),
+                      () if shared is not None else (H, key.shape[1]))
+
+
+def _lanes_taken(query, key_value, heads, mask) -> bool:
+    """Do the flash kernels take this call in the projections' own layout?
+    Where they would take its head-major form (``dot_product_attention``'s
+    impl and the kernels' switch, on a TPU), the shapes have a lane layout
+    (``flash_attention._lane_layout``), and a mesh the call runs under
+    shards no heads (``tp``) and no sequence (``sp``, the ring path): by
+    what the call can observe."""
+    import os
+    from ..parallel.mesh import current_active_mesh
+    from .pallas import flash_attention
+    if os.environ.get("MXTPU_ATTN_IMPL", "auto") not in ("auto", "flash") \
+            or os.environ.get("MXTPU_FLASH_ATTENTION", "1") == "0" \
+            or flash_attention._interpret_for(query):
+        return False
+    mesh = current_active_mesh()
+    if mesh is not None and isinstance(query, jax.core.Tracer) and (
+            mesh.shape.get("tp", 1) > 1 or mesh.shape.get("sp", 1) > 1):
+        return False
+    return flash_attention._lane_layout(query, key_value, heads, mask) is not None
+
+
+@register_op()
+def projected_attention(query, *operands, heads=1, cross=False, causal=False,
+                        scale=None, **_):
+    """Multi-head attention over the projections as they are.
+    Self-attention: ``query (B, L, 3C)``, the fused q, k, v projection,
+    ``C = heads * D``. Cross-attention (``cross=True``): ``query (B, Lq,
+    C)`` and, first of ``operands``, ``(B, Lk, 2C)``, the k, v projection.
+    A mask, as :func:`dot_product_attention` takes it, comes last in
+    ``operands``. Returns ``(B, Lq, C)``, what the output projection reads.
+
+    On a TPU the flash kernels read q, k and v as 128-lane column blocks of
+    these arrays (one head a block at D = 128, two at D = 64) and write the
+    result, and in the backward pass the gradient, in the same layout
+    (``flash_attention.flash_attention_lanes``): no head-major transpose in
+    HBM either way. Taken where the call allows it: head size 32, 64 or
+    128, ``C`` whole lane blocks, lengths the tiles divide, a key mask or
+    none, no ``tp`` or ``sp`` in the mesh; inside a step compiled over a
+    mesh, per shard of the batch (``dp``). Elsewhere the heads are split
+    out, ``(B, H, L, D)``, for :func:`dot_product_attention` and its result
+    is transposed back. The gauge ``mxtpu_flash_lane_layout{kernel=}`` says
+    which way the last call of that head count and size went."""
+    from functools import partial
+    from ..telemetry import metrics
+    from .pallas.flash_attention import flash_attention_lanes
+    if cross and not operands:
+        raise ValueError("projected_attention(cross=True) needs the k, v projection")
+    key_value = operands[0] if cross else None
+    mask = operands[-1] if len(operands) > int(cross) else None
+    B, Lq = query.shape[:2]
+    C = query.shape[-1] // (1 if cross else 3)
+    taken = _lanes_taken(query, key_value, heads, mask)
+    metrics.gauge("mxtpu_flash_lane_layout", "1 where the flash kernels took the last call "
+                  "of this head count and size in the projections' own layout, 0 where "
+                  "its heads were split out for them", kernel=f"flash_h{heads}_d{C // heads}"
+                  ).set(int(taken))
+    if taken:
+        Lk = (key_value if cross else query).shape[1]
+        return _per_shard(partial(flash_attention_lanes, heads=heads, causal=causal, scale=scale),
+                          (query, key_value), mask, heads, (Lq, Lk))
+
+    def split(x, n):
+        # (B, L, n*C) -> n tensors of (B, H, L, D)
+        return [p.reshape(B, x.shape[1], heads, C // heads).transpose(0, 2, 1, 3)
+                for p in jnp.split(x, n, axis=2)]
+    q, k, v = split(query, 1) + split(key_value, 2) if cross else split(query, 3)
+    out = dot_product_attention(q, k, v, mask, causal=causal, scale=scale)
+    return out.transpose(0, 2, 1, 3).reshape(B, Lq, C)
 
 
 @functools.cache

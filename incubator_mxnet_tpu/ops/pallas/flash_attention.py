@@ -59,6 +59,27 @@ HBM, forward or backward. ``dk_s`` is the sum over the heads, which the dkv
 kernel streams one after another past an fp32 accumulator. Such calls are
 named ``flash_*_mla``.
 
+Two layouts, one set of kernel bodies. **Head-major** (``flash_attention``):
+``q (B, H, Lq, D)``, ``k, v (B, Hkv, Lk, D)``, a block a head; what
+``dot_product_attention`` hands over, and every call of the decoders, whose
+prologues write that layout (grouped K/V, windows, the shared pair and the
+ring's hops are this layout's alone). **Lane blocks**
+(``flash_attention_lanes``, behind ``ops.attention.projected_attention``):
+the projections' own arrays, the fused ``(B, L, 3C)`` q, k, v of
+self-attention or ``(B, Lq, C)`` with ``(B, Lk, 2C)`` of cross-attention,
+read as 128-lane column blocks through the index maps, one head a block at
+D = 128 and two at D = 64 (a grid row is a batch row and a lane block; a
+head's scores see its own lanes, the others zeroed, at the MXU cost a
+64-wide contraction pays anyway, and each head keeps its own softmax state).
+``o`` is written as ``(B, Lq, C)``, what the output projection reads; the
+dkv kernel writes k's and v's gradient blocks by DMA into one buffer shaped
+like their array, and the dq kernel q's into the same buffer where q came
+from it too, so the fused projection's gradient is one array. No
+head-major transpose exists in HBM either way: BERT's encoder
+(``models/transformer.py`` ``MultiHeadAttention``) hands its projections to
+``projected_attention``, which takes this layout where the call allows it.
+The kernels keep their names.
+
 Masking: ``causal`` and/or a key-padding mask of shape (B, Lk) (1 = valid).
 The generic (B, H, Lq, Lk) mask case falls back to the XLA path in
 ``ops/attention.py`` — loading an L² mask would defeat the point.
@@ -79,7 +100,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 _VMEM = pltpu.VMEM
 
-__all__ = ["flash_attention", "flash_supported", "REMAT_KEEP"]
+__all__ = ["flash_attention", "flash_attention_lanes", "flash_supported", "REMAT_KEEP"]
 
 #: the names the forward rules give the kernel's output and log-sum-exp
 #: (``jax.ad_checkpoint.checkpoint_name``): a ``jax.checkpoint`` whose policy
@@ -298,18 +319,19 @@ def _kernel_name(base: str, window, shared=None) -> str:
             + ("" if shared is None else "_mla"))
 
 
-def _optional_inputs(kernel, tables: int, fixed: int, present: tuple):
+def _optional_inputs(kernel, tables: int, fixed: int, present: tuple, unused: int = 0):
     """Pallas hands a kernel its refs by position: the ``tables`` of a
     schedule first (scalar prefetch), which ``kernel`` takes as one tuple
     (``None`` where there are none), then ``fixed`` inputs, then one ref for
-    each entry of ``present``, then outputs and scratch: the absent ones
-    are passed as ``None``."""
+    each entry of ``present``, then ``unused`` inputs the kernel does not
+    see (a buffer its output is aliased to), then outputs and scratch: the
+    absent ones are passed as ``None``."""
     def call(*refs, **kw):
         sched, refs = refs[:tables] or None, list(refs[tables:])
         given = iter(refs[fixed:fixed + sum(present)])
         optional = [next(given) if p else None for p in present]
         return kernel(sched, *refs[:fixed], *optional,
-                      *refs[fixed + sum(present):], **kw)
+                      *refs[fixed + sum(present) + unused:], **kw)
     return call
 
 
@@ -374,42 +396,74 @@ def _probs(s, lseb, hidden: bool):
     return jnp.where(s > _NEG * 0.5, jnp.exp(s - lseb[:, None]), 0.0)
 
 
+def _lanes_of(x, h, heads):
+    """Where head ``h`` of the ``heads`` a lane block holds sits in ``x``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return lane // (x.shape[-1] // heads) == h
+
+
+def _head(x, h, heads):
+    """Head ``h`` of a block of ``heads``, the other heads' lanes zeroed: a
+    product that contracts over the lanes then sees that head alone, at the
+    MXU's cost of a 128-wide contraction, which a 64-wide one pays too. A
+    block of one head is returned as it is."""
+    return x if heads == 1 else jnp.where(_lanes_of(x, h, heads), x, 0)
+
+
+def _merge(parts):
+    """One block from a full-width value a head: each head's lanes from its
+    own. One head's value is the block."""
+    out = parts[0]
+    for h, part in enumerate(parts[1:], 1):
+        out = jnp.where(_lanes_of(part, h, len(parts)), part, out)
+    return out
+
+
 # ---------------------------------------------------------------------------
 # forward: K/V streamed block by block past each q-block, state in scratch.
 # Grid (B·H, nq, nk), or for a causal call (B·H, steps of the schedule)
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(sched, q_ref, k_ref, v_ref, qs_ref, ks_ref, mask_ref, o_ref,
-                lse_ref, acc_ref, m_ref, l_ref, *, scale, causal_off,
-                window=None):
+                lse_ref, *scratch, scale, causal_off, window=None, heads=1):
+    # ``heads`` heads a block (the lane layout at D < 128), each with its
+    # own softmax state: (acc, m, l) a head
     iq, jk, (first, last, _, _), run = _walk(sched)
+    state = [scratch[3 * h:3 * h + 3] for h in range(heads)]
 
     @pl.when(first)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        for acc_ref, m_ref, l_ref in state:
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, _NEG)
+            l_ref[...] = jnp.zeros_like(l_ref)
 
     def _step(cut):
-        s = _scores(q_ref[0], k_ref[0], qs_ref, ks_ref, mask_ref, scale,
-                    (iq, jk, causal_off, window) if cut else None)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        for h, (acc_ref, m_ref, l_ref) in enumerate(state):
+            s = _scores(_head(q_ref[0], h, heads), k_ref[0], qs_ref, ks_ref, mask_ref,
+                        scale, (iq, jk, causal_off, window) if cut else None)
+            m_prev = m_ref[...]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            # a head's p against the whole block of v: its own lanes are
+            # its output, the others' are dropped by _merge
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
 
     run(_step)
 
     @pl.when(last)
     def _finish():
-        l = jnp.maximum(l_ref[...], 1e-30)  # fully-masked rows → output 0
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[...] + jnp.log(l))[:, 0]
+        # fully-masked rows → output 0
+        ls = [jnp.maximum(l_ref[...], 1e-30) for _, _, l_ref in state]
+        o_ref[0] = _merge([acc_ref[...] / l for (acc_ref, _, _), l in zip(state, ls)]
+                          ).astype(o_ref.dtype)
+        for h, ((_, m_ref, _), l) in enumerate(zip(state, ls)):
+            lse_ref[h, 0] = (m_ref[...] + jnp.log(l))[:, 0]
 
 
 def _scratch(bq, d):
@@ -419,12 +473,19 @@ def _scratch(bq, d):
 
 
 def _pallas(kernel, name, sched, rectangle, interpret, in_specs, out_specs,
-            out_shape, scratch, args):
+            out_shape, scratch, args, in_order=False, aliases=None):
     """One ``pallas_call``: over the grid ``rectangle``, or where the call
     has a schedule over ``(rows, its steps)`` with the tables prefetched, so
-    that every index map reads its block from them."""
+    that every index map reads its block from them. ``in_order``: every
+    dimension "arbitrary" (a kernel that waits for one step's DMA in a
+    later one); ``aliases``: ``input_output_aliases``."""
     kwargs = {} if interpret else {
         "compiler_params": _COMPILER_PARAMS if sched is None else _SCHEDULED_PARAMS}
+    if in_order and not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * (3 if sched is None else 2))
+    if aliases:
+        kwargs["input_output_aliases"] = aliases
     if sched is None:
         return pl.pallas_call(
             kernel, name=name, grid=rectangle, in_specs=in_specs,
@@ -517,12 +578,20 @@ def _fwd(q, k, v, key_mask, causal, scale, window=None, shared=None):
 # ---------------------------------------------------------------------------
 
 def _bwd_dkv_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    qs_ref, ks_ref, mask_ref, dk_ref, dv_ref, *more, scale,
-                    causal_off, window=None, group=1):
-    if qs_ref is None:
-        dk_acc, dv_acc = more
+                    qs_ref, ks_ref, mask_ref, *refs, scale, causal_off,
+                    window=None, group=1, heads=1, lanes=None):
+    # ``lanes`` (the lane layout): dk and dv leave by DMA, not as blocks of
+    # two outputs, because both are lane blocks of one buffer (section
+    # "lane layout" below); refs are then that buffer, the accumulators, the
+    # two blocks' staging buffers and their semaphores
+    dks_ref = dks_acc = None
+    if lanes is not None:
+        out_ref, *accs, dk_buf, dv_buf, sem = refs
+    elif qs_ref is None:
+        dk_ref, dv_ref, *accs = refs
     else:
-        dks_ref, dk_acc, dv_acc, dks_acc = more
+        dk_ref, dv_ref, dks_ref, *accs, dks_acc = refs
+    accs = [accs[2 * h:2 * h + 2] for h in range(heads)]
     # the streamed dimension runs over the q-blocks of every query head of
     # this K/V head's group, one head after another: dk and dv add them up.
     # With the shared pair the group is the shared key's (all the heads of a
@@ -531,11 +600,14 @@ def _bwd_dkv_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     jk, iq, (first, last, opened, closed), run = _walk(sched, group)
     if qs_ref is None:
         first, last = opened, closed
+    if lanes is not None:               # read outside any pl.when: interpret mode
+        grid_row, grid_rows = pl.program_id(0), pl.num_programs(0)
 
     @pl.when(first)
     def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+        for dk_acc, dv_acc in accs:
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
 
     if qs_ref is not None:
         @pl.when(opened)
@@ -547,32 +619,38 @@ def _bwd_dkv_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         vb = v_ref[0]
         qb = q_ref[0]
         dob = do_ref[0]
-        lseb = lse_ref[0, 0]
-        deltab = delta_ref[0, 0]
-        s = _scores(qb, kb, qs_ref, ks_ref, mask_ref, scale,
-                    (iq, jk, causal_off, window) if cut else None)
-        p = _probs(s, lseb, cut or mask_ref is not None)
-        pb = p.astype(dob.dtype)
-        dv_acc[...] += jax.lax.dot_general(
-            pb, dob, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(dob, vb, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - deltab[:, None]) * scale).astype(qb.dtype)
-        dk_acc[...] += jax.lax.dot_general(
-            ds, qb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if qs_ref is not None:
-            dks_acc[...] += jax.lax.dot_general(
-                ds, qs_ref[0], (((0,), (0,)), ((), ())),
+        for h, (dk_acc, dv_acc) in enumerate(accs):
+            lseb = lse_ref[h, 0]
+            deltab = delta_ref[h, 0]
+            s = _scores(qb, _head(kb, h, heads), qs_ref, ks_ref, mask_ref, scale,
+                        (iq, jk, causal_off, window) if cut else None)
+            p = _probs(s, lseb, cut or mask_ref is not None)
+            pb = p.astype(dob.dtype)
+            dv_acc[...] += jax.lax.dot_general(
+                pb, dob, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(dob, _head(vb, h, heads), (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - deltab[:, None]) * scale).astype(qb.dtype)
+            dk_acc[...] += jax.lax.dot_general(
+                ds, qb, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if qs_ref is not None:
+                dks_acc[...] += jax.lax.dot_general(
+                    ds, qs_ref[0], (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
     run(_step)
 
     @pl.when(last)
     def _finish():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+        if lanes is None:
+            dk_ref[0] = _merge([dk_acc[...] for dk_acc, _ in accs]).astype(dk_ref.dtype)
+            dv_ref[0] = _merge([dv_acc[...] for _, dv_acc in accs]).astype(dv_ref.dtype)
+            return
+        _write_lanes(out_ref, sem, (grid_row, grid_rows, jk), lanes, (
+            (dk_buf, _merge([dk_acc[...] for dk_acc, _ in accs])),
+            (dv_buf, _merge([dv_acc[...] for _, dv_acc in accs]))))
 
     if qs_ref is not None:
         @pl.when(closed)
@@ -580,18 +658,55 @@ def _bwd_dkv_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dks_ref[0] = dks_acc[...].astype(dks_ref.dtype)
 
 
+def _write_lanes(out_ref, sem, where, lanes, blocks):
+    """Write a fixed k-block's results into lane blocks of ``out_ref``
+    (HBM): each ``(staging buffer, value)`` of ``blocks`` is stored and
+    copied to block column ``col + p`` (``lanes = (P, cols, nk)``; ``where =
+    (g, grid rows, jk)``: grid row ``g`` is batch row ``g // P``, lane block
+    ``p = g % P``), rows ``jk``.
+    A copy is waited for when the next block's is about to reuse its
+    buffer, so it runs under that block's work; the grid's last block waits
+    for its own. The grid runs in order (every dimension "arbitrary"): a
+    chain that ended at each grid row would let the rows run in parallel
+    on a chip with two TensorCores, but a row holds one k-block wherever
+    ``bk`` is the whole length (BERT's L = 512 and 128), so every copy
+    would be waited for where it starts."""
+    (P, cols, nk), (g, grid_rows, jk) = lanes, where
+    bk, W = blocks[0][0].shape
+    rows = pl.ds(pl.multiple_of(jk * bk, bk), bk)
+    copies = [pltpu.make_async_copy(
+        buf, out_ref.at[g // P, rows, pl.ds(pl.multiple_of((col + g % P) * W, W), W)],
+        sem.at[i]) for i, ((buf, _), col) in enumerate(zip(blocks, cols))]
+
+    @pl.when((g > 0) | (jk > 0))
+    def _previous():
+        for copy in copies:
+            copy.wait()
+
+    for buf, value in blocks:
+        buf[...] = value.astype(buf.dtype)
+    for copy in copies:
+        copy.start()
+
+    @pl.when((g == grid_rows - 1) & (jk == nk - 1))
+    def _own():
+        for copy in copies:
+            copy.wait()
+
+
 def _bwd_dq_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    qs_ref, ks_ref, mask_ref, dq_ref, *more, scale,
-                   causal_off, window=None):
+                   causal_off, window=None, heads=1):
     if qs_ref is None:
-        dq_acc, = more
+        dq_accs = more                  # one a head of the block
     else:
-        dqs_ref, dq_acc, dqs_acc = more
+        dqs_ref, *dq_accs, dqs_acc = more
     iq, jk, (first, last, _, _), run = _walk(sched)
 
     @pl.when(first)
     def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+        for dq_acc in dq_accs:
+            dq_acc[...] = jnp.zeros_like(dq_acc)
         if qs_ref is not None:
             dqs_acc[...] = jnp.zeros_like(dqs_acc)
 
@@ -600,27 +715,28 @@ def _bwd_dq_kernel(sched, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         kb = k_ref[0]
         vb = v_ref[0]
         dob = do_ref[0]
-        lseb = lse_ref[0, 0]
-        deltab = delta_ref[0, 0]
-        s = _scores(qb, kb, qs_ref, ks_ref, mask_ref, scale,
-                    (iq, jk, causal_off, window) if cut else None)
-        p = _probs(s, lseb, cut or mask_ref is not None)
-        dp = jax.lax.dot_general(dob, vb, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - deltab[:, None]) * scale).astype(kb.dtype)
-        dq_acc[...] += jax.lax.dot_general(
-            ds, kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if qs_ref is not None:
-            dqs_acc[...] += jax.lax.dot_general(
-                ds, ks_ref[0], (((1,), (0,)), ((), ())),
+        for h, dq_acc in enumerate(dq_accs):
+            lseb = lse_ref[h, 0]
+            deltab = delta_ref[h, 0]
+            s = _scores(_head(qb, h, heads), kb, qs_ref, ks_ref, mask_ref, scale,
+                        (iq, jk, causal_off, window) if cut else None)
+            p = _probs(s, lseb, cut or mask_ref is not None)
+            dp = jax.lax.dot_general(_head(dob, h, heads), vb, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - deltab[:, None]) * scale).astype(kb.dtype)
+            dq_acc[...] += jax.lax.dot_general(
+                ds, kb, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
+            if qs_ref is not None:
+                dqs_acc[...] += jax.lax.dot_general(
+                    ds, ks_ref[0], (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
 
     run(_step)
 
     @pl.when(last)
     def _finish():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        dq_ref[0] = _merge([dq_acc[...] for dq_acc in dq_accs]).astype(dq_ref.dtype)
         if qs_ref is not None:
             dqs_ref[0] = dqs_acc[...].astype(dqs_ref.dtype)
 
@@ -727,6 +843,186 @@ def _bwd(q, k, v, key_mask, causal, scale, o, lse, do, dlse=None,
 
 
 # ---------------------------------------------------------------------------
+# lane layout: the same kernels over the projections' own (B, L, n·H·D)
+# arrays, read and written as 128-lane column blocks of ``128 // D`` heads.
+# A grid row is (batch row, lane block): g = b·P + p with P = H·D / 128.
+# ---------------------------------------------------------------------------
+
+_LANES = 128
+
+#: ``heads`` in all, ``per_block`` heads a 128-lane block, ``blocks`` = P
+#: blocks a projection, ``cols``: the block columns where q, k and v start in
+#: the arrays they are read from; the tiles ``bq``, ``bk`` and ``interpret``
+#: as the call found them (static arguments of the jitted call below)
+_Lanes = collections.namedtuple("_Lanes", "heads per_block blocks cols bq bk interpret")
+
+
+def _lane_layout(x_q, x_kv, heads, mask=None) -> Optional[_Lanes]:
+    """The lane layout of a call, or None where it has none: self-attention
+    over one fused ``(B, L, 3C)`` projection (``x_kv`` None: q, k and v are
+    its thirds), or cross-attention over ``(B, Lq, C)`` and ``(B, Lk, 2C)``
+    (k, v), with ``C = heads * D``, ``D`` one of 32, 64, 128 (at most four
+    heads a block, each an unrolled body with its own accumulators in VMEM),
+    ``C`` whole blocks, lengths the tiles divide, a key mask or none."""
+    if x_q.ndim != 3 or heads <= 0:
+        return None
+    B, Lq, width = x_q.shape
+    if width % (3 if x_kv is None else 1):
+        return None
+    C = width // (3 if x_kv is None else 1)
+    D = C // heads
+    if C % heads or D not in (32, 64, 128) or C % _LANES:
+        return None
+    if x_kv is not None and (x_kv.ndim != 3 or x_kv.shape[0] != B
+                             or x_kv.shape[2] != 2 * C or x_kv.dtype != x_q.dtype):
+        return None
+    Lk = (x_q if x_kv is None else x_kv).shape[1]
+    bq, bk = _bq(Lq), _bk(Lk)
+    if Lq % bq or Lk % bk:
+        return None
+    if mask is not None and _as_key_mask(mask, B, heads, Lq, Lk) is None:
+        return None
+    P = C // _LANES
+    return _Lanes(heads, _LANES // D, P, (0, P, 2 * P) if x_kv is None else (0, 0, P),
+                  bq, bk, _interpret_for(x_q))
+
+
+def _lane_q(P, col):
+    """A q-block's lane block: batch row, row block, column ``col + p``."""
+    return lambda g, h, i, j: (g // P, i, col + g % P)
+
+
+def _lane_k(P, col):
+    return lambda g, h, i, j: (g // P, j, col + g % P)
+
+
+def _lane_mask(P):
+    return lambda g, h, i, j: (g // P, 0, j)
+
+
+def _fwd_lanes(x_q, x_kv, key_mask, lay, causal, scale):
+    """``o (B, Lq, C)`` and ``lse (B·H, 1, Lq)`` (a block's heads' rows
+    together, as a grid row reads them)."""
+    B, Lq, _ = x_q.shape
+    kv = x_q if x_kv is None else x_kv
+    Lk = kv.shape[1]
+    H, n, P, (cq, ck, cv), bq, bk, interpret = lay
+    name = "flash_fwd"
+    sched = _schedule(name, causal, Lq, Lk, bq, bk)
+    spec = functools.partial(_spec, _place_qk(sched))
+    in_specs = [spec((1, bq, _LANES), _lane_q(P, cq)), spec((1, bk, _LANES), _lane_k(P, ck)),
+                spec((1, bk, _LANES), _lane_k(P, cv))]
+    args = [x_q, kv, kv]
+    if key_mask is not None:
+        in_specs.append(spec((1, 1, bk), _lane_mask(P)))
+        args.append(key_mask.astype(jnp.int32).reshape(B, 1, Lk))
+    kern = functools.partial(
+        _optional_inputs(_fwd_kernel, len(sched or ()), 3, (False, False, key_mask is not None)),
+        scale=scale, causal_off=Lk - Lq, heads=n)
+    return _pallas(
+        kern, name, sched, (B * P, Lq // bq, Lk // bk), interpret, in_specs,
+        [spec((1, bq, _LANES), _lane_q(P, 0)), spec((n, 1, bq), _q_row)],
+        [jax.ShapeDtypeStruct((B, Lq, P * _LANES), x_q.dtype),
+         jax.ShapeDtypeStruct((B * H, 1, Lq), jnp.float32)],
+        _scratch(bq, _LANES) * n, args)
+
+
+def _bwd_lanes(x_q, x_kv, key_mask, lay, causal, scale, o, lse, do):
+    """The gradients of ``x_q`` and ``x_kv`` in their own layout. The dkv
+    kernel writes k's and v's lane blocks of one buffer shaped like the
+    array they were read from; with self-attention the dq kernel then
+    writes q's blocks into that same buffer (``input_output_aliases``), so
+    the fused projection's gradient is one array and no concatenation."""
+    B, Lq, _ = x_q.shape
+    kv = x_q if x_kv is None else x_kv
+    Lk = kv.shape[1]
+    H, n, P, (cq, ck, cv), bq, bk, interpret = lay
+    nq, nk = Lq // bq, Lk // bk
+    # delta = rowsum(do * o) a head. Summed by a product with the 0/1 matrix
+    # of which head a lane is: a reshape of the lanes into (H, D) is a
+    # relayout copy on a TPU (v5e compile: five times the estimated cycles)
+    D = _LANES // n
+    heads_of = (np.arange(H * D)[:, None] // D == np.arange(H)[None, :]).astype(np.float32)
+    delta = jnp.einsum("blc,ch->bhl", do.astype(jnp.float32) * o.astype(jnp.float32),
+                       heads_of, precision=jax.lax.Precision.HIGHEST).reshape(B * H, 1, Lq)
+    args = [x_q, kv, kv, do, lse, delta]
+    present = (False, False, key_mask is not None)
+    if key_mask is not None:
+        args.append(key_mask.astype(jnp.int32).reshape(B, 1, Lk))
+    params = dict(scale=scale, causal_off=Lk - Lq, heads=n)
+
+    def operands(spec):
+        specs = [spec((1, bq, _LANES), _lane_q(P, cq)), spec((1, bk, _LANES), _lane_k(P, ck)),
+                 spec((1, bk, _LANES), _lane_k(P, cv)), spec((1, bq, _LANES), _lane_q(P, 0)),
+                 spec((n, 1, bq), _q_row), spec((n, 1, bq), _q_row)]
+        return specs + ([spec((1, 1, bk), _lane_mask(P))] if key_mask is not None else [])
+
+    # ---- dk/dv: fixed k-block, stream q-blocks, out by DMA (_write_lanes)
+    name = "flash_bwd_dkv"
+    sched = _schedule(name, causal, Lq, Lk, bq, bk, None, 1)
+    if sched is None:
+        def place(b, j, t):
+            return b, b, t, j
+    else:
+        def place(b, t, kj, qi, kind, head):
+            return b, b, qi[t], kj[t]
+    dkv, = _pallas(
+        functools.partial(_optional_inputs(_bwd_dkv_kernel, len(sched or ()), 6, present),
+                          **params, lanes=(P, (ck, cv), nk)),
+        name, sched, (B * P, nk, nq), interpret, operands(functools.partial(_spec, place)),
+        [pl.BlockSpec(memory_space=pl.ANY)], [jax.ShapeDtypeStruct(kv.shape, kv.dtype)],
+        [pltpu.VMEM((bk, _LANES), jnp.float32)] * (2 * n)
+        + [pltpu.VMEM((bk, _LANES), kv.dtype)] * 2 + [pltpu.SemaphoreType.DMA((2,))],
+        args, in_order=True)
+
+    # ---- dq: fixed q-block, stream k-blocks; into dkv's buffer where q
+    # came from the same array
+    name = "flash_bwd_dq"
+    sched = _schedule(name, causal, Lq, Lk, bq, bk)
+    spec = functools.partial(_spec, _place_qk(sched))
+    in_specs, aliases = operands(spec), None
+    if x_kv is None:
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        aliases = {len(sched or ()) + len(args): 0}
+        args = args + [dkv]
+    dq, = _pallas(
+        functools.partial(_optional_inputs(_bwd_dq_kernel, len(sched or ()), 6, present,
+                                           unused=int(x_kv is None)), **params),
+        name, sched, (B * P, nq, nk), interpret, in_specs,
+        [spec((1, bq, _LANES), _lane_q(P, cq))], [jax.ShapeDtypeStruct(x_q.shape, x_q.dtype)],
+        [pltpu.VMEM((bq, _LANES), jnp.float32)] * n, args, aliases=aliases)
+    return (dq, None) if x_kv is None else (dq, dkv)
+
+
+#: Both directions jitted and inlined: the layers of a model, which make the
+#: same call, then share one trace and one lowering of each kernel (jax caches
+#: both by the traced jaxpr) and the program's text still holds every call.
+#: Traced and lowered anew a layer, the kernels cost BERT-base's step some
+#: 6 s of set-up (v5e: warm `first_call_s` 19.4 against the parent's 13.0 s)
+_fwd_lanes_once = jax.jit(_fwd_lanes, static_argnums=(3, 4, 5), inline=True)
+_bwd_lanes_once = jax.jit(_bwd_lanes, static_argnums=(3, 4, 5), inline=True)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_lanes(x_q, x_kv, key_mask, lay, causal, scale):
+    return _fwd_lanes_once(x_q, x_kv, key_mask, lay, causal, scale)[0]
+
+
+def _flash_lanes_fwd(x_q, x_kv, key_mask, lay, causal, scale):
+    o, lse = _named(*_fwd_lanes_once(x_q, x_kv, key_mask, lay, causal, scale))
+    return o, (x_q, x_kv, key_mask, o, lse)
+
+
+def _flash_lanes_bwd(lay, causal, scale, res, do):
+    x_q, x_kv, key_mask, o, lse = res
+    return (*_bwd_lanes_once(x_q, x_kv, key_mask, lay, causal, scale, o, lse,
+                             do.astype(o.dtype)), None)
+
+
+_flash_lanes.defvjp(_flash_lanes_fwd, _flash_lanes_bwd)
+
+
+# ---------------------------------------------------------------------------
 # public entry with custom VJP
 # ---------------------------------------------------------------------------
 
@@ -826,3 +1122,29 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
         raise ValueError("flash_attention supports key-padding masks "
                          "(B, Lk) / (B,1,1,Lk); use the XLA path otherwise")
     return _flash(q, k, v, key_mask, shared, causal, scale, window)
+
+
+def flash_attention_lanes(x_q, x_kv=None, heads: int = 1, mask=None, causal: bool = False,
+                          scale: Optional[float] = None):
+    """Blockwise attention read from, and written to, the projections' own
+    layout: ``x_q (B, L, 3C)``, the fused q, k, v projection, with ``x_kv``
+    None (self-attention); or ``x_q (B, Lq, C)`` and ``x_kv (B, Lk, 2C)``,
+    the k, v projection (cross-attention). ``C = heads * D``; q, k and v are
+    the thirds (halves) of their array, head ``h`` lanes ``h·D`` to
+    ``(h+1)·D`` of each. Returns ``(B, Lq, C)``, what the output projection
+    reads; the gradient of ``x_q`` (and ``x_kv``) comes back in the same
+    layout. What :func:`flash_attention` computes on the head-major
+    transposes of these arrays, without the transposes (module docstring).
+    Raises where :func:`_lane_layout` finds no lane layout."""
+    lay = _lane_layout(x_q, x_kv, heads, mask)
+    if lay is None:
+        raise ValueError(
+            f"no lane layout for x_q {x_q.shape}, x_kv {None if x_kv is None else x_kv.shape}, "
+            f"{heads} heads: needs (B, L, 3C) or (B, Lq, C) with (B, Lk, 2C), head size "
+            "32, 64 or 128, C whole 128-lane blocks, lengths the tiles divide and a key mask "
+            "or none; use flash_attention on head-major arrays")
+    D = x_q.shape[-1] // (3 if x_kv is None else 1) // heads
+    scale = (D ** -0.5) if scale is None else float(scale)
+    B, Lq = x_q.shape[:2]
+    Lk = (x_q if x_kv is None else x_kv).shape[1]
+    return _flash_lanes(x_q, x_kv, _as_key_mask(mask, B, heads, Lq, Lk), lay, causal, scale)

@@ -1,6 +1,9 @@
 """Fused attention ops: interleaved contrib parity + flash kernel vs XLA
 (reference test model: tests/python/unittest/test_operator.py attention
 cases + check_consistency, SURVEY §4)."""
+import collections
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as onp
@@ -508,3 +511,253 @@ def test_scheduled_kernels_match_dense(Hkv, Lq, Lk, window, padded, Ds, monkeypa
                          jax.tree_util.tree_leaves(ref_vjp(do))):
         assert got.shape == want.shape
         onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want), atol=3e-4)
+
+
+# ---------------------------------------------------------------------------
+# the lane layout: q, k and v read as 128-lane blocks of the projections'
+# own arrays (flash_attention.flash_attention_lanes, ops.projected_attention)
+# ---------------------------------------------------------------------------
+
+def _split_heads(x, n, H):
+    """``(B, L, n·H·D)`` -> ``n`` head-major ``(B, H, L, D)``: what
+    ``MultiHeadAttention`` traces where the lane layout is not taken."""
+    B, L, _ = x.shape
+    return [p.reshape(B, L, H, -1).transpose(0, 2, 1, 3) for p in jnp.split(x, n, -1)]
+
+
+def _head_major_from_projections(attn, H):
+    def run(x_q, x_kv):
+        if x_kv is None:
+            q, k, v = _split_heads(x_q, 3, H)
+        else:
+            (q,), (k, v) = _split_heads(x_q, 1, H), _split_heads(x_kv, 2, H)
+        o = attn(q, k, v)
+        B, _, Lq, D = o.shape
+        return o.transpose(0, 2, 1, 3).reshape(B, Lq, H * D)
+    return run
+
+
+# D, self- or cross-attention (Lk), key mask, causal; 64-row tiles, so a
+# call has several of each kind
+@pytest.mark.parametrize("D,Lk,masked,causal", [
+    pytest.param(64, None, False, False, id="D64_self"),
+    pytest.param(64, None, True, False, id="D64_self_masked"),
+    pytest.param(64, None, True, True, id="D64_self_masked_causal"),
+    pytest.param(128, None, True, False, id="D128_self_masked"),
+    pytest.param(128, None, False, True, id="D128_self_causal"),
+    pytest.param(64, 192, True, False, id="D64_cross_masked"),
+    pytest.param(64, 128, False, True, id="D64_cross_causal"),
+    pytest.param(128, 192, False, False, id="D128_cross"),
+    pytest.param(32, 128, True, True, id="D32_cross_masked_causal"),
+])
+def test_lane_layout_matches_head_major_and_xla(D, Lk, masked, causal, monkeypatch):
+    """Output and the gradients of the projections, against today's
+    head-major kernel on the transposes (to float32 rounding: the same
+    kernel bodies, a head's other lanes zeroed) and against the XLA path."""
+    from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
+    monkeypatch.setenv("MXTPU_FLASH_BQ", "64")
+    monkeypatch.setenv("MXTPU_FLASH_BK", "64")
+    rng = onp.random.RandomState(D + (Lk or 0))
+    B, L, H = 2, 128, 2 * 128 // D
+    C = H * D
+    if Lk is None:
+        x_q, x_kv, Lk = jnp.asarray(rng.randn(B, L, 3 * C), jnp.float32), None, L
+    else:
+        x_q = jnp.asarray(rng.randn(B, L, C), jnp.float32)
+        x_kv = jnp.asarray(rng.randn(B, Lk, 2 * C), jnp.float32)
+    mask = None
+    if masked:
+        mask = jnp.asarray(onp.arange(Lk)[None, :] < onp.array([Lk - 37, Lk])[:, None]
+                           ).reshape(B, 1, 1, Lk)
+    do = jnp.asarray(rng.randn(B, L, C), jnp.float32)
+    assert fa._lane_layout(x_q, x_kv, H, mask) is not None
+
+    def lanes(x_q, x_kv):
+        return fa.flash_attention_lanes(x_q, x_kv, H, mask=mask, causal=causal)
+    got, vjp = jax.vjp(lanes, x_q, x_kv)
+    got_g = vjp(do)
+    for attn, tol in ((lambda q, k, v: flash_attention(q, k, v, mask=mask, causal=causal), 1e-6),
+                      (lambda q, k, v: dot_product_attention(q, k, v, mask, causal=causal,
+                                                             impl="xla"), 2e-5)):
+        want, want_vjp = jax.vjp(_head_major_from_projections(attn, H), x_q, x_kv)
+        onp.testing.assert_allclose(onp.asarray(got), onp.asarray(want), atol=tol)
+        for a, b in zip(jax.tree_util.tree_leaves(got_g),
+                        jax.tree_util.tree_leaves(want_vjp(do))):
+            assert a.shape == b.shape
+            onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b), atol=10 * tol)
+
+
+def test_lane_layout_kernels_are_the_three_named_kernels(monkeypatch):
+    """Self-attention traces ``flash_fwd``, ``flash_bwd_dkv`` and
+    ``flash_bwd_dq`` over grid rows of (batch row, lane block): two heads a
+    block at D = 64. The dq kernel writes into the buffer the dkv kernel
+    began (one aliased operand), so the projection's gradient needs no
+    concatenation; the only ``transpose`` left is delta's, of the small
+    float32 ``(B, L, H)``."""
+    monkeypatch.setenv("MXTPU_FLASH_BQ", "64")
+    monkeypatch.setenv("MXTPU_FLASH_BK", "64")
+    from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
+    B, L, H, D = 2, 128, 4, 64
+    x = jnp.zeros((B, L, 3 * H * D), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda x: fa.flash_attention_lanes(x, None, H).sum()))(x)
+    calls = _pallas_calls(jaxpr.jaxpr)
+    assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    grids = {name: eqn.params["grid_mapping"].grid for name, eqn in calls.items()}
+    assert grids == {"flash_fwd": (B * 2, 2, 2), "flash_bwd_dkv": (B * 2, 2, 2),
+                     "flash_bwd_dq": (B * 2, 2, 2)}
+    assert list(calls["flash_bwd_dq"].params["input_output_aliases"]) == [(6, 0)]
+    names = collections.Counter(e.primitive.name for e in _walk(jaxpr.jaxpr))
+    assert names["concatenate"] == 0 and names["split"] == 0
+    transposes = [e for e in _walk(jaxpr.jaxpr) if e.primitive.name == "transpose"]
+    assert [e.outvars[0].aval.shape for e in transposes] == [(B, H, L)]
+
+
+def _walk(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            if eqn.primitive.name != "pallas_call":
+                yield from _walk(sub)
+
+
+def _on_a_chip(monkeypatch):
+    """Steer the kernels' one platform decision to the chip's, for a test
+    that only asks which path a call takes (nothing runs)."""
+    from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
+    monkeypatch.setattr(fa, "_interpret_for", lambda x: False)
+
+
+@pytest.mark.parametrize("H,D,axes", [
+    pytest.param(3, 64, None, id="odd_heads_at_D64"),
+    pytest.param(4, 96, None, id="D96"),
+    pytest.param(4, 64, dict(sp=2), id="sp2_ring"),
+    pytest.param(4, 64, dict(dp=2, tp=2), id="tp2_heads_split"),
+])
+def test_lane_layout_falls_back_where_it_cannot_be_taken(H, D, axes, monkeypatch):
+    """Odd heads at D = 64 (C no whole lane blocks), D = 96 (not a divisor
+    of 128), and a mesh that shards the sequence (ring attention) or the
+    heads: ``projected_attention`` splits the heads out for
+    ``dot_product_attention``, the gauge reads 0, and the same call on a
+    one-device mesh at D = 64 with even heads takes the lane layout."""
+    from incubator_mxnet_tpu import parallel
+    from incubator_mxnet_tpu.ops import attention
+    from incubator_mxnet_tpu.parallel.mesh import active_mesh
+    from incubator_mxnet_tpu.telemetry import metrics
+    _on_a_chip(monkeypatch)
+    decide = attention._lanes_taken
+    seen = []
+    monkeypatch.setattr(attention, "_lanes_taken",
+                        lambda *a: seen.append(decide(*a)) or seen[-1])
+    x = jnp.zeros((4, 256, 3 * H * D), jnp.bfloat16)
+    mask = jnp.ones((4, 1, 1, 256), bool)
+    mesh = None if axes is None else parallel.make_mesh(
+        devices=jax.devices()[:2 * len(axes)], **axes)
+
+    def attend(x, mask):
+        return attention.projected_attention(x, mask, heads=x.shape[-1] // 3 // D)
+    with active_mesh(mesh) if mesh is not None else contextlib.nullcontext():
+        out = jax.eval_shape(attend, x, mask)
+    assert seen == [False] and out.shape == (4, 256, H * D)
+    assert metrics.gauge("mxtpu_flash_lane_layout", kernel=f"flash_h{H}_d{D}").value == 0
+    y = jnp.zeros((4, 256, 3 * 4 * 64), jnp.bfloat16)
+    assert jax.eval_shape(lambda y, m: attention.projected_attention(y, m, heads=4),
+                          y, mask).shape == (4, 256, 256)
+    assert seen == [False, True]
+    assert metrics.gauge("mxtpu_flash_lane_layout", kernel="flash_h4_d64").value == 1
+
+
+# mesh size, self- or cross-attention: the lane kernels under a dp mesh, each
+# shard its rows (bert_base_pretrain.dp4 is the dp = 4 case)
+@pytest.mark.parametrize("dp", [2, 4], ids=["dp2", "dp4"])
+@pytest.mark.parametrize("cross", [False, True], ids=["self", "cross"])
+def test_projected_attention_under_a_dp_mesh_runs_per_shard(dp, cross, monkeypatch):
+    """Inside a step compiled over a ``dp`` mesh the lane kernels run under
+    a full-manual shard_map, each shard its batch rows and their key mask:
+    output and the projections' gradients as the head-major kernels give
+    them under the same mesh (interpret mode here), and as the XLA path."""
+    from incubator_mxnet_tpu import parallel
+    from incubator_mxnet_tpu.ops import attention
+    from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
+    from incubator_mxnet_tpu.parallel.mesh import active_mesh
+    monkeypatch.setenv("MXTPU_FLASH_BQ", "64")
+    monkeypatch.setenv("MXTPU_FLASH_BK", "64")
+    mesh = parallel.make_mesh(devices=jax.devices()[:dp], dp=dp)
+    rng = onp.random.RandomState(dp + 2 * cross)
+    B, L, H, D = 2 * dp, 128, 4, 64
+    C, Lk = H * D, 192 if cross else L
+    xs = ((jnp.asarray(rng.randn(B, L, C), jnp.float32),
+           jnp.asarray(rng.randn(B, Lk, 2 * C), jnp.float32)) if cross
+          else (jnp.asarray(rng.randn(B, L, 3 * C), jnp.float32),))
+    mask = jnp.asarray(onp.arange(Lk)[None, :] < rng.randint(1, Lk + 1, B)[:, None]
+                       ).reshape(B, 1, 1, Lk)
+    do = jnp.asarray(rng.randn(B, L, C), jnp.float32)
+
+    def run(lanes, impl):
+        monkeypatch.setenv("MXTPU_ATTN_IMPL", impl)
+        taken = []
+        monkeypatch.setattr(attention, "_lanes_taken", lambda q, kv, h, m: taken.append(
+            lanes and fa._lane_layout(q, kv, h, m) is not None) or taken[-1])
+
+        def f(xs, do):
+            o, vjp = jax.vjp(lambda xs: attention.projected_attention(
+                *xs, mask, heads=H, cross=cross), xs)
+            return (o,) + vjp(do)[0]
+        with active_mesh(mesh):
+            jaxpr = str(jax.make_jaxpr(f)(xs, do))
+            out = jax.jit(f)(xs, do)
+        assert taken and all(t == lanes for t in taken)
+        return out, jaxpr
+
+    got, jaxpr = run(True, "auto")
+    assert "shard_map" in jaxpr and jaxpr.count("pallas_call") >= 3
+    for (want, ref_jaxpr), tol in ((run(False, "flash"), 1e-6), (run(False, "xla"), 2e-5)):
+        assert ("shard_map" in ref_jaxpr) == (tol == 1e-6)
+        for a, b in zip(got, want):
+            assert a.shape == b.shape
+            onp.testing.assert_allclose(onp.asarray(a), onp.asarray(b), atol=10 * tol)
+
+
+@pytest.mark.parametrize("H,D", [(2, 64), (3, 64), (2, 96)],
+                         ids=["lane_layout", "fallback_odd_heads", "fallback_D96"])
+@pytest.mark.parametrize("cross", [False, True], ids=["self", "cross"])
+def test_multihead_attention_is_the_same_on_both_paths(H, D, cross, monkeypatch):
+    """``MultiHeadAttention``'s output and parameter gradients where its
+    projections go to the kernels as they are (interpret mode here) and
+    where its heads are split out for ``dot_product_attention`` (the XLA
+    path on this CPU). A shape without a lane layout takes the second path
+    whatever the platform, and agrees with itself."""
+    from incubator_mxnet_tpu import autograd, nd
+    from incubator_mxnet_tpu.models.transformer import MultiHeadAttention
+    from incubator_mxnet_tpu.ops import attention
+    from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
+    monkeypatch.setenv("MXTPU_FLASH_BQ", "64")
+    monkeypatch.setenv("MXTPU_FLASH_BK", "64")
+    rng = onp.random.RandomState(H * D + cross)
+    B, L, Lk, C = 2, 128, 192 if cross else 128, H * D
+    net = MultiHeadAttention(C, H, cross_attention=cross)
+    net.initialize()
+    x = nd.array(rng.randn(B, L, C).astype("float32"))
+    kv = nd.array(rng.randn(B, Lk, C).astype("float32")) if cross else None
+    mask = nd.array((onp.arange(Lk)[None, :] < onp.array([Lk - 37, Lk])[:, None]
+                     ).astype("float32").reshape(B, 1, 1, Lk))
+
+    def run():
+        with autograd.record():
+            out = net(x, kv, mask)
+            loss = (out * out).sum()
+        loss.backward()
+        return out.asnumpy(), {n: p.grad().asnumpy() for n, p in net.collect_params().items()}
+    want, want_g = run()
+    taken = []
+
+    def interpret_lanes(q, kv, heads, m):
+        taken.append(fa._lane_layout(q, kv, heads, m) is not None)
+        return taken[-1]
+    monkeypatch.setattr(attention, "_lanes_taken", interpret_lanes)
+    got, got_g = run()
+    assert taken == [D == 64 and H % 2 == 0]
+    onp.testing.assert_allclose(got, want, atol=2e-5)
+    assert sorted(got_g) == sorted(want_g)
+    for n in want_g:
+        onp.testing.assert_allclose(got_g[n], want_g[n], rtol=1e-4,
+                                    atol=1e-4 * max(1.0, float(onp.abs(want_g[n]).max())))

@@ -45,7 +45,7 @@ def _net(dtype, dropout):
 
 class _Step:
     """One net's ``ShardedTrainer`` step, traced (nothing compiles): its
-    jaxpr and the dtypes ``dot_product_attention`` was handed."""
+    jaxpr and the dtypes ``projected_attention`` was handed."""
 
     def __init__(self, dtype, attn_dtypes):
         net = _net(dtype, dropout=0.1)
@@ -65,17 +65,18 @@ class _Step:
 
 @pytest.fixture(scope="module")
 def steps():
-    seen, real = [], nd.dot_product_attention
+    seen, real = [], nd.projected_attention
 
-    def spy(q, k, v, *args, **kwargs):
-        seen.append(tuple(str(a.dtype) for a in (q, k, v)))
+    def spy(qkv, *args, **kwargs):
+        # the fused projection: q, k and v are its thirds
+        seen.append(str(qkv.dtype))
         # named so the walk can tell the op's own dots (on a CPU the XLA
-        # path, on the chip the flash kernel) from the model's
+        # path, on the chip the flash kernels) from the model's
         with jax.named_scope(_ATTN_SCOPE):
-            return real(q, k, v, *args, **kwargs)
+            return real(qkv, *args, **kwargs)
 
     with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(nd, "dot_product_attention", spy)
+        mp.setattr(nd, "projected_attention", spy)
         yield {d: _Step(d, seen) for d in ("bfloat16", "float32")}
 
 
@@ -103,8 +104,8 @@ def test_step_matmuls_take_no_float32_activation(steps):
 
 def test_attention_is_handed_bf16(steps):
     seen = steps["bfloat16"].attn_dtypes
-    assert len(seen) == 2 and set(seen) == {("bfloat16",) * 3}
-    assert set(steps["float32"].attn_dtypes) == {("float32",) * 3}
+    assert len(seen) == 2 and set(seen) == {"bfloat16"}
+    assert set(steps["float32"].attn_dtypes) == {"float32"}
 
 
 def test_step_loss_is_float32(steps):

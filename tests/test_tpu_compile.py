@@ -332,6 +332,38 @@ def test_flash_inside_a_mesh_step_compiles_for_v5e_2x2(topo, for_the_chip, axes,
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+# mesh axes, (B, L, H, D) global, whether the lane kernels take the call:
+# the four-chip benchmark cell's attention (32 rows a chip) and a dp·tp mesh,
+# which splits heads and so the head-major kernels take it
+@pytest.mark.parametrize("axes,shape,lanes", [
+    pytest.param(dict(dp=4), (128, 512, 12, 64), True, id="dp4_bert_base_B128"),
+    pytest.param(dict(dp=2, tp=2), (8, 512, 12, 64), False, id="dp2_tp2"),
+])
+def test_projected_attention_inside_a_mesh_step_compiles_for_v5e_2x2(topo, for_the_chip, axes,
+                                                                     shape, lanes):
+    """``projected_attention`` over the fused projection inside a step
+    compiled over a mesh: per shard, the three named kernels forward and
+    backward; with the lane layout no head-major array on a chip."""
+    from incubator_mxnet_tpu.ops.attention import projected_attention
+    from incubator_mxnet_tpu.parallel.mesh import active_mesh
+    mesh = parallel.make_mesh(devices=list(topo.devices), **axes)
+    B, L, H, D = shape
+    x = jax.ShapeDtypeStruct((B, L, 3 * H * D), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp", None, None)))
+    mask = jax.ShapeDtypeStruct((B, 1, 1, L), jnp.bool_, sharding=NamedSharding(mesh, P("dp")))
+    do = jax.ShapeDtypeStruct((B, L, H * D), jnp.bfloat16,
+                              sharding=NamedSharding(mesh, P("dp", None, None)))
+    with active_mesh(mesh):
+        text = _fwd_bwd(lambda x, m: projected_attention(x, m, heads=H)).lower(
+            x, mask, do).compile().as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert re.search(rf'^\s*%\S*{kernel}\S* = .*custom_call_target="tpu_custom_call"',
+                         text, re.M), kernel
+    b = B // axes["dp"]
+    head_major = re.search(rf'bf16\[(?:{b},{H // axes.get("tp", 1)},{L},{D}|{b},{L},{H},{D})\]', text)
+    assert (head_major is None) == lanes
+
+
 def test_v5e_reports_a_kind_the_peak_table_knows(topo):
     """What the chip calls itself must resolve to the v5e row, whole-kind:
     'TPU v5 lite' contains 'TPU v5', the v5p's kind."""
@@ -342,3 +374,153 @@ def test_v5e_reports_a_kind_the_peak_table_knows(topo):
     assert util.device_peaks("TPU v5") == util.device_peaks("TPU v5p") \
         != util.device_peaks(kind)
     assert onp.isclose(util.device_peaks(kind)[2] * 8, 1600.0)  # Gbit/s
+
+
+# (B, L, H, D), self- or cross-attention, masked, causal: BERT-base's and
+# -large's cells at L = 512 (8 rows), phase 1's at L = 128, a cross-attention
+# call at D = 128 and a causal one
+@pytest.mark.parametrize("shape,cross,masked,causal", [
+    pytest.param((8, 512, 12, 64), False, True, False, id="bert_base_L512_masked"),
+    pytest.param((8, 512, 16, 64), False, True, False, id="bert_large_L512_masked"),
+    pytest.param((32, 128, 12, 64), False, True, False, id="bert_base_L128_masked"),
+    pytest.param((4, 512, 8, 128), True, False, False, id="cross_head_dim128"),
+    pytest.param((2, 1024, 12, 64), False, False, True, id="causal_L1024"),
+])
+def test_flash_lane_layout_compiles_for_v5e(topo, for_the_chip, shape, cross, masked, causal):
+    """The kernels over the projections' own arrays: the three named
+    kernels, the gradient of the fused projection leaving the dq kernel as
+    one ``(B, L, 3C)`` array (the dkv kernel's buffer, aliased), and no
+    head-major bf16 array or concatenation anywhere in the program."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    B, L, H, D = shape
+    C = H * D
+    sds = lambda *dims, dt=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)  # noqa: E731
+    x_q = sds(B, L, C if cross else 3 * C)
+    x_kv = sds(B, L, 2 * C) if cross else None
+    mask = sds(B, L, dt=jnp.bool_) if masked else None
+
+    def f(x_q, x_kv, mask, do):
+        o, vjp = jax.vjp(lambda a, b: fa.flash_attention_lanes(a, b, H, mask=mask, causal=causal),
+                         x_q, x_kv)
+        return (o,) + vjp(do)
+
+    text = jax.jit(f).lower(x_q, x_kv, mask, sds(B, L, C)).compile().as_text()
+    for kernel in ("flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"):
+        assert len(re.findall(rf'^\s*%\S*{kernel}[_.\d]* = .*custom_call_target="tpu_custom_call"',
+                              text, re.M)) == 1, kernel
+    assert re.search(rf'flash_bwd_dq[_.\d]* = bf16\[{B},{L},{C if cross else 3 * C}\]', text)
+    assert not re.search(rf'bf16\[(?:{B},{H},{L},{D}|{B},{L},{H},{D}|{B * H},{L},{D})\]', text)
+    assert " concatenate(" not in text
+
+
+def _bert_step_text(topo, monkeypatch, lanes, chips=1):
+    """BERT's whole training step at the rehearsal size (2 layers, 2 heads
+    of 64, 4 rows of 128 a chip), compiled for one described v5e or, with
+    ``chips=4``, for the described 2x2 under the mesh of
+    ``bert_base_pretrain.dp4`` (dp = 4, zero1 by the trainer's default),
+    with the lane layout taken or not; the trainer built on the CPU."""
+    from incubator_mxnet_tpu import models
+    from incubator_mxnet_tpu.ops import attention
+    from incubator_mxnet_tpu.parallel.mesh import active_mesh
+    B, L, P, V = 4 * chips, 128, 19, 1000
+    rng = onp.random.RandomState(0)
+    batch = (rng.randint(0, V, (B, L)).astype("int32"), rng.randint(0, 2, (B, L)).astype("int32"),
+             onp.full((B,), L, "float32"), onp.sort(rng.rand(B, L).argsort(1)[:, :P], 1).astype("int32"),
+             rng.randint(0, V, (B, P)).astype("float32"), onp.ones((B, P), "float32"),
+             rng.randint(0, 2, (B,)).astype("float32"))
+    net = models.get_bert("bert_2_128_2", vocab_size=V, max_length=L, dropout=0.1, dtype="bfloat16")
+    net.initialize()
+    axes = {"dp": chips} if chips > 1 else {}
+    tr = parallel.ShardedTrainer(net, models.bert_pretrain_loss, "adamw",
+                                 {"learning_rate": 1e-4, "multi_precision": True},
+                                 mesh=parallel.make_mesh(devices=jax.devices()[:chips], **axes),
+                                 rules=models.bert_sharding_rules(), n_labels=3)
+    with monkeypatch.context() as m:
+        m.setattr(fa, "_interpret_for", lambda x: True)   # the eager warm-up runs here
+        tr.prepare(*batch)
+    if not lanes:
+        monkeypatch.setattr(attention, "_lanes_taken", lambda *a: False)
+    monkeypatch.setattr(jax.lax, "with_sharding_constraint", lambda x, s: x)
+    if chips == 1:
+        mesh, one_chip = tr.mesh, SingleDeviceSharding(topo.devices[0])
+        place = lambda x: one_chip  # noqa: E731
+    else:
+        mesh = parallel.make_mesh(devices=list(topo.devices)[:chips], **axes)
+        place = lambda x: NamedSharding(  # noqa: E731
+            mesh, x.sharding.spec if isinstance(x.sharding, NamedSharding) else P())
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=place(x))
+                        if hasattr(x, "shape") else x, tr.step_trace_args(*batch))
+    with active_mesh(mesh):
+        return jax.jit(tr._step_fn.__wrapped__).lower(*args).compile().as_text()
+
+
+def test_bert_step_holds_no_head_major_copy_on_v5e(topo, for_the_chip, monkeypatch):
+    """With the lane layout the compiled step moves no q, k, v, o or their
+    gradients into or out of head-major order: no ``copy``, ``transpose``
+    or ``concatenate`` whose result is ``bf16[B, H, L, D]`` (or its
+    transpose) or the fused projection's ``(B, L, 3C)`` gradient; the
+    program that splits the heads out holds several a layer (ten at this
+    size, eight a layer at BERT-base's cell, 96 in all)."""
+    B, H, L, D, layers = 4, 2, 128, 64, 2
+    moved = re.compile(rf'= bf16\[(?:{B},{H},{L},{D}|{B},{L},{H},{D}|{B},{L},{3 * H * D})\]'
+                       r'\S* (?:copy|transpose|concatenate)\(')
+    kernels = re.compile(r'^\s*%\S*flash_\S* = .*custom_call_target="tpu_custom_call"', re.M)
+    text = _bert_step_text(topo, monkeypatch, lanes=True)
+    assert len(kernels.findall(text)) == 3 * layers
+    assert moved.findall(text) == []
+    split = _bert_step_text(topo, monkeypatch, lanes=False)
+    assert len(kernels.findall(split)) == 3 * layers
+    assert len(moved.findall(split)) >= 4 * layers
+
+
+def test_bert_dp4_step_holds_no_head_major_copy_on_v5e_2x2(topo, for_the_chip, monkeypatch):
+    """``bert_base_pretrain.dp4``'s step at the rehearsal size, compiled for
+    the described 2x2 v5e: the lane kernels run per shard of the batch
+    under the step's ``dp`` mesh (each chip its 4 rows), beside the
+    gradients' reduce-scatter and the weights' all-gather, and no q, k, v,
+    o or gradient is moved into or out of head-major order on a chip."""
+    B, H, L, D, layers = 4, 2, 128, 64, 2
+    text = _bert_step_text(topo, monkeypatch, lanes=True, chips=4)
+    kernels = re.findall(r'^\s*%\S*(flash_[a-z_]+?)[_.\d]* = .*custom_call_target="tpu_custom_call"',
+                         text, re.M)
+    assert sorted(kernels) == sorted(["flash_fwd", "flash_bwd_dkv", "flash_bwd_dq"] * layers)
+    assert re.search(rf'flash_bwd_dq[_.\d]* = bf16\[{B},{L},{3 * H * D}\]', text)
+    assert not re.search(rf'= bf16\[(?:{B},{H},{L},{D}|{B},{L},{H},{D}|{B},{L},{3 * H * D})\]'
+                         r'\S* (?:copy|transpose|concatenate)\(', text)
+    assert re.search(r"all-gather|reduce-scatter|all-reduce", text)
+
+
+@pytest.mark.parametrize("cell", ["trinity_mini_train.packed8k", "kanana2_30b_a3b_train.packed8k",
+                                  "lfm2_8b_a1b_train.packed8k_b4"])
+def test_decoder_steps_never_ask_for_the_lane_layout(cell, monkeypatch):
+    """The three decoder blocks build their own head-major q, k and v and
+    call ``dot_product_attention``: their whole training step, lowered at
+    the benchmark's rehearsal size (CPU), never consults the lane layout's
+    gate, so their programs are the head-major kernels' as before."""
+    import importlib
+    import json
+    from incubator_mxnet_tpu.ops import attention
+    from incubator_mxnet_tpu.parallel.mesh import active_mesh
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def load(*parts):
+        with open(os.path.join(root, *parts)) as f:
+            return json.load(f)
+    bench = load("BENCHMARK.json")
+    workload = next(w for w in bench["workloads"] if w["name"] == cell)
+    cfg = load(next(c["file"] for c in bench["configs"] if c["name"] == workload["config"]))
+    cfg = {**cfg, **cfg["rehearse"]}
+    traffic = load("chipbench", "workloads", cell + ".json")["traffic"]
+    traffic = {**traffic, **traffic["rehearse"]}
+    asked = []
+    monkeypatch.setattr(attention, "_lanes_taken", lambda *a: asked.append(a) or False)
+    family = importlib.import_module("chipbench.families." + cfg["family"])
+    system = family.build_train(cfg, jax.devices()[:1], 2147483001)
+    batch = family.train_batches(cfg, traffic, 2147483001, 1, traffic["batch"])[0]
+    tr = system.trainer
+    with system.ctx:
+        tr.prepare(*batch)
+        with active_mesh(tr.mesh):
+            text = tr._step_fn.lower(*tr.step_trace_args(*batch)).as_text()
+    assert "dot_general" in text          # the whole step was traced and lowered
+    assert asked == []

@@ -616,7 +616,11 @@ def gen_profiler() -> str:
            "term whose one key head all query heads read (latent "
            "attention: `flash_fwd_mla`, `flash_bwd_dkv_mla`, "
            "`flash_bwd_dq_mla`, read by `mla_attn_ms.train` and "
-           "`mla_attn_roofline.train`); `moe_gmm`, `moe_tgmm`; "
+           "`mla_attn_roofline.train`); the same three names where BERT's "
+           "attention reads its projections as they are "
+           "(`ops.projected_attention`, the gauge "
+           "`mxtpu_flash_lane_layout{kernel=}` says whether a call took "
+           "it); `moe_gmm`, `moe_tgmm`; "
            "`moe_rows_*`; `short_conv_fwd`, `short_conv_bwd` (the gated "
            "short convolution `ops.nn.short_conv_gate`, one pass over its "
            "operands each way, read by `short_conv_ms.train` and "
