@@ -30,6 +30,8 @@ from . import deepseek_v3  # noqa: F401
 from .deepseek_v3 import DeepseekV3Model, get_deepseek_v3  # noqa: F401
 from . import lfm2_moe  # noqa: F401
 from .lfm2_moe import Lfm2MoeModel, get_lfm2_moe  # noqa: F401
+from . import granite_hybrid  # noqa: F401
+from .granite_hybrid import GraniteHybridModel, get_granite_hybrid  # noqa: F401
 
 #: Serving axis specs per model family — the ``input_axes``/``pad_values``
 #: a ``serve.CompiledModel``/``ModelRegistry.load`` needs to bucket each

@@ -5,6 +5,7 @@ Importing this package registers all ops into ``registry.OPS``; the
 """
 from . import tensor  # noqa: F401
 from . import nn  # noqa: F401
+from . import ssm  # noqa: F401
 from . import attention  # noqa: F401
 from . import detection  # noqa: F401
 from . import quantization  # noqa: F401

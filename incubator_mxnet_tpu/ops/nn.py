@@ -1008,6 +1008,38 @@ def short_conv_gate(bcx, w, **_):
 
 
 # ---------------------------------------------------------------------------
+# Around a state-space scan: the causal convolution before it and the gated
+# norm after it (Mamba-2-style mixer; no reference counterpart)
+# ---------------------------------------------------------------------------
+
+@register_op()
+def causal_conv1d(x, weight, bias, **_):
+    """Causal depthwise convolution over positions with bias, then SiLU:
+    ``x (B, L, C)``, ``weight (C, K)`` one weight a channel and a tap,
+    ``bias (C,)``; ``out[t] = silu(sum_k weight[:, k] * x[t - (K - 1) + k]
+    + bias)`` with ``x`` zero before a row's first position (PyTorch's
+    ``Conv1d`` with ``groups=C``, ``padding=K-1``, cut to ``L``). Products
+    and sums in fp32, one cast back: left to XLA, which fuses it into one
+    pass."""
+    C, K = weight.shape
+    L = x.shape[1]
+    s = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+    w = weight.astype(jnp.float32)
+    y = sum(w[:, k] * s[:, k:k + L] for k in range(K)) + bias.astype(jnp.float32)
+    return jax.nn.silu(y).astype(x.dtype)
+
+
+@register_op()
+def rms_norm_gated(data, gate, gamma, eps=1e-6, **_):
+    """``gamma * g / rms(g)`` with ``g = data * silu(gate)``, the statistic
+    over the last axis (one group), all in fp32, one cast back: the norm
+    between a Mamba-2 scan and its out-projection."""
+    g = data.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32))
+    v = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+    return (g * lax.rsqrt(v + eps) * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
+# ---------------------------------------------------------------------------
 # q and k between their projections and the attention kernels: per-head
 # RMSNorm, rotary, the head-major layout (no reference counterpart)
 # ---------------------------------------------------------------------------

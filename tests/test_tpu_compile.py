@@ -26,7 +26,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from incubator_mxnet_tpu import parallel
 from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
 from incubator_mxnet_tpu.ops import nn as ops_nn
-from incubator_mxnet_tpu.ops.pallas import moe_gmm, moe_rows, qk_prologue, short_conv
+from incubator_mxnet_tpu.ops import ssm
+from incubator_mxnet_tpu.ops.pallas import moe_gmm, moe_rows, qk_prologue, short_conv, ssd
 from incubator_mxnet_tpu.parallel import collectives, moe_dropless, ring
 
 
@@ -54,6 +55,7 @@ def for_the_chip(monkeypatch):
     monkeypatch.setattr(moe_rows, "_interpret_for", lambda x: False)
     monkeypatch.setattr(short_conv, "_interpret_for", lambda x: False)
     monkeypatch.setattr(qk_prologue, "_interpret_for", lambda x: False)
+    monkeypatch.setattr(ssd, "_interpret_for", lambda x: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -211,6 +213,29 @@ def test_short_conv_gate_compiles_for_v5e(topo, for_the_chip, shape, dtype):
         assert len(re.findall(rf'^\s*%\S*{kernel}\S* = .*custom_call_target="tpu_custom_call"',
                               text, re.M)) == 1, kernel
     assert " convolution(" not in text
+
+
+# Granite-4.0-H-Micro's scan at the cell's size: one row of 8,192 tokens, 64
+# heads of 64, a state of 128, one group, chunks of 256
+def test_ssd_scan_compiles_for_v5e(topo, for_the_chip):
+    """The op through its own backward rule: one ``ssd_fwd`` and one
+    ``ssd_bwd`` kernel, and no chunk-by-chunk decay array of XLA's."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    Bt, L, H, D, N = 1, 8192, 64, 64, 128
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)  # noqa: E731
+
+    def f(x, dt, A, B, C, skip, dy):
+        out, vjp = jax.vjp(lambda *a: ssm.ssd_scan(*a, 256), x, dt, A, B, C, skip)
+        return (out,) + vjp(dy)
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    text = jax.jit(f).lower(
+        sds((Bt, L, H, D), bf16), sds((Bt, L, H), f32), sds((H,), f32), sds((Bt, L, 1, N), bf16),
+        sds((Bt, L, 1, N), bf16), sds((H,), f32), sds((Bt, L, H, D), bf16)).compile().as_text()
+    for kernel in ("ssd_fwd", "ssd_bwd"):
+        assert len(re.findall(rf'^\s*%\S*{kernel}\S* = .*custom_call_target="tpu_custom_call"',
+                              text, re.M)) == 1, kernel
+    assert "256,256" not in text
 
 
 # Trinity-Mini's q and k at the cell's size (one row of 8,192 tokens, 32 and 4

@@ -628,7 +628,11 @@ def gen_profiler() -> str:
            "(`ops.nn.qk_norm_rope`: per-head RMSNorm, rotary and the "
            "head-major write of a q or k projection in one pass each way; "
            "the gauge `mxtpu_qk_prologue_fused{kernel=}` says whether a "
-           "call took them). `jax.named_scope`s sit in every operation's "
+           "call took them); `ssd_fwd`, `ssd_bwd` (the chunked state-space "
+           "scan `ops.ssm.ssd_scan` of a Mamba-2 mixer, the state carried "
+           "in VMEM from chunk to chunk, read by `ssd_scan_ms.train` and "
+           "`ssd_scan_roofline.train`; the gauge `mxtpu_ssd_fused{kernel=}` "
+           "says whether a call took them). `jax.named_scope`s sit in every operation's "
            "`op_name`: `afmoe_attention`, `moe_router`, `moe_dispatch`, "
            "`moe_experts`, `moe_combine`, `lm_head`, and in "
            "`models/deepseek_v3.py`'s attention block `mla_q` (the query "
@@ -639,6 +643,10 @@ def gen_profiler() -> str:
            "`lfm2_mixer_conv` and `lfm2_mixer_attn` round the two kinds of "
            "token mixer, and `short_conv` round the fused op inside the first; "
            "`qk_prologue` round the fused q/k prologue where its kernels run; "
+           "in `models/granite_hybrid.py` `mamba_mixer` round the whole "
+           "Mamba-2 mixer (read by `mamba_mixer_ms.train`), `mamba_conv` and "
+           "`mamba_gated_norm` round its convolution and its gated norm, and "
+           "`ssd_scan` (`ops/ssm.py`) round the scan; "
            "`layer_norm` and `rms_norm` (`ops/nn.py`) round the two norms; "
            "`optimizer_update` (`ShardedTrainer`) round the per-parameter "
            "update. jax adds `transpose(jvp(...))` for the backward pass and "
