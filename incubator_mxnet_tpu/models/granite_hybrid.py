@@ -122,10 +122,10 @@ class GraniteMamba(HybridBlock):
         inner = H * P
         with jax.named_scope("mamba_mixer"):
             zxbcdt = self.in_proj(x)._data
-            z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * G * N], axis=-1)
-            with jax.named_scope("mamba_conv"):
-                xbc = causal_conv1d(xbc, conv_weight._data, conv_bias._data)
-            xs, b, c = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+            z, dt = zxbcdt[..., :inner], zxbcdt[..., 2 * inner + 2 * G * N:]
+            with jax.named_scope("mamba_conv"):     # reads xBC where the projection wrote it
+                xs, b, c = causal_conv1d(zxbcdt, conv_weight._data, conv_bias._data,
+                                         split=(inner, inner + G * N), start=inner)
             dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias._data.astype(jnp.float32))
             A = -jnp.exp(A_log._data.astype(jnp.float32))
             y = ssd_scan(xs.reshape(Bt, L, H, P), dt, A, b.reshape(Bt, L, G, N),

@@ -1012,21 +1012,80 @@ def short_conv_gate(bcx, w, **_):
 # norm after it (Mamba-2-style mixer; no reference counterpart)
 # ---------------------------------------------------------------------------
 
+def causal_conv1d_plain(x, weight, bias, split=(), start=0):
+    """:func:`causal_conv1d` in plain ``jax.numpy``, differentiated by jax
+    itself: what XLA makes of the op, what a call the kernels do not take
+    traces, and the oracle the kernels are tested against. Products and sums
+    in fp32, one cast back."""
+    C, K = weight.shape
+    L = x.shape[1]
+    x = lax.slice_in_dim(x, start, start + C, axis=-1)
+    s = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+    w = weight.astype(jnp.float32)
+    y = sum(w[:, k] * s[:, k:k + L] for k in range(K)) + bias.astype(jnp.float32)
+    y = jax.nn.silu(y).astype(x.dtype)
+    return tuple(jnp.split(y, split, axis=-1)) if split else y
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def causal_conv_fused(x, weight, bias, split, start):
+    """:func:`causal_conv1d` on the kernel pair whatever the platform
+    (interpret mode off the TPU), with the op's own backward: ``dx``, ``d
+    weight`` and ``d bias`` come from the inputs and the output's gradient
+    alone, so nothing but the inputs is held for it."""
+    return _causal_conv_fused_fwd(x, weight, bias, split, start)[0]
+
+
+def _causal_conv_fused_fwd(x, weight, bias, split, start):
+    from .pallas import causal_conv
+    y = causal_conv.forward(x, weight, bias, split, start)
+    return (y if split else y[0]), (x, weight, bias)
+
+
+def _causal_conv_fused_bwd(split, start, res, dy):
+    from .pallas import causal_conv
+    x, weight, _ = res
+    dx, dw, db = causal_conv.backward(*res, dy if split else (dy,), start)
+    after = x.shape[-1] - start - weight.shape[0]
+    if start or after:       # the channels the convolution does not read
+        dx = jnp.pad(dx, ((0, 0), (0, 0), (start, after)))
+    return dx, dw, db
+
+
+causal_conv_fused.defvjp(_causal_conv_fused_fwd, _causal_conv_fused_bwd)
+
+
 @register_op()
-def causal_conv1d(x, weight, bias, **_):
+def causal_conv1d(x, weight, bias, split=(), start=0, **_):
     """Causal depthwise convolution over positions with bias, then SiLU:
     ``x (B, L, C)``, ``weight (C, K)`` one weight a channel and a tap,
     ``bias (C,)``; ``out[t] = silu(sum_k weight[:, k] * x[t - (K - 1) + k]
     + bias)`` with ``x`` zero before a row's first position (PyTorch's
-    ``Conv1d`` with ``groups=C``, ``padding=K-1``, cut to ``L``). Products
-    and sums in fp32, one cast back: left to XLA, which fuses it into one
-    pass."""
+    ``Conv1d`` with ``groups=C``, ``padding=K-1``, cut to ``L``). ``x`` may
+    be wider, the convolution reading its ``C`` channels from ``start`` (a
+    projection's whole output, read where it lies). With ``split``, the
+    output's parts as ``jnp.split(out, split, axis=-1)`` gives them, and
+    their gradients come back the same way.
+
+    The call decides by what it can observe: bf16 or fp32, every part and
+    ``start`` whole lane tiles and at most 8 taps on a TPU is one Pallas
+    kernel each way (``ops/pallas/causal_conv.py``: ``causal_conv_fwd``,
+    ``causal_conv_bwd``) behind a backward rule of the op's own, each part
+    written where the next op reads it; anything else traces
+    :func:`causal_conv1d_plain`. The gauge ``mxtpu_causal_conv_fused{kernel=}``
+    says which way the last call of that width and tap count went."""
+    from ..telemetry import metrics
+    from .pallas import causal_conv
     C, K = weight.shape
-    L = x.shape[1]
-    s = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
-    w = weight.astype(jnp.float32)
-    y = sum(w[:, k] * s[:, k:k + L] for k in range(K)) + bias.astype(jnp.float32)
-    return jax.nn.silu(y).astype(x.dtype)
+    split = tuple(split)
+    fused = (not causal_conv._interpret_for(x)
+             and causal_conv.supported(x, weight, split, start))
+    metrics.gauge("mxtpu_causal_conv_fused", "1 where the causal convolution's kernel pair "
+                  "took the last call of this width and tap count, 0 where the plain form "
+                  "did", kernel=f"causal_conv_c{C}_k{K}").set(int(fused))
+    if fused:
+        return causal_conv_fused(x, weight, bias, split, start)
+    return causal_conv1d_plain(x, weight, bias, split, start)
 
 
 @register_op()

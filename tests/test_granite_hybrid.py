@@ -19,7 +19,7 @@ import incubator_mxnet_tpu as mx
 from incubator_mxnet_tpu import models, parallel
 from incubator_mxnet_tpu.models.granite_hybrid import GraniteAttention, GraniteMamba
 from incubator_mxnet_tpu.ops import nn as ops_nn, ssm
-from incubator_mxnet_tpu.ops.pallas import ssd
+from incubator_mxnet_tpu.ops.pallas import causal_conv, ssd
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from chipbench.reference import granite_hybrid as reference  # noqa: E402
@@ -145,7 +145,10 @@ def test_the_kernels_take_the_chips_shapes_and_refuse_others(monkeypatch):
     assert ssm.ssd_scan(*args, 8) == "kernels" and gauge.value == 1
 
 
-def test_causal_conv1d_is_the_plain_convolution():
+@pytest.mark.parametrize("conv", [ops_nn.causal_conv1d,
+                                  lambda x, w, b: ops_nn.causal_conv_fused(x, w, b, (), 0)],
+                         ids=["plain", "kernels"])
+def test_causal_conv1d_is_the_plain_convolution(conv):
     rng = onp.random.default_rng(8)
     x = rng.normal(size=(2, 12, 6)).astype("float32")
     w, b = rng.normal(size=(6, 4)).astype("float32"), rng.normal(size=6).astype("float32")
@@ -157,12 +160,115 @@ def test_causal_conv1d_is_the_plain_convolution():
                 want[:, t] += w[:, k] * x[:, s]
     want = want + b
     want = want / (1 + onp.exp(-want))                       # silu
-    got = ops_nn.causal_conv1d(jnp.asarray(x), jnp.asarray(w), jnp.asarray(b))
+    got = conv(jnp.asarray(x), jnp.asarray(w), jnp.asarray(b))
     onp.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
     # and through the gradient: a position's output reads no later position
-    g = jax.grad(lambda x: ops_nn.causal_conv1d(x, jnp.asarray(w), jnp.asarray(b))[:, 5].sum())(
+    g = jax.grad(lambda x: conv(x, jnp.asarray(w), jnp.asarray(b))[:, 5].sum())(
         jnp.asarray(x))
     assert float(jnp.abs(g[:, 6:]).max()) == 0.0 and float(jnp.abs(g[:, 2:6]).min()) > 0.0
+
+
+def _conv_operands(shape, taps, dtype, width=None, seed=10):
+    """``x (B, L, width)``, taps ``(C, K)`` and a bias, drawn as the Mamba-2
+    initialisers draw the taps (``U[+-1/sqrt(K)]``)."""
+    B_, L_, C_ = shape
+    rng = onp.random.default_rng(seed)
+    bound = taps ** -0.5
+    return (jnp.asarray(rng.normal(size=(B_, L_, width or C_)), dtype),
+            jnp.asarray(rng.uniform(-bound, bound, (C_, taps)), dtype),
+            jnp.asarray(rng.uniform(-bound, bound, C_), dtype))
+
+
+def _close(got, want, tol):
+    f32 = lambda t: onp.asarray(t, "float32")  # noqa: E731
+    assert got.shape == want.shape and got.dtype == want.dtype
+    onp.testing.assert_allclose(f32(got), f32(want), rtol=5 * tol,
+                                atol=tol * max(1.0, float(onp.abs(f32(want)).max())))
+
+
+# (B, L, C), taps, split, start, width, tile: rows that are no whole number of
+# tiles (40 in tiles of 16, 100 in tiles of 32) so that the halo crosses tile
+# borders both ways, the output cut into parts as a Mamba-2 mixer cuts it, and
+# the input read as a window of a wider array at a lane-tile offset
+_CONV_CASES = [((2, 40, 256), 4, (), 0, None, 16), ((2, 100, 384), 2, (256, 320), 0, None, 32),
+               ((2, 40, 384), 4, (256, 320), 128, 640, 16), ((2, 48, 256), 2, (128,), 0, None, 512)]
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("shape,taps,split,start,width,tile", _CONV_CASES,
+                         ids=["L40_C256_K4", "L100_C384_K2_parts", "window_C384_K4_parts",
+                              "one_tile_C256_K2"])
+def test_causal_conv_kernels_against_the_plain_form(shape, taps, split, start, width, tile,
+                                                    dtype, tol):
+    """The kernel pair in interpret mode against the plain form and
+    ``jax.vjp`` of it: ``y`` a part at a time, ``dx``, ``d w``, ``d b``;
+    bf16 in and out with fp32 inside leaves one rounding of each result."""
+    x, w, b = _conv_operands(shape, taps, dtype, width)
+    want = ops_nn.causal_conv1d_plain(x, w, b, split, start)
+    want = want if split else (want,)
+    dys = tuple(jnp.asarray(onp.random.default_rng(11).normal(size=y.shape), dtype)
+                for y in want)
+    got = causal_conv.forward(x, w, b, split, start, tile)
+    assert len(got) == len(want)
+    for g, y in zip(got, want):
+        _close(g, y, tol)
+    _, vjp = jax.vjp(lambda *a: ops_nn.causal_conv1d_plain(*a, split, start), x, w, b)
+    want_x, want_w, want_b = vjp(dys if split else dys[0])
+    got_x, got_w, got_b = causal_conv.backward(x, w, b, dys, start, tile)
+    _close(got_x, want_x[..., start:start + shape[2]], tol)
+    _close(got_w, want_w, tol)
+    _close(got_b, want_b, tol)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 2e-2)],
+                         ids=["float32", "bfloat16"])
+def test_causal_conv_op_and_its_backward_against_jax_grad_of_the_plain_form(dtype, tol):
+    """The fused op through its own backward rule, as the mixer calls it:
+    the input a window of the projection's wider output, the output in
+    three parts; the gradient of the whole input is zero outside the
+    window."""
+    x, w, b = _conv_operands((2, 40, 384), 4, dtype, width=640)
+    cots = [jnp.asarray(onp.random.default_rng(12).normal(size=(2, 40, n)), jnp.float32)
+            for n in (256, 64, 64)]
+
+    def total(f):
+        return lambda *a: sum((y.astype(jnp.float32) * c).sum()
+                              for y, c in zip(f(*a, (256, 320), 128), cots))
+    want = jax.grad(total(ops_nn.causal_conv1d_plain), (0, 1, 2))(x, w, b)
+    got = jax.grad(total(ops_nn.causal_conv_fused), (0, 1, 2))(x, w, b)
+    for g, y in zip(got, want):
+        _close(g, y, tol)
+    assert float(jnp.abs(got[0][..., :128]).max()) == float(jnp.abs(got[0][..., 512:]).max()) == 0
+
+
+def test_the_conv_kernels_take_the_chips_shapes_and_refuse_others(monkeypatch):
+    """By what the call can observe: bf16 or fp32, whole lane tiles, at most
+    8 taps; off the TPU never (the gauge says so)."""
+    from incubator_mxnet_tpu.telemetry import metrics
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    x, w = sds((1, 8192, 4352)), sds((4352, 4))
+    assert causal_conv.supported(x, w) and causal_conv.supported(x, w, (4096, 4224))
+    assert causal_conv.supported(sds((1, 8192, 8512)), w, (4096, 4224), 4096)  # in place
+    assert causal_conv.supported(sds((1, 8192, 4352), jnp.float32), w)
+    assert not causal_conv.supported(sds((1, 8192, 4352), jnp.float16), w)
+    assert not causal_conv.supported(sds((1, 8192, 4300)), sds((4300, 4)))   # not lane tiles
+    assert not causal_conv.supported(x, sds((4352, 9)))                     # too many taps
+    assert not causal_conv.supported(x, w, (4000,))                         # a part not whole
+    assert not causal_conv.supported(sds((1, 8192, 8512)), w, (), 4000)     # a window not whole
+    assert not causal_conv.supported(sds((1, 8192, 8512)), w, (), 4224)     # past the end
+    x, w, b = _conv_operands((1, 16, 128), 4, jnp.bfloat16)
+    ops_nn.causal_conv1d(x, w, b)
+    gauge = metrics.gauge("mxtpu_causal_conv_fused", kernel="causal_conv_c128_k4")
+    assert gauge.value == 0                                  # the CPU traced the plain form
+    monkeypatch.setattr(causal_conv, "_interpret_for", lambda x: False)
+    monkeypatch.setattr(ops_nn, "causal_conv_fused", lambda *a: "kernels")
+    assert ops_nn.causal_conv1d(x, w, b) == "kernels" and gauge.value == 1
+    w9 = jnp.zeros((128, 9), jnp.bfloat16)
+    assert ops_nn.causal_conv1d(x, w9, b).shape == x.shape             # refused: the plain form
+    assert metrics.gauge("mxtpu_causal_conv_fused", kernel="causal_conv_c128_k9").value == 0
 
 
 def test_rms_norm_gated_is_the_plain_form():

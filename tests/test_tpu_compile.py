@@ -27,7 +27,8 @@ from incubator_mxnet_tpu import parallel
 from incubator_mxnet_tpu.ops.pallas import flash_attention as fa
 from incubator_mxnet_tpu.ops import nn as ops_nn
 from incubator_mxnet_tpu.ops import ssm
-from incubator_mxnet_tpu.ops.pallas import moe_gmm, moe_rows, qk_prologue, short_conv, ssd
+from incubator_mxnet_tpu.ops.pallas import (causal_conv, moe_gmm, moe_rows, qk_prologue,
+                                             short_conv, ssd)
 from incubator_mxnet_tpu.parallel import collectives, moe_dropless, ring
 
 
@@ -56,6 +57,7 @@ def for_the_chip(monkeypatch):
     monkeypatch.setattr(short_conv, "_interpret_for", lambda x: False)
     monkeypatch.setattr(qk_prologue, "_interpret_for", lambda x: False)
     monkeypatch.setattr(ssd, "_interpret_for", lambda x: False)
+    monkeypatch.setattr(causal_conv, "_interpret_for", lambda x: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
@@ -236,6 +238,35 @@ def test_ssd_scan_compiles_for_v5e(topo, for_the_chip):
         assert len(re.findall(rf'^\s*%\S*{kernel}\S* = .*custom_call_target="tpu_custom_call"',
                               text, re.M)) == 1, kernel
     assert "256,256" not in text
+
+
+# Granite-4.0-H-Micro's causal convolution at the cell's size: one row of 8,192
+# tokens, 4,352 channels (x | B | C), 4 taps, bf16; alone, and read where it
+# lies in the in-projection's 8,512-wide output, as the mixer calls it
+@pytest.mark.parametrize("width,start", [(4352, 0), (8512, 4096)],
+                         ids=["alone", "in_projection_window"])
+def test_causal_conv1d_compiles_for_v5e(topo, for_the_chip, width, start):
+    """The op through its own backward rule, its output in the scan's three
+    parts: one ``causal_conv_fwd`` and one ``causal_conv_bwd`` kernel, and
+    neither a pad nor a shifted slice of the activations left to XLA (nor
+    a copy of the window the kernels read in place)."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    L, C, split = 8192, 4352, (4096, 4224)
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)  # noqa: E731
+
+    def f(x, w, b, dys):       # x and dx as (L, width): a row-major layout, as a matmul's
+        parts, vjp = jax.vjp(lambda *a: ops_nn.causal_conv1d(*a, split=split, start=start),
+                             x.reshape(1, L, width), w, b)
+        dx, dw, db = vjp(dys)
+        return parts + (dx.reshape(L, width), dw, db)
+
+    dys = tuple(sds((1, L, n)) for n in (4096, 128, 128))
+    text = jax.jit(f).lower(sds((L, width)), sds((C, 4)), sds((C,)), dys).compile().as_text()
+    for kernel in ("causal_conv_fwd", "causal_conv_bwd"):
+        assert len(re.findall(rf'^\s*%\S*{kernel}\S* = .*custom_call_target="tpu_custom_call"',
+                              text, re.M)) == 1, kernel
+    assert not re.findall(rf'= \S+\[1,{L}\d*,{C}\]\S* (?:pad|slice|fusion|copy)\(', text)
+    assert not re.findall(rf'= \S+\[1,{L + 3},', text)       # XLA's padded row
 
 
 # Trinity-Mini's q and k at the cell's size (one row of 8,192 tokens, 32 and 4

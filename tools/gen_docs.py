@@ -632,7 +632,13 @@ def gen_profiler() -> str:
            "scan `ops.ssm.ssd_scan` of a Mamba-2 mixer, the state carried "
            "in VMEM from chunk to chunk, read by `ssd_scan_ms.train` and "
            "`ssd_scan_roofline.train`; the gauge `mxtpu_ssd_fused{kernel=}` "
-           "says whether a call took them). `jax.named_scope`s sit in every operation's "
+           "says whether a call took them); `causal_conv_fwd`, "
+           "`causal_conv_bwd` (the causal convolution with bias and SiLU "
+           "`ops.nn.causal_conv1d` of a Mamba-2 mixer, one pass over its "
+           "operands each way, read through the scope `mamba_conv` by "
+           "`mamba_conv_ms.train` and `mamba_conv_roofline.train`; the gauge "
+           "`mxtpu_causal_conv_fused{kernel=}` says whether a call took "
+           "them). `jax.named_scope`s sit in every operation's "
            "`op_name`: `afmoe_attention`, `moe_router`, `moe_dispatch`, "
            "`moe_experts`, `moe_combine`, `lm_head`, and in "
            "`models/deepseek_v3.py`'s attention block `mla_q` (the query "
